@@ -56,7 +56,7 @@ from . import payload_registry
 from .cost_model import (
     HWSpec,
     LayerSpec,
-    TPU_V5E,
+    device_hw,
     decode_linear_spec,
     layer_latency,
     network_estimate,
@@ -308,8 +308,10 @@ def sparse_candidates(M: int, pattern: BlockSparsePattern,
 
 
 def quant_candidates(M: int, K: int, N: int, x_dtype,
-                     hw: HWSpec = TPU_V5E) -> List[TunedConfig]:
-    """XLA twin + (bm, bn, bk) grid over dividing 128-multiples, VMEM-gated."""
+                     hw: Optional[HWSpec] = None) -> List[TunedConfig]:
+    """XLA twin + (bm, bn, bk) grid over dividing 128-multiples, gated on
+    the scoped VMEM limit of ``hw`` (default: this device's spec)."""
+    hw = hw or device_hw()
     cands = [TunedConfig(use_pallas=False), TunedConfig(use_pallas=True)]
     x_bytes = jnp.dtype(x_dtype).itemsize
     for bm in _bm_candidates(x_dtype):
@@ -320,7 +322,7 @@ def quant_candidates(M: int, K: int, N: int, x_dtype,
                 if K % bk:
                     continue
                 if tile_vmem_bytes(bm, bk, bn, x_bytes=x_bytes,
-                                   w_bytes=1) > hw.vmem_bytes:
+                                   w_bytes=1) > hw.vmem_scoped_bytes:
                     continue
                 cands.append(TunedConfig(use_pallas=True, bm=bm, bn=bn, bk=bk))
     return cands
@@ -334,8 +336,9 @@ def _predict_us(kind: str, cand: TunedConfig, *, M: int, K: int, N: int,
         bk, bn = pattern.block
         n_blocks = pattern.n_blocks_present
     else:
-        bk = cand.bk or (128 if K % 128 == 0 else K)
-        bn = cand.bn or (128 if N % 128 == 0 else N)
+        from .dispatch import quant_tiles
+        bk0, bn0 = quant_tiles(K, N)
+        bk, bn = cand.bk or bk0, cand.bn or bn0
         n_blocks = None
     if cand.use_pallas:
         # None = the decode entry's auto row tile — the kernel's own rule
@@ -384,7 +387,7 @@ class TuneOptions:
     iters: int = 10
     warmup: int = 2
     measure_interpret: bool = False
-    hw: HWSpec = TPU_V5E
+    hw: Optional[HWSpec] = None  # None = this device's spec (device_hw)
 
 
 def _runner(kind: str, cand: TunedConfig, x: jnp.ndarray,
@@ -466,13 +469,14 @@ def autotune_leaf(
     interpret = not on_tpu
     measurable_pallas = on_tpu or options.measure_interpret
 
+    hw = options.hw or device_hw()
     if fam.needs_pattern:
         cands = sparse_candidates(M, pattern, x.dtype)
     else:
-        cands = quant_candidates(M, K, N, x.dtype, options.hw)
+        cands = quant_candidates(M, K, N, x.dtype, hw)
     scored = [(c, _predict_us(family, c, M=M, K=K, N=N, pattern=pattern,
                               weight_bits=weight_bits, x_dtype=x.dtype,
-                              hw=options.hw)) for c in cands]
+                              hw=hw)) for c in cands]
     scored.sort(key=lambda cp: cp[1])
 
     # measured set: the XLA twin + the default-tile Pallas candidate are
@@ -516,8 +520,8 @@ def autotune_leaf(
 # ------------------------------------------------- packed-attention tuning
 
 # kv-tile candidates for the fused packed-attention read: power-of-two row
-# counts the kernel's uint8 VMEM tiles can take (128 = one MXU pass; the
-# hardware floor is 32 — smaller tiles are twin-only shapes)
+# counts (the compiled kernel takes 128 multiples, or any tile that covers
+# the whole extent — see dispatch.attn_packed_eligible)
 _ATTN_BT_CANDIDATES = (8, 16, 32, 64, 128)
 
 
@@ -610,7 +614,8 @@ def autotune_attn(
         measured.append((TunedConfig(use_pallas=False, bm=bt), us))
         n_timed += 1
         from .dispatch import attn_packed_eligible
-        if measurable_pallas and attn_packed_eligible(Dh, bt):
+        if measurable_pallas and all(attn_packed_eligible(Dh, bt, e)
+                                     for e in extents):
 
             def kern(bt=bt):
                 return [packed_decode_attention(
@@ -786,7 +791,7 @@ def tuned_policy(
         return "dense", 16
     if spec is None:
         spec = decode_linear_spec(K, N, rules.batch_tokens)
-    hw = rules.hw
+    hw = rules.hw or device_hw()
     cands: List[Tuple[str, int, FoldingConfig]] = [
         ("dense", 16, FoldingConfig(parallelism=hw.lanes, unroll="factor",
                                     quant_bits=16)),
@@ -809,7 +814,7 @@ def tuned_policy(
 
 
 def dse_retune(spec: LayerSpec, cfg: FoldingConfig,
-               hw: HWSpec = TPU_V5E) -> Optional[FoldingConfig]:
+               hw: Optional[HWSpec] = None) -> Optional[FoldingConfig]:
     """Bottleneck retune move for :func:`repro.core.dse.run_dse`.
 
     When step 3's bottleneck elimination stalls on a layer, this proposes
@@ -819,6 +824,7 @@ def dse_retune(spec: LayerSpec, cfg: FoldingConfig,
     current config is already the best, so the DSE's move loop stays
     monotone.
     """
+    hw = hw or device_hw()
     best_lat, best = None, None
     for bits in (16, 8, 4):
         trial = cfg.replace(quant_bits=bits)

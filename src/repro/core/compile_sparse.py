@@ -60,8 +60,8 @@ from . import payload_registry
 from .cost_model import (
     HWSpec,
     LayerSpec,
-    TPU_V5E,
     decode_linear_spec,
+    device_hw,
     layer_latency,
 )
 from .dispatch import ConvPayload, conv_out_hw
@@ -125,7 +125,7 @@ class CompileRules:
     block_density: float = 0.25           # target when deriving masks
     in_block_density: float = 1.0         # unstructured level inside blocks
     batch_tokens: int = 1                 # cost-model shape (decode default)
-    hw: HWSpec = TPU_V5E
+    hw: Optional[HWSpec] = None           # None = this device's spec
     min_weight_elems: int = 4096          # below this: always dense
     quantize_sparse: bool = True          # sparse blocks stored int8
     dtype: Any = jnp.float32              # float storage dtype (non-quant)
@@ -268,7 +268,7 @@ def choose_policy(
         return "dense"
     if spec is None:
         spec = decode_linear_spec(K, N, rules.batch_tokens)
-    hw = rules.hw
+    hw = rules.hw or device_hw()
     lat = {
         "dense": layer_latency(
             spec, FoldingConfig(parallelism=hw.lanes, unroll="factor",

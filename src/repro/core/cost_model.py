@@ -20,7 +20,9 @@ from .folding import FoldingConfig
 
 __all__ = [
     "HWSpec",
+    "HW_BY_DEVICE_KIND",
     "TPU_V5E",
+    "device_hw",
     "LayerSpec",
     "decode_linear_spec",
     "layer_latency",
@@ -42,11 +44,17 @@ class HWSpec:
     hbm_bytes: int
     vmem_bytes: int
     lanes: int              # modelled compute lanes per chip (MXU columns)
+    # the most VMEM one Pallas kernel's blocks + scratch may claim under
+    # Mosaic's default scoped limit (the kernels here never raise it)
+    vmem_scoped_bytes: int = 16 * 2**20
 
     def peak_flops(self, bits: int) -> float:
         return self.peak_flops_int8 if bits <= 8 else self.peak_flops_bf16
 
 
+# Peaks from Google Cloud's "TPU v5e" documentation: 197 TFLOP/s bf16,
+# 394 TOP/s int8, 16 GB HBM at 819 GB/s.  The 16 MiB scoped-VMEM limit is
+# where the chip's compiler starts refusing kernels (compile rehearsal).
 TPU_V5E = HWSpec(
     name="tpu_v5e",
     peak_flops_bf16=197e12,
@@ -57,6 +65,31 @@ TPU_V5E = HWSpec(
     vmem_bytes=128 * 2**20,
     lanes=2048,  # folding granularity: latency scales ~1/parallelism up to this
 )
+
+# ``jax.Device.device_kind`` -> spec.  A TPU whose kind is missing here is
+# an error, never a silent v5e.
+HW_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def device_hw(device=None) -> HWSpec:
+    """The HWSpec of ``device`` (default: the first JAX device).
+
+    On a TPU the spec is looked up by ``device_kind``, and an unknown kind
+    raises.  Off the chip (CPU tests, compile rehearsals) the v5e spec is
+    the modelled target.
+    """
+    import jax
+
+    d = jax.devices()[0] if device is None else device
+    if d.platform != "tpu":
+        return TPU_V5E
+    try:
+        return HW_BY_DEVICE_KIND[d.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HWSpec for TPU device kind {d.device_kind!r} — add its "
+            f"published peaks to HW_BY_DEVICE_KIND (known: "
+            f"{sorted(HW_BY_DEVICE_KIND)})") from None
 
 
 @dataclasses.dataclass

@@ -37,10 +37,10 @@ Selection policy (:func:`resolve` / :class:`DispatchConfig`):
   everywhere else (CPU CI, awkward tiles).  Both lower the *same* static
   schedule — the jnp path's gather indices are numpy constants — so this
   is a kernel-substitution choice, never a semantics choice.
-* ``pallas`` — force the Pallas kernels; off-TPU they run in interpret
-  mode (Python-speed, bit-compatible — the differential test mode).  In
-  compiled (on-TPU) execution, shapes that cannot satisfy the hardware
-  tile minima still take the jnp twin — same numerics, no Mosaic crash.
+* ``pallas`` — force the Pallas kernels; on the CPU they run in
+  interpret mode (Python-speed — the differential test mode).  In
+  compiled (on-TPU) execution, shapes the chip's compiler would refuse
+  still take the jnp twin, with a warning (or an error under strict).
 * ``jnp``   — force the reference path (oracle, and the CPU prod path).
 * ``autotune`` — ``auto`` plus the on-disk :class:`TunedTable`
   (:mod:`repro.core.autotune`): per-leaf measured tile/backend choices,
@@ -84,7 +84,9 @@ Forced-pallas fallbacks are never silent: when ``mode="pallas"`` must run
 the jnp twin in compiled execution (shape fails the hardware eligibility
 predicate), a one-time structured :class:`DispatchFallbackWarning` names
 the leaf and the failed predicate; ``REPRO_DISPATCH_STRICT=1`` upgrades
-the fallback to a :class:`DispatchStrictError`.
+the fallback to a :class:`DispatchStrictError`, and also makes ``auto``
+on a TPU raise for a compressed leaf the kernels cannot take — a strict
+chip run either runs every compressed leaf through its kernel or stops.
 """
 from __future__ import annotations
 
@@ -97,7 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.fc_stack import fc_stack_eligible, fc_stack_matmul
+from ..kernels.fc_stack import fc_stack_matmul, fc_stack_vmem_bytes
 from ..kernels.quant_matmul.kernel import quant_conv, quant_matmul
 from ..kernels.sparse_matmul.kernel import (
     ACTIVATIONS,
@@ -111,6 +113,7 @@ from ..kernels.sparse_matmul.kernel import (
 )
 from ..kernels.sparse_matmul.ops import sparse_linear
 from . import payload_registry
+from .cost_model import device_hw, tile_vmem_bytes
 from .sparsity import BlockSparsePattern
 
 __all__ = [
@@ -124,6 +127,8 @@ __all__ = [
     "resolve",
     "sparse_kernel_eligible",
     "quant_kernel_eligible",
+    "fc_stack_eligible",
+    "quant_tiles",
     "ATTN_BT_DEFAULT",
     "attn_packed_eligible",
     "attn_packed_dispatch",
@@ -156,8 +161,9 @@ _LEGAL_BM = tuple(range(8, 129, 8))
 class DispatchConfig:
     """Trace-time kernel-selection knobs (never traced values).
 
-    ``interpret=None`` means "interpret iff the backend is not a TPU" —
-    forced-pallas runs stay runnable (and differentially testable) on CPU.
+    ``interpret=None`` means "compiled on a TPU, interpreted on the CPU"
+    — forced-pallas runs stay runnable (and differentially testable) in
+    CPU tests; any other backend has no Pallas path and raises.
     ``tuned`` is an optional :class:`repro.core.autotune.TunedTable`
     (identity-hashed, so this dataclass stays hashable): per-leaf measured
     tile/backend choices consulted at trace time in ``auto`` mode.
@@ -198,7 +204,12 @@ class DispatchConfig:
     def run_interpret(self) -> bool:
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise ValueError(
+                f"no Pallas path for the {backend!r} backend — the kernels "
+                "compile for a TPU and interpret only on the CPU")
+        return backend == "cpu"
 
 
 def resolve(dispatch: Union[None, str, DispatchConfig] = None) -> DispatchConfig:
@@ -225,44 +236,74 @@ def resolve(dispatch: Union[None, str, DispatchConfig] = None) -> DispatchConfig
 # ------------------------------------------------------------- eligibility
 
 
-def sparse_kernel_eligible(pattern: BlockSparsePattern, blocks_dtype) -> bool:
-    """Can the Pallas kernel execute this pattern on real TPU hardware?
+def _lane_ok(tile: int, dim: int) -> bool:
+    """TPU block rule for a lane (last) dim: a 128 multiple or the whole
+    array dim."""
+    return tile % 128 == 0 or tile == dim
 
-    The kernel streams x as (bm, bk) tiles and w as (1, bk, bn): bk is the
-    activation tile's *lane* dim and bn the weight tile's, so both must be
-    multiples of 128; 128 also covers every storage dtype's sublane minimum
-    (f32 8 / bf16 16 / int8 32) on the (bk, bn) weight tile.  In interpret
-    mode anything goes — callers only consult this for compiled
-    (non-interpret) execution.
+
+def _fits_vmem(nbytes: int) -> bool:
+    return nbytes <= device_hw().vmem_scoped_bytes
+
+
+def sparse_kernel_eligible(pattern: BlockSparsePattern, blocks_dtype) -> bool:
+    """Does the chip's compiler accept the block-sparse kernel for this
+    pattern?
+
+    The x tile is (bm, bk) and the output, scale and bias tiles are
+    (·, bn), so bk and bn each obey the lane rule (a 128 multiple or the
+    whole K / N); the (1, bk, bn) weight block always spans its array's
+    last two dims.  One step's double-buffered tiles plus the f32
+    accumulator, at the 128-row tile, must fit the scoped VMEM limit.
+    Interpret mode imposes none of this — callers only consult it for
+    compiled execution.
     """
-    del blocks_dtype  # 128-multiple bk satisfies every dtype's sublane
+    K, N = pattern.shape
     bk, bn = pattern.block
-    return bk % 128 == 0 and bn % 128 == 0
+    w_bytes = 4 if blocks_dtype is None else jnp.dtype(blocks_dtype).itemsize
+    return (_lane_ok(bk, K) and _lane_ok(bn, N)
+            and _fits_vmem(tile_vmem_bytes(128, bk, bn, w_bytes=w_bytes)))
+
+
+def quant_tiles(K: int, N: int) -> Tuple[int, int]:
+    """Default (bk, bn) of the quant kernels: 128 where it divides, else
+    the whole dim (always a legal block)."""
+    return (128 if K % 128 == 0 else K), (128 if N % 128 == 0 else N)
 
 
 def quant_kernel_eligible(K: int, N: int) -> bool:
-    """quant_matmul tiles (128, 128, 128) on real hardware."""
-    return K % 128 == 0 and N % 128 == 0
+    """Does the chip's compiler accept quant_matmul at its default tiles?
+
+    :func:`quant_tiles` always yields legal block shapes, so the bound is
+    VMEM: a whole-dim tile of a large odd-sized weight outgrows the
+    scoped limit."""
+    bk, bn = quant_tiles(K, N)
+    return _fits_vmem(tile_vmem_bytes(128, bk, bn, w_bytes=1))
+
+
+def fc_stack_eligible(dims: Sequence[Tuple[int, int]]) -> bool:
+    """Does the chip's compiler accept the fused FC stack?  Every block
+    spans whole weights, so any shape is a legal tile; the bound is the
+    scoped VMEM limit."""
+    return _fits_vmem(fc_stack_vmem_bytes(dims))
 
 
 # Default kv-tile rows for the fused packed-attention decode read.  The
 # serving engine resolves the tile size ONCE at startup (tuned entry or
-# this default) and passes it to every prefill/decode step: the online
-# softmax is only extent-invariant at a *fixed* tile size, so letting the
-# tile drift with the cache-length bucket would break cross-step bitwise
-# consistency between the kernel and its twin.
-ATTN_BT_DEFAULT = 64
+# this default) and passes it to every prefill/decode step, so every read
+# of a cache walks the same tiles whatever its extent bucket.  128 is the
+# smallest tile whose (1, bt) scale rows are legal at any extent.
+ATTN_BT_DEFAULT = 128
 
 
-def attn_packed_eligible(Dh: int, bt: int) -> bool:
-    """Can the packed-decode attention kernel tile on real hardware?
+def attn_packed_eligible(Dh: int, bt: int, T: int) -> bool:
+    """Does the chip's compiler accept the packed-decode attention kernel?
 
-    The packed uint8 tiles land in VMEM as (bt, ceil(Dh/2)) blocks: bt is
-    the sublane dim and must be a multiple of the uint8 sublane minimum
-    (32); an even head dim keeps the nibble pairs within one row so the
-    in-register decode never crosses a byte boundary.
-    """
-    return Dh % 2 == 0 and bt % 32 == 0
+    The per-row scales ride as (1, Hkv, bt) blocks of a head-major
+    (B, Hkv, T_pad) view: bt obeys the lane rule unless one tile covers
+    the whole extent.  The head dim must be even (two codes per byte, no pad
+    nibble)."""
+    return Dh % 2 == 0 and (bt % 128 == 0 or T <= bt)
 
 
 class DispatchFallbackWarning(UserWarning):
@@ -289,11 +330,11 @@ _FALLBACK_WARNED: set = set()
 
 def _note_forced_fallback(leaf: Optional[str], predicate: str) -> None:
     leaf = leaf or "<unnamed>"
-    msg = (f"forced-pallas dispatch fell back to the jnp twin for leaf "
-           f"{leaf!r}: eligibility predicate {predicate} failed — the shape "
-           f"cannot tile on hardware, so the kernel would die in Mosaic "
-           f"lowering.  Numerics are identical but the kernel perf is lost. "
-           f"Set {STRICT_ENV}=1 to raise instead.")
+    msg = (f"kernel dispatch fell back to the jnp twin for leaf "
+           f"{leaf!r}: eligibility predicate {predicate} failed — the chip's "
+           f"compiler would refuse the kernel for this shape.  Numerics "
+           f"agree to float tolerance but the kernel is lost.  Set "
+           f"{STRICT_ENV}=1 to raise instead.")
     if os.environ.get(STRICT_ENV, "").strip() == "1":
         raise DispatchStrictError(msg)
     key = (leaf, predicate)
@@ -319,7 +360,12 @@ def _use_pallas(cfg: DispatchConfig, eligible: bool, *,
         _note_forced_fallback(leaf, predicate)
         return False
     # auto: compiled Pallas on TPU when the shape tiles; jnp twin otherwise
-    return jax.default_backend() == "tpu" and eligible
+    # (under strict mode an ineligible leaf on the chip is an error too)
+    if jax.default_backend() != "tpu":
+        return False
+    if not eligible and os.environ.get(STRICT_ENV, "").strip() == "1":
+        _note_forced_fallback(leaf, predicate)
+    return eligible
 
 
 def _tuned_entry(cfg: DispatchConfig, kind: str, M: int, K: int, N: int,
@@ -462,10 +508,9 @@ def _quant_apply_pallas(w, scales, x, cfg: DispatchConfig, out_dtype,
                         packed=False):
     """quant_matmul kernel path with the fused bias/activation epilogue.
 
-    Tiles come from the tuned entry when present, else the defaults; tiles
-    fall back to whole-dim blocks when 128 does not divide — legal only in
-    interpret mode, which is the sole way here for such shapes (_use_pallas
-    gates compiled execution on quant_kernel_eligible).  ``packed`` takes
+    Tiles come from the tuned entry when present, else
+    :func:`quant_tiles` (whole-dim blocks where 128 does not divide;
+    compiled execution is gated on quant_kernel_eligible).  ``packed`` takes
     a bit-packed sub-byte container (uint8 along K; K divisible by the
     code count — guaranteed by the caller) through the kernel's packed
     prologue: a fraction of the weight bytes, identical numerics.  Tags:
@@ -483,10 +528,11 @@ def _quant_apply_pallas(w, scales, x, cfg: DispatchConfig, out_dtype,
     if entry is not None:
         bm, bn, bk = entry.bm, entry.bn, entry.bk
     bm = _effective_bm(bm, xm.dtype) or _row_tile(xm.shape[0], xm.dtype)
+    bk0, bn0 = quant_tiles(K, N)
     if bn is None or N % bn:
-        bn = 128 if N % 128 == 0 else N
+        bn = bn0
     if bk is None or K % bk or bk % ratio:
-        bk = 128 if K % 128 == 0 else K
+        bk = bk0
     xm, M = _pad_rows(xm, bm)
     y = quant_matmul(xm, w, scales.reshape(N), bias,
                      bm=bm, bn=bn, bk=bk, activation=activation,
@@ -614,8 +660,8 @@ def attn_packed_dispatch(
     double-buffered and nibble-decodes in-register; it applies only to
     the packed container on single-query-row (decode) calls.  Everything
     else — prefill chunks (C>1), the unpacked ``int4`` cache mode, the
-    jnp twin — runs :func:`tiled_packed_attention`, bitwise identical by
-    construction (same tile walk, same masking, shared ``unpack_int4``).
+    jnp twin — runs :func:`tiled_packed_attention`, which walks the same
+    tiles with the same masking and agrees to float tolerance.
 
     The kv tile size comes from the caller (``bt``), else the tuned entry
     for kind ``attn_packed`` (the entry's ``bm`` slot carries it), else
@@ -639,7 +685,7 @@ def attn_packed_dispatch(
     # the backend pick so forced-pallas never warns about chunk (C>1) or
     # unpacked-container calls the kernel was never meant to take
     if packed and C == 1 and _pick_backend(
-            cfg, entry, attn_packed_eligible(Dh, bt),
+            cfg, entry, attn_packed_eligible(Dh, bt, T),
             leaf=leaf or "attn.kv", predicate="attn_packed_eligible"):
         return packed_decode_attention(q, k_c, v_c, k_s, v_s,
                                        lengths[:, 0], bt=bt,

@@ -28,14 +28,19 @@ from jax.experimental import pallas as pl
 from .sparse_matmul.kernel import (ACTIVATIONS, _check_activation,
                                    _pad_rows, apply_activation)
 
-__all__ = ["fc_stack_matmul", "fc_stack_eligible"]
+__all__ = ["fc_stack_matmul", "fc_stack_vmem_bytes"]
 
 
-def fc_stack_eligible(dims: Sequence[Tuple[int, int]]) -> bool:
-    """Can the fused stack compile on real hardware?  Every chained
-    (K, N) must tile the 128-lane MXU pass (same rule as quant_matmul);
-    interpret mode imposes no constraint, exactly like the other kernels."""
-    return all(K % 128 == 0 and N % 128 == 0 for K, N in dims)
+def fc_stack_vmem_bytes(dims: Sequence[Tuple[int, int]],
+                        bm: int = 128) -> int:
+    """VMEM one fused-stack step claims: the double-buffered (bm, K1)
+    input, every whole (K, N) f32 weight and (1, N) bias block (padded to
+    8 sublanes), the (bm, N_L) output, plus one (bm, N) f32 intermediate
+    per layer."""
+    K1, n_out = dims[0][0], dims[-1][1]
+    weights = sum(K * N * 4 + 8 * N * 4 for K, N in dims)
+    inter = sum(bm * N * 4 for _, N in dims)
+    return 2 * (bm * K1 * 4 + weights + bm * n_out * 4) + inter
 
 
 def _stack_kernel(*refs, n_layers: int, activations):

@@ -32,7 +32,6 @@ from ..sparse_matmul.kernel import (
     _im2col_tile,
     _packed_ratio,
     _pool_tile,
-    _unpack_int4_rows,
     apply_activation,
 )
 
@@ -170,7 +169,7 @@ def quant_matmul(
         kernel = functools.partial(_kernel_packed_db, n_n=N // bn, n_k=n_k,
                                    w_bk=w_bk, bn=bn, activation=activation,
                                    packed=packed)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        w_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((bm, bn), jnp.float32),
                    pltpu.VMEM((2, w_bk, bn), jnp.uint8),
                    pltpu.SemaphoreType.DMA((2,))]

@@ -101,35 +101,37 @@ def _check_activation(activation) -> None:
         f"supported: {sorted(ACTIVATIONS)}, ('trelu', tau) or None")
 
 
-def _unpack_int4_rows(w: jnp.ndarray) -> jnp.ndarray:
-    """(bk/2, bn) uint8 container -> (bk, bn) int8 codes, in-register.
+def field_planes(w: jnp.ndarray, per_byte: int):
+    """uint8 container -> its ``per_byte`` code planes, int32, in-register.
 
-    Two int4 codes per byte along the sublane (row) axis: even logical row
-    = low nibble, odd = high nibble; sign-extension via ``(n ^ 8) - 8``
-    (exact for the full [-8, 7] range).  This is the kernel-prologue twin
-    of :func:`repro.core.quant.unpack_int4` — duplicated here (6 lines)
-    so the kernel modules stay import-cycle-free from ``repro.core``;
-    tests pin the two bit-exact against each other.
+    Plane j holds field j of every byte — bits ``[j*w, (j+1)*w)`` —
+    sign-extended via ``(c ^ s) - s`` with ``s = 2**(w-1)``, exact over
+    the full signed range; it is the codes at logical indices
+    ``per_byte*i + j`` along the packed axis.  The arithmetic runs in
+    32-bit lanes: Mosaic does not legalise shifts on i8 vectors.
     """
-    lo = jnp.bitwise_and(w, jnp.uint8(0x0F))
-    hi = jnp.right_shift(w, jnp.uint8(4))
-    both = jnp.stack([lo, hi], axis=1).reshape(w.shape[0] * 2, w.shape[1])
-    return jnp.bitwise_xor(both, jnp.uint8(8)).astype(jnp.int8) - jnp.int8(8)
+    width = 8 // per_byte
+    sign = 1 << (width - 1)
+    w32 = w.astype(jnp.int32)
+    return [(((w32 >> (j * width)) & ((1 << width) - 1)) ^ sign) - sign
+            for j in range(per_byte)]
 
 
-def _unpack_int2_rows(w: jnp.ndarray) -> jnp.ndarray:
-    """(bk/4, bn) uint8 container -> (bk, bn) int8 codes, in-register.
+def unpack_fields(w: jnp.ndarray, per_byte: int, axis: int = 0) -> jnp.ndarray:
+    """uint8 container -> int8 codes, ``per_byte`` codes per byte along
+    ``axis`` (2 for int4x2, 4 for int2x4), in-register.
 
-    Four int2 codes (crumbs) per byte along the sublane axis, low field
-    first; sign-extension via ``(c ^ 2) - 2`` (exact for [-2, 1]).  The
-    kernel-prologue twin of ``unpack_codes(..., bits=2)`` — pinned
-    bit-exact against it by tests, same import-cycle rationale as
-    :func:`_unpack_int4_rows`.
+    The :func:`field_planes` interleaved low field first along ``axis``
+    (in 32-bit lanes) and narrowed to int8.  This is the kernel-side twin
+    of :func:`repro.core.quant.unpack_codes`, duplicated so the kernel
+    modules stay import-cycle-free from ``repro.core``; tests pin the two
+    byte-identical.
     """
-    parts = [jnp.bitwise_and(jnp.right_shift(w, jnp.uint8(2 * j)),
-                             jnp.uint8(0x03)) for j in range(4)]
-    both = jnp.stack(parts, axis=1).reshape(w.shape[0] * 4, w.shape[1])
-    return jnp.bitwise_xor(both, jnp.uint8(2)).astype(jnp.int8) - jnp.int8(2)
+    axis = axis % w.ndim
+    shape = list(w.shape)
+    shape[axis] *= per_byte
+    return jnp.stack(field_planes(w, per_byte),
+                     axis=axis + 1).reshape(shape).astype(jnp.int8)
 
 
 def _packed_ratio(packed) -> int:
@@ -152,9 +154,7 @@ def _packed_ratio(packed) -> int:
 
 def _decode_rows(w: jnp.ndarray, packed) -> jnp.ndarray:
     """Container prologue: uint8 rows -> int8 codes for a packed tag."""
-    if _packed_ratio(packed) == 4:
-        return _unpack_int2_rows(w)
-    return _unpack_int4_rows(w)
+    return unpack_fields(w, _packed_ratio(packed), axis=0)
 
 
 # Fused pooling modes for the conv entry's emit step.
@@ -335,15 +335,7 @@ def _call(
     P = rows.size
     meta = jnp.asarray(np.stack([rows, cols, packed_idx, first, last]))  # (5, P)
 
-    if scales is None:
-        scales = jnp.ones((n_cols, bn), jnp.float32)  # unused for float blocks
-    else:
-        scales = scales.reshape(n_cols, bn).astype(jnp.float32)
-    if bias is None:
-        bias = jnp.zeros((n_cols, bn), jnp.float32)
-    else:
-        bias = bias.reshape(n_cols, bn).astype(jnp.float32)
-
+    scales, bias = _row_vectors(scales, bias, N)
     grid = (M // bm, P)
     # packed containers stream (bk/ratio, bn) uint8 tiles — half (int4x2)
     # or a quarter (int2x4) of the HBM bytes per block — through a
@@ -353,7 +345,7 @@ def _call(
     if packed:
         kernel = functools.partial(_kernel_packed_db, activation=activation,
                                    packed=packed)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        w_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((bm, bn), jnp.float32),
                    pltpu.VMEM((2, w_bk, bn), jnp.uint8),
                    pltpu.SemaphoreType.DMA((2,))]
@@ -363,6 +355,7 @@ def _call(
         w_spec = pl.BlockSpec((1, w_bk, bn),
                               lambda m, p, meta: (meta[2, p], 0, 0))
         scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    col_spec = pl.BlockSpec((1, bn), lambda m, p, meta: (0, meta[1, p]))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -371,8 +364,8 @@ def _call(
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda m, p, meta: (m, meta[0, p])),
                 w_spec,
-                pl.BlockSpec((1, bn), lambda m, p, meta: (meta[1, p], 0)),
-                pl.BlockSpec((1, bn), lambda m, p, meta: (meta[1, p], 0)),
+                col_spec,
+                col_spec,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda m, p, meta: (m, meta[1, p])),
             scratch_shapes=scratch,
@@ -382,6 +375,21 @@ def _call(
         name="logicsparse_block_sparse_matmul",
     )(meta, x, blocks, scales, bias)
     return out
+
+
+def _row_vectors(scales: Optional[jnp.ndarray], bias: Optional[jnp.ndarray],
+                 N: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-output-channel scale and bias as (1, N) f32 rows.
+
+    The kernels read a (1, bn) block of each at the step's output column:
+    a block whose leading dim equals the array's is a legal TPU tile,
+    where a (1, bn) block of an (n_cols, bn) table is not.  Float blocks
+    get unit scales (unused)."""
+    scales = jnp.ones((1, N), jnp.float32) if scales is None \
+        else scales.reshape(1, N).astype(jnp.float32)
+    bias = jnp.zeros((1, N), jnp.float32) if bias is None \
+        else bias.reshape(1, N).astype(jnp.float32)
+    return scales, bias
 
 
 def _epilogue_of_zero(N: int, bias: Optional[jnp.ndarray],
@@ -567,15 +575,8 @@ def _conv_call(
     )
     P = rows.size
     meta = jnp.asarray(np.stack([rows, cols, packed_idx, first, last]))
-
-    if scales is None:
-        scales = jnp.ones((n_cols, bn), jnp.float32)
-    else:
-        scales = scales.reshape(n_cols, bn).astype(jnp.float32)
-    if bias is None:
-        bias = jnp.zeros((n_cols, bn), jnp.float32)
-    else:
-        bias = bias.reshape(n_cols, bn).astype(jnp.float32)
+    scales, bias = _row_vectors(scales, bias, N)
+    col_spec = pl.BlockSpec((1, bn), lambda m, p, meta: (0, meta[1, p]))
 
     Hp, Wp = (Ho // pool[1], Wo // pool[1]) if pool is not None else (Ho, Wo)
     w_bk = bk // _packed_ratio(packed)
@@ -592,8 +593,8 @@ def _conv_call(
                 pl.BlockSpec((1, H, W, cin), lambda m, p, meta: (m, 0, 0, 0)),
                 pl.BlockSpec((1, w_bk, bn),
                              lambda m, p, meta: (meta[2, p], 0, 0)),
-                pl.BlockSpec((1, bn), lambda m, p, meta: (meta[1, p], 0)),
-                pl.BlockSpec((1, bn), lambda m, p, meta: (meta[1, p], 0)),
+                col_spec,
+                col_spec,
             ],
             out_specs=pl.BlockSpec(
                 (1, Hp, Wp, bn), lambda m, p, meta: (m, 0, 0, meta[1, p])),

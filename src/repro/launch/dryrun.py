@@ -119,13 +119,13 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         step = make_train_step(cfg, opt_cfg, n_micro)
         jitted = jax.jit(step, in_shardings=(p_shard, o_shard, b_shard),
                          out_shardings=(p_shard, o_shard, None))
-        with mesh:
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(pshapes, oshapes, input_specs(cfg, shape))
     elif shape.kind == "prefill":
         step = make_prefill_step(cfg)
         jitted = jax.jit(step, in_shardings=(p_shard, b_shard),
                          out_shardings=None)
-        with mesh:
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(pshapes, input_specs(cfg, shape))
     else:  # decode
         cshapes = cache_shapes(cfg, shape)
@@ -140,7 +140,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         tok_shard = NamedSharding(mesh, tok_spec)
         jitted = jax.jit(step, in_shardings=(p_shard, c_shard, tok_shard),
                          out_shardings=(None, c_shard))
-        with mesh:
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(pshapes, cshapes,
                                    input_specs(cfg, shape)["tokens"])
     t_lower = time.time() - t0

@@ -14,6 +14,7 @@ import numpy as np
 from ..configs import ARCH_IDS, get_config, reduced_config
 from ..models.model import init_params
 from ..serve.engine import Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else reduced_config(args.arch)
     if not cfg.supports_decode or cfg.frontend == "frame":

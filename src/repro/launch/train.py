@@ -22,6 +22,7 @@ from ..models.model import init_params
 from ..train.optimizer import AdamWConfig, adamw_init
 from ..train.runtime import RunnerConfig, TrainRunner
 from ..train.trainer import make_train_step, pick_n_micro
+from .compile_cache import enable_compile_cache
 from .mesh import data_axes, make_local_mesh, make_production_mesh, mesh_size
 from .sharding import batch_specs, param_specs, sanitize_specs
 
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--step-deadline", type=float, default=0.0,
                     help="straggler watchdog seconds (0 = off)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.full:
         cfg = get_config(args.arch)
@@ -66,7 +68,7 @@ def main():
     p_shard = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), pspecs,
         is_leaf=lambda x: isinstance(x, P))
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         params = jax.tree_util.tree_map(jax.device_put, params, p_shard)
         step = jax.jit(make_train_step(cfg, opt_cfg, n_micro))
 

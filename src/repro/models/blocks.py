@@ -129,7 +129,7 @@ def patch_embed_apply(p, x, *, bias=None, dispatch=None, activation=None,
 #   "int4"   — int8 codes in [-7, 7] + per-(slot, pos, head) f32 scales
 #   "int4x2" — the codes bit-packed two-per-byte along Dh (the weights' PR 5
 #              container applied to activations-at-rest); exact round trip,
-#              so "int4" and "int4x2" decode bitwise identically
+#              so "int4" and "int4x2" hold the same codes
 KV_CACHE_MODES = ("float", "int4", "int4x2")
 
 
@@ -232,7 +232,7 @@ def attn_apply(
         # off its keys — the float form stores activations, the int4/int4x2
         # forms quantise-(pack-)on-append *vectorised over the whole chunk*
         # (one amax/scale pass, one pack_int4) and decode nibbles at the
-        # attention read (bitwise identical to each other; see
+        # attention read (the same codes either way; see
         # attn_cache_init).  ``n_valid`` marks how many of the T rows are
         # real (chunk tails / inactive decode slots write garbage rows at
         # positions >= the new length — masked on every later read, or

@@ -412,8 +412,9 @@ def prefill_step(params: Params, cfg: ArchConfig, cache,
     step: each layer quantise-packs the whole chunk's K/V vectorised
     (one amax/scale pass per (slot, pos, head) row, one ``pack_int4``
     over the chunk) and writes it into the cache at the slot's current
-    length — bitwise identical to appending the same C tokens through
-    :func:`decode_step` one at a time, which tests assert.  Row ``c``
+    length — the same result, to float tolerance, as appending the same
+    C tokens through :func:`decode_step` one at a time, which tests
+    assert against the full-sequence forward.  Row ``c``
     attends causally to ``length + c + 1`` positions via the batched
     chunk read (:func:`repro.models.blocks.attn_apply` with T > 1).
 
@@ -425,8 +426,8 @@ def prefill_step(params: Params, cfg: ArchConfig, cache,
 
     Only the attention-only families chunk: an SSM/hybrid state must
     advance token-by-token, and a MoE chunk changes the router's static
-    expert capacity (a function of the token count), which would break
-    the bitwise-equals-drip contract.
+    expert capacity (a function of the token count), so a chunk would
+    route differently from the drip.
     """
     if cfg.family not in ("dense", "vlm"):
         raise ValueError(

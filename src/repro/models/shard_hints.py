@@ -1,8 +1,8 @@
 """Activation sharding hints (sequence / context parallelism).
 
 ``hint(x, *axes)`` applies ``with_sharding_constraint`` when called under a
-mesh whose axis names include the requested ones, and is a no-op otherwise
-(CPU tests, single-device runs).  This is how the DSE's chosen activation
+mesh (``jax.sharding.set_mesh``) whose axis names include the requested
+ones, and is a no-op otherwise (CPU tests, single-device runs).  This is how the DSE's chosen activation
 folding materialises without threading mesh objects through model code.
 """
 from __future__ import annotations
@@ -12,24 +12,14 @@ from jax.sharding import PartitionSpec as P
 
 
 def _mesh_axes():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return set(m.axis_names)
-    except Exception:
-        pass
-    try:  # classic `with mesh:` context manager path
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return set(m.axis_names)
-    except Exception:
-        pass
-    return set()
+    """Axis names of the mesh set by ``jax.sharding.set_mesh`` (empty when
+    none is set)."""
+    return set(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def hint(x, spec: P):
-    """Best-effort sharding constraint: drops axes the mesh doesn't have."""
+    """Sharding constraint that drops axes the mesh doesn't have; a no-op
+    without a mesh.  A constraint the mesh rejects raises."""
     axes = _mesh_axes()
     if not axes:
         return x
@@ -42,10 +32,7 @@ def hint(x, spec: P):
             fixed.append(keep if keep else None)
         else:
             fixed.append(ax if ax in axes else None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*fixed[:x.ndim]))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*fixed[:x.ndim]))
 
 
 def seq_shard_hint(x, enabled: bool):

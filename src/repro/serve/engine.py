@@ -27,7 +27,8 @@ write can never clobber a neighbouring slot; the decoding slots' masked
 garbage rows land beyond their live length and are overwritten by their
 next real write.  Cache reads are bucketed to a power-of-two extent
 (``_bucket_t``) with the kv tile size pinned once at startup — the fused
-read skips dead tiles, so bucketing changes compile shapes, never bits.
+read skips dead tiles, so every bucket walks the same live tiles and
+bucketing changes compile shapes, not the tiles attended.
 
 Per-phase accounting rides along: ``stats()`` reports prefill/decode
 step counts, token counts and per-step wall-clock, ``tokens_processed()``
@@ -36,7 +37,9 @@ the total token throughput numerator, and each :class:`Request` carries
 t_submit).
 
 This is the same ``decode_step`` the dry run lowers for the 256-chip
-mesh; here it runs on CPU for examples/tests.
+mesh.  ``chip_smoke.py`` serves a compressed llama3.2-1b through this
+engine on one TPU chip; the tests and examples run it on the CPU (Pallas
+kernels in interpret mode).
 """
 from __future__ import annotations
 
@@ -146,9 +149,8 @@ class ServeEngine:
         self.packed_read = packed_read
         self._chunked = cfg.family in _CHUNKED_FAMILIES
         # kv tile rows of the fused read — resolved ONCE (tuned entry when
-        # available, default otherwise) and pinned: the online softmax is
-        # extent-invariant only at a fixed tile size, so a drifting tile
-        # would break cross-step bitwise consistency
+        # available, default otherwise) and pinned, so every step of a
+        # sequence walks the same tiles whatever its extent bucket
         self._bt = None
         if kv_cache in ("int4", "int4x2"):
             self._bt = ATTN_BT_DEFAULT
@@ -269,8 +271,8 @@ class ServeEngine:
 
     def _bucket_t(self, t: int) -> int:
         """Power-of-two cache-read extent covering ``t`` positions (floor
-        32, capped at max_len) — one jitted step per bucket, bitwise
-        identical across buckets (dead tiles / masked extents)."""
+        32, capped at max_len) — one jitted step per bucket; every
+        bucket attends the same live tiles (dead tiles / masked extents)."""
         b = 32
         while b < t:
             b *= 2

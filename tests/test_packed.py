@@ -73,11 +73,11 @@ def test_kernel_prologue_unpack_matches_host_unpack():
     """The kernel-local nibble decoder must stay bit-exact with the
     canonical core.quant implementation (it is deliberately duplicated to
     keep the kernel modules import-cycle-free)."""
-    from repro.kernels.sparse_matmul.kernel import _unpack_int4_rows
+    from repro.kernels.sparse_matmul.kernel import unpack_fields
 
     v = _rng(2).integers(-8, 8, (10, 4)).astype(np.int8)
     packed = pack_int4(jnp.asarray(v), axis=0)
-    assert np.array_equal(np.asarray(_unpack_int4_rows(jnp.asarray(packed))),
+    assert np.array_equal(np.asarray(unpack_fields(jnp.asarray(packed), 2)),
                           np.asarray(unpack_int4(packed, 10, axis=0)))
 
 

@@ -111,6 +111,34 @@ def test_unpack_int4_is_unpack_codes():
         np.asarray(unpack_codes(p, 4, axis=1, bits=4)))
 
 
+@pytest.mark.parametrize("bits,lo,hi", [(4, -8, 7), (2, -2, 1)])
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("n", [8, 7, 5, 1])
+def test_kernel_unpack_fields_matches_unpack_codes(bits, lo, hi, axis, n):
+    """The kernels' in-register decode (32-bit lanes, then narrowed)
+    gives byte-identical int8 codes to unpack_codes under jit, over the
+    full signed range, on both axes — including odd lengths, where the
+    container's pad fields decode to the zero codes pack_codes wrote."""
+    import functools
+
+    import jax
+
+    from repro.kernels.sparse_matmul.kernel import unpack_fields
+    rng = np.random.default_rng(bits * 100 + axis * 10 + n)
+    shape = (n, 6) if axis == 0 else (6, n)
+    codes = rng.integers(lo, hi + 1, size=shape).astype(np.int8)
+    packed = pack_codes(jnp.asarray(codes), axis=axis, bits=bits)
+    per_byte = codes_per_byte(bits)
+    got = np.asarray(jax.jit(functools.partial(
+        unpack_fields, per_byte=per_byte, axis=axis))(packed))
+    assert got.dtype == np.int8
+    full = packed.shape[axis] * per_byte
+    np.testing.assert_array_equal(
+        got, np.asarray(unpack_codes(packed, full, axis=axis, bits=bits)))
+    np.testing.assert_array_equal(np.take(got, np.arange(n), axis=axis),
+                                  codes)
+
+
 # ----------------------------------------------------- container plumbing
 
 

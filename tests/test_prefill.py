@@ -1,13 +1,16 @@
-"""Chunked prefill + fused packed-attention decode: bitwise contracts.
+"""Chunked prefill + fused packed-attention decode: tolerance contracts.
 
-The prefill path's whole claim is that chunking is a pure scheduling
-choice: running a prompt through ``prefill_step`` in C-token chunks
+The prefill path's claim is that chunking is a scheduling choice:
+running a prompt through ``prefill_step`` in C-token chunks
 (quantise-packing each chunk's K/V vectorised, writing straight into the
-packed container) must leave the cache and the logits **bitwise**
-identical to feeding the same tokens one at a time through
-``decode_step``.  Likewise the fused nibble-decode attention kernel must
-be bitwise identical to its jnp twin on every dispatch leg.  These tests
-pin both contracts, plus the engine-level interleave built on them.
+packed container) must leave the cache and the logits equal, to float
+tolerance, to feeding the same tokens one at a time through
+``decode_step`` — and both must match the full-sequence ``forward``
+reference.  Likewise the fused nibble-decode attention kernel must match
+its jnp twin and a plain softmax over the dequantised cache.  A C-row
+chunk and a 1-row drip are different XLA programs, and a Mosaic kernel
+is not its XLA twin, so none of these is a bitwise contract; the
+tolerances below say what each comparison can differ by and why.
 """
 import dataclasses
 
@@ -28,13 +31,24 @@ def _cfg():
                       param_dtype="float32", remat=False)
 
 
+# f32 model with logits of magnitude ~0.5: chunk-vs-drip and the float
+# cache against forward differ only by f32 summation order (~4e-7 seen);
+# 1e-5 leaves margin yet a bf16 path (~1e-3) would fail it
+F32_ATOL = 1e-5
+# int4 KV codes round every cached K/V entry to 1/14 of its row's max:
+# against the float forward that moves these logits by ~0.065
+INT4_KV_ATOL = 0.1
+
+
 @pytest.mark.parametrize("leg", ["jnp", "pallas", "autotune"])
 @pytest.mark.parametrize("kv", ["float", "int4x2"])
 def test_chunked_prefill_bitwise_matches_drip(leg, kv, monkeypatch,
                                               tmp_path):
-    """prefill_step in odd-length chunks == decode_step token drip,
-    bitwise, on every dispatch leg — logits AND the whole live cache
-    (codes, scales, lengths)."""
+    """prefill_step in odd-length chunks agrees with the decode_step token
+    drip, and both with the full-sequence forward reference, on every
+    dispatch leg — logits AND the whole live cache (codes within one
+    quantisation step, scales, lengths)."""
+    from repro.models.model import forward
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "cache.json"))
     cfg = _cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -42,11 +56,13 @@ def test_chunked_prefill_bitwise_matches_drip(leg, kv, monkeypatch,
     P = 11                      # odd on purpose: final chunk is ragged
     C = 4
     prompt = rng.integers(1, cfg.vocab, size=(2, P)).astype(np.int32)
+    ref = np.asarray(forward(params, cfg, {"tokens": jnp.asarray(prompt)},
+                             dispatch="jnp"))[:, -1]
 
-    # reference: one token at a time
+    # one token at a time
     cache_a = init_cache(cfg, 2, 32, kv_cache=kv)
     for i in range(P):
-        ref_logits, cache_a = decode_step(
+        drip_logits, cache_a = decode_step(
             params, cfg, cache_a, jnp.asarray(prompt[:, i:i + 1]),
             dispatch=leg)
 
@@ -60,25 +76,60 @@ def test_chunked_prefill_bitwise_matches_drip(leg, kv, monkeypatch,
             params, cfg, cache_b, jnp.asarray(toks), dispatch=leg,
             n_valid=jnp.full((2,), nv, jnp.int32))
 
-    assert np.array_equal(np.asarray(ref_logits[:, 0]),
-                          np.asarray(logits[:, nv - 1]))
+    drip, chunk = np.asarray(drip_logits[:, 0]), np.asarray(logits[:, nv - 1])
+    np.testing.assert_allclose(chunk, drip, rtol=0, atol=F32_ATOL)
+    ref_atol = F32_ATOL if kv == "float" else INT4_KV_ATOL
+    np.testing.assert_allclose(chunk, ref, rtol=0, atol=ref_atol)
+    np.testing.assert_allclose(drip, ref, rtol=0, atol=ref_atol)
     assert np.array_equal(np.asarray(cache_a["length"]),
                           np.asarray(cache_b["length"]))
-    for key in cache_a:
-        if key == "length":
-            continue
-        for la, lb in zip(jax.tree_util.tree_leaves(cache_a[key]),
-                          jax.tree_util.tree_leaves(cache_b[key])):
-            # leaves are (L, B, T, ...): compare the live T-rows only —
-            # the ragged chunk's pad rows hold garbage beyond `length`
-            assert np.array_equal(np.asarray(la)[:, :, :P],
-                                  np.asarray(lb)[:, :, :P]), key
+    # leaves are (L, B, T, ...): compare the live T-rows only — the ragged
+    # chunk's pad rows hold garbage beyond `length`
+    live = lambda c, key: np.asarray(c[key])[:, :, :P]  # noqa: E731
+    if kv == "float":
+        for key in ("k", "v"):
+            np.testing.assert_allclose(live(cache_a, key),
+                                       live(cache_b, key),
+                                       rtol=0, atol=F32_ATOL, err_msg=key)
+        return
+    from repro.core.quant import unpack_int4
+    for key in ("k", "v"):
+        np.testing.assert_allclose(live(cache_a, key + "_s"),
+                                   live(cache_b, key + "_s"),
+                                   rtol=F32_ATOL, atol=0, err_msg=key)
+        codes = [np.asarray(unpack_int4(live(c, key + "_p"), cfg.head_dim,
+                                        axis=-1), np.int32)
+                 for c in (cache_a, cache_b)]
+        # a K/V entry within f32 rounding of a .5 boundary may round apart
+        assert np.abs(codes[0] - codes[1]).max() <= 1, key
+
+
+def _attention_oracle(q, k_p, v_p, k_s, v_s, lengths):
+    """Plain softmax attention over the dequantised cache, numpy f64."""
+    from repro.core.quant import unpack_int4
+    B, _, H, Dh = q.shape
+    Hkv = k_p.shape[2]
+    G = H // Hkv
+    k = np.asarray(unpack_int4(k_p, Dh, axis=-1), np.float64) \
+        * np.asarray(k_s, np.float64)[..., None]
+    v = np.asarray(unpack_int4(v_p, Dh, axis=-1), np.float64) \
+        * np.asarray(v_s, np.float64)[..., None]
+    out = np.zeros((B, 1, H, Dh))
+    for b in range(B):
+        L = int(lengths[b])
+        for h in range(H):
+            s = k[b, :L, h // G] @ np.asarray(q[b, 0, h], np.float64)
+            p = np.exp(s / np.sqrt(Dh) - (s / np.sqrt(Dh)).max())
+            out[b, 0, h] = (p / p.sum()) @ v[b, :L, h // G]
+    return out
 
 
 @pytest.mark.parametrize("bt", [32, 64])
 def test_fused_kernel_bitwise_matches_twin(bt):
-    """The Pallas nibble-decode attention kernel == its jnp twin,
-    bitwise, across ragged live lengths (dead tiles included)."""
+    """The Pallas nibble-decode attention kernel agrees with its jnp twin
+    and with a plain f64 softmax over the dequantised cache, across
+    ragged live lengths (dead tiles included).  Outputs are O(0.1); both
+    f32 paths sit within ~1e-7 of the oracle, 1e-5 gives margin."""
     from repro.core.quant import pack_int4
     from repro.kernels.flash_attention.decode_packed import (
         packed_decode_attention, tiled_packed_attention)
@@ -98,7 +149,11 @@ def test_fused_kernel_bitwise_matches_twin(bt):
                                   interpret=True)
     want = tiled_packed_attention(q, k_p, v_p, k_s, v_s,
                                   lengths[:, None], bt=bt, packed=True)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    oracle = _attention_oracle(q, k_p, v_p, k_s, v_s, lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got), oracle, rtol=0, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(want), oracle, rtol=0, atol=1e-5)
 
 
 def test_prefill_step_rejects_unsupported_family():

@@ -17,7 +17,6 @@
              batching): tokens/sec at saturation + p50/p99 latency for
              dense vs compressed vs compressed+packed-int4x2-KV; written
              to the stable top-level BENCH_serve.json
-  roofline — 40-cell dry-run roofline table (reads results/dryrun)
 """
 from __future__ import annotations
 
@@ -65,7 +64,7 @@ def _kernel_bench():
 
 def main() -> None:
     sections = sys.argv[1:] or ["table1", "fig2", "kernels", "compressed",
-                                "autotune", "serve", "roofline"]
+                                "autotune", "serve"]
     print("name,us_per_call,derived")
     if "table1" in sections:
         from . import table1_lenet
@@ -139,15 +138,6 @@ def main() -> None:
         with open(serve_traffic.SERVE_JSON, "w") as f:
             _json.dump(result, f, indent=2)
         print(f"# wrote {serve_traffic.SERVE_JSON}")
-    if "roofline" in sections:
-        from . import roofline
-        for r in roofline.rows("pod1"):
-            if r["status"] == "ok":
-                print(f"roofline/{r['arch']}/{r['shape']},"
-                      f"{r['total_s']*1e6:.1f},"
-                      f"bound={r['bound']};frac={r['roofline_frac']:.3f}")
-            else:
-                print(f"roofline/{r['arch']}/{r['shape']},,{r['status']}")
 
 
 if __name__ == "__main__":

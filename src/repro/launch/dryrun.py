@@ -7,7 +7,7 @@ _DOC = """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 Proves the distribution config is coherent without hardware: sharding
 mismatches, compile-time OOM and unsupported collectives all fail here.
 Records memory_analysis / cost_analysis / collective-bytes per cell into a
-JSON cache consumed by the roofline benchmark and EXPERIMENTS.md.
+JSON cache.
 
 Usage:
   python -m repro.launch.dryrun --arch llama3-405b --shape train_4k

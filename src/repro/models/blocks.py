@@ -160,7 +160,8 @@ def _kv_insert(cache_kv, upd, idx):
     def one(c, u, i):
         start = (i,) + (0,) * (c.ndim - 1)
         return jax.lax.dynamic_update_slice(c, u, start)
-    return jax.vmap(one)(cache_kv, upd, idx)
+    with jax.named_scope("kv_insert"):
+        return jax.vmap(one)(cache_kv, upd, idx)
 
 
 def _extent(arr, t_bound: Optional[int]):
@@ -172,7 +173,8 @@ def _extent(arr, t_bound: Optional[int]):
     changing a single bit of the output.
     """
     if t_bound is not None and t_bound < arr.shape[1]:
-        return jax.lax.slice_in_dim(arr, 0, t_bound, axis=1)
+        with jax.named_scope("kv_extent"):
+            return jax.lax.slice_in_dim(arr, 0, t_bound, axis=1)
     return arr
 
 

@@ -392,7 +392,8 @@ def decode_step(params: Params, cfg: ArchConfig, cache, tokens: jnp.ndarray,
     else:
         raise ValueError(cfg.family)
 
-    h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
+    with jax.named_scope("layer_scan"):
+        h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
     h = norm_apply(cfg, params["final_norm"], h)
     if cfg.tie_embeddings:
         logits = jnp.dot(h, params["embed"]["w"].T.astype(h.dtype))
@@ -448,7 +449,8 @@ def prefill_step(params: Params, cfg: ArchConfig, cache,
                                   packed_read=packed_read)
         return out, new_c
 
-    h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
+    with jax.named_scope("layer_scan"):
+        h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
     h = norm_apply(cfg, params["final_norm"], h)
     if cfg.tie_embeddings:
         logits = jnp.dot(h, params["embed"]["w"].T.astype(h.dtype))

@@ -30,11 +30,29 @@ next real write.  Cache reads are bucketed to a power-of-two extent
 read skips dead tiles, so every bucket walks the same live tiles and
 bucketing changes compile shapes, not the tiles attended.
 
-Per-phase accounting rides along: ``stats()`` reports prefill/decode
-step counts, token counts and per-step wall-clock, ``tokens_processed()``
-the total token throughput numerator, and each :class:`Request` carries
+Accounting: ``stats()`` reports prefill/decode step counts, token
+counts and the host-clock milliseconds of each step program from its
+dispatch until its output is on the host; ``tokens_processed()`` the
+total token throughput numerator; and each :class:`Request` carries
 ``t_submit`` / ``t_first`` / ``t_done`` stamps (TTFT = t_first −
 t_submit).
+
+Tracing: every engine boundary is a ``jax.profiler.TraceAnnotation``
+span, so under ``jax.profiler.trace`` the host's time lands on the
+device trace's clock (with no profiler running a span costs about a
+microsecond).  ``engine.admit`` (arg ``admitted``) wraps admission and
+slot resets; ``engine.prefill`` (args ``uid``, ``slot``, ``rows``,
+``tb``) one chunk; ``engine.decode`` (args ``rows``, ``tb``) one decode
+step.  Each phase span holds ``.dispatch`` (the jitted call until it
+returns; for decode also the on-device argmax, queued behind it; and
+the start of the transfer to the host), ``.wait`` (until what the host
+fetches is ready on the device) and ``.fetch`` (until it is on the
+host, arg ``bytes``: a chunk's logits, with the host argmax of a
+prompt's last row, or the decode step's tokens).  The step programs
+are named functions, so the device trace shows ``jit_engine_prefill``,
+``jit_engine_decode`` and ``jit_engine_decode_legacy``; inside them the
+cache path carries the scopes ``slot_gather`` / ``slot_scatter``,
+``layer_scan``, ``kv_insert`` and ``kv_extent``.
 
 This is the same ``decode_step`` the dry run lowers for the 256-chip
 mesh.  ``chip_smoke.py`` serves a compressed llama3.2-1b through this
@@ -50,6 +68,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.config import ArchConfig
 from ..models.model import (cache_batch_axes, decode_step, init_cache,
@@ -181,9 +200,11 @@ class ServeEngine:
                        "prefill_ms": [], "decode_ms": []}
         self._decode_fns: Dict[int, object] = {}   # t_bound -> jitted step
         self._prefill_fns: Dict[int, object] = {}
-        self._step = jax.jit(
-            lambda p, c, t: decode_step(p, cfg, c, t, patterns=patterns,
-                                        dispatch=dispatch))
+
+        def engine_decode_legacy(p, c, t):
+            return decode_step(p, cfg, c, t, patterns=patterns,
+                               dispatch=dispatch)
+        self._step = jax.jit(engine_decode_legacy)
 
     def submit(self, req: Request):
         if len(req.prompt) == 0:
@@ -248,24 +269,27 @@ class ServeEngine:
         return -(-len(req.prompt) // C) * C <= self.max_len
 
     def _admit(self):
-        free = [s for s in range(self.slots) if s not in self.active]
-        while free and self.queue:
-            slot = free.pop(0)
-            req = self.queue.pop(0)
-            self._reset_slot(slot)
-            self.active[slot] = req
-            self.remaining[slot] = req.max_new_tokens
-            self._len[slot] = 0
-            if self._chunked and self._chunk_fits(req):
-                self._phase[slot] = "prefill"
-                self.prompt_pos[slot] = 0
-                self._order.append(slot)
-            else:
-                # legacy token drip (non-attention families, or a prompt
-                # whose rounded-up chunk schedule overruns the cache)
-                self._phase[slot] = "decode"
-                self.prompt_pos[slot] = 1
-                self.last_tok[slot, 0] = int(req.prompt[0])
+        with TraceAnnotation("engine.admit") as span:
+            free = [s for s in range(self.slots) if s not in self.active]
+            n = min(len(free), len(self.queue))
+            span.set_metadata(admitted=n)
+            for slot in free[:n]:
+                req = self.queue.pop(0)
+                self._reset_slot(slot)
+                self.active[slot] = req
+                self.remaining[slot] = req.max_new_tokens
+                self._len[slot] = 0
+                if self._chunked and self._chunk_fits(req):
+                    self._phase[slot] = "prefill"
+                    self.prompt_pos[slot] = 0
+                    self._order.append(slot)
+                else:
+                    # legacy token drip (non-attention families, or a
+                    # prompt whose rounded-up chunk schedule overruns the
+                    # cache)
+                    self._phase[slot] = "decode"
+                    self.prompt_pos[slot] = 1
+                    self.last_tok[slot, 0] = int(req.prompt[0])
 
     # ------------------------------------------------- chunked interleave
 
@@ -283,9 +307,12 @@ class ServeEngine:
         if fn is None:
             cfg, patterns, dispatch = self.cfg, self.patterns, self.dispatch
             bt, pr = self._bt, self.packed_read
-            fn = jax.jit(lambda p, c, t, a: decode_step(
-                p, cfg, c, t, patterns=patterns, dispatch=dispatch,
-                active=a, t_bound=tb, bt=bt, packed_read=pr))
+
+            def engine_decode(p, c, t, a):
+                return decode_step(p, cfg, c, t, patterns=patterns,
+                                   dispatch=dispatch, active=a, t_bound=tb,
+                                   bt=bt, packed_read=pr)
+            fn = jax.jit(engine_decode)
             self._decode_fns[tb] = fn
         return fn
 
@@ -298,24 +325,22 @@ class ServeEngine:
             cfg, patterns, dispatch = self.cfg, self.patterns, self.dispatch
             bt, pr, axes = self._bt, self.packed_read, self._batch_axes
 
-            def gather(cache, slot):
-                return jax.tree_util.tree_map(
-                    lambda leaf, ax: jax.lax.dynamic_slice_in_dim(
-                        leaf, slot, 1, axis=ax), cache, axes)
-
-            def scatter(cache, sub, slot):
-                return jax.tree_util.tree_map(
-                    lambda leaf, s, ax: jax.lax.dynamic_update_slice_in_dim(
-                        leaf, s, slot, axis=ax), cache, sub, axes)
-
-            def f(p, cache, slot, toks, nv):
-                sub = gather(cache, slot)
+            def engine_prefill(p, cache, slot, toks, nv):
+                with jax.named_scope("slot_gather"):
+                    sub = jax.tree_util.tree_map(
+                        lambda leaf, ax: jax.lax.dynamic_slice_in_dim(
+                            leaf, slot, 1, axis=ax), cache, axes)
                 logits, sub = prefill_step(
                     p, cfg, sub, toks, patterns=patterns, dispatch=dispatch,
                     n_valid=nv, t_bound=tb, bt=bt, packed_read=pr)
-                return logits, scatter(cache, sub, slot)
+                with jax.named_scope("slot_scatter"):
+                    cache = jax.tree_util.tree_map(
+                        lambda leaf, s, ax:
+                            jax.lax.dynamic_update_slice_in_dim(
+                                leaf, s, slot, axis=ax), cache, sub, axes)
+                return logits, cache
 
-            fn = jax.jit(f)
+            fn = jax.jit(engine_prefill)
             self._prefill_fns[tb] = fn
         return fn
 
@@ -336,34 +361,61 @@ class ServeEngine:
         C = self.prefill_chunk
         pos = self.prompt_pos[slot]
         nv = min(C, len(req.prompt) - pos)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :nv] = req.prompt[pos:pos + nv]
         tb = self._bucket_t(int(self._len[slot]) + C)
-        fn = self._prefill_fn(tb)
-        t0 = time.perf_counter()
-        logits, self.cache = fn(self.params, self.cache,
-                                jnp.asarray(slot, jnp.int32),
-                                jnp.asarray(toks),
-                                jnp.asarray([nv], jnp.int32))
-        logits = np.asarray(logits)  # sync for honest phase timing
-        now = time.perf_counter()
-        self._stats["prefill_steps"] += 1
-        self._stats["prefill_tokens"] += nv
-        self._stats["prefill_ms"].append((now - t0) * 1e3)
-        self.prompt_pos[slot] = pos + nv
-        self._len[slot] += nv
-        if self.prompt_pos[slot] == len(req.prompt):
-            # prompt complete: the first generated token IS the final
-            # chunk's last valid row — no separate decode step (TTFT win)
-            self._order.pop(0)
-            self._phase[slot] = "decode"
-            if self.remaining[slot] > 0:
-                nxt = int(np.argmax(logits[0, nv - 1]))
-                self.last_tok[slot, 0] = nxt
-                req.out.append(nxt)
-                req.t_first = now
-                self.remaining[slot] -= 1
-            self._finish(slot, now)
+        # prompt complete: the first generated token IS the final chunk's
+        # last valid row — no separate decode step (TTFT win)
+        done = pos + nv == len(req.prompt)
+        with TraceAnnotation("engine.prefill", uid=req.uid, slot=slot,
+                             rows=nv, tb=tb):
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :nv] = req.prompt[pos:pos + nv]
+            fn = self._prefill_fn(tb)
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.prefill.dispatch"):
+                logits, self.cache = fn(self.params, self.cache,
+                                        jnp.asarray(slot, jnp.int32),
+                                        jnp.asarray(toks),
+                                        jnp.asarray([nv], jnp.int32))
+                # the one transfer to the host starts as soon as the
+                # logits are ready, with no host round trip after the wait
+                logits.copy_to_host_async()
+            with TraceAnnotation("engine.prefill.wait"):
+                jax.block_until_ready(logits)
+            with TraceAnnotation("engine.prefill.fetch",
+                                 bytes=logits.nbytes):
+                logits = np.asarray(logits)
+                now = time.perf_counter()
+                if done and self.remaining[slot] > 0:
+                    nxt = int(np.argmax(logits[0, nv - 1]))
+            self._stats["prefill_steps"] += 1
+            self._stats["prefill_tokens"] += nv
+            self._stats["prefill_ms"].append((now - t0) * 1e3)
+            self.prompt_pos[slot] = pos + nv
+            self._len[slot] += nv
+            if done:
+                self._order.pop(0)
+                self._phase[slot] = "decode"
+                if self.remaining[slot] > 0:
+                    self.last_tok[slot, 0] = nxt
+                    req.out.append(nxt)
+                    req.t_first = now
+                    self.remaining[slot] -= 1
+                self._finish(slot, now)
+
+    def _decode_tokens(self, fn, *args):
+        """Run one decode step program on the host ``args`` and bring the
+        greedy next token of every slot to the host."""
+        with TraceAnnotation("engine.decode.dispatch"):
+            logits, self.cache = fn(self.params, self.cache,
+                                    *map(jnp.asarray, args))
+            # queued behind the step, and the transfer behind it: no host
+            # round trip in between
+            tok = jnp.argmax(logits[:, 0], axis=-1)
+            tok.copy_to_host_async()
+        with TraceAnnotation("engine.decode.wait"):
+            jax.block_until_ready(tok)
+        with TraceAnnotation("engine.decode.fetch", bytes=tok.nbytes):
+            return np.asarray(tok, np.int32)
 
     def _step_decode(self, dec_slots: List[int]):
         """One generated (or dripped prompt) token for every decoding
@@ -371,32 +423,30 @@ class ServeEngine:
         act = np.zeros(self.slots, np.int32)
         act[dec_slots] = 1
         tb = self._bucket_t(max(int(self._len[s]) for s in dec_slots) + 1)
-        fn = self._decode_fn(tb)
-        t0 = time.perf_counter()
-        logits, self.cache = fn(self.params, self.cache,
-                                jnp.asarray(self.last_tok),
-                                jnp.asarray(act))
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
-        now = time.perf_counter()
-        self._stats["decode_steps"] += 1
-        self._stats["decode_tokens"] += len(dec_slots)
-        self._stats["decode_ms"].append((now - t0) * 1e3)
-        for slot in dec_slots:
-            req = self.active[slot]
-            self._len[slot] += 1
-            pos = self.prompt_pos[slot]
-            if pos < len(req.prompt):
-                # drip fallback: still feeding the prompt
-                self.last_tok[slot, 0] = int(req.prompt[pos])
-                self.prompt_pos[slot] = pos + 1
-                continue
-            if self.remaining[slot] > 0:
-                self.last_tok[slot, 0] = int(nxt[slot])
-                req.out.append(int(nxt[slot]))
-                if req.t_first is None:
-                    req.t_first = now
-                self.remaining[slot] -= 1
-            self._finish(slot, now)
+        with TraceAnnotation("engine.decode", rows=len(dec_slots), tb=tb):
+            fn = self._decode_fn(tb)
+            t0 = time.perf_counter()
+            nxt = self._decode_tokens(fn, self.last_tok, act)
+            now = time.perf_counter()
+            self._stats["decode_steps"] += 1
+            self._stats["decode_tokens"] += len(dec_slots)
+            self._stats["decode_ms"].append((now - t0) * 1e3)
+            for slot in dec_slots:
+                req = self.active[slot]
+                self._len[slot] += 1
+                pos = self.prompt_pos[slot]
+                if pos < len(req.prompt):
+                    # drip fallback: still feeding the prompt
+                    self.last_tok[slot, 0] = int(req.prompt[pos])
+                    self.prompt_pos[slot] = pos + 1
+                    continue
+                if self.remaining[slot] > 0:
+                    self.last_tok[slot, 0] = int(nxt[slot])
+                    req.out.append(int(nxt[slot]))
+                    if req.t_first is None:
+                        req.t_first = now
+                    self.remaining[slot] -= 1
+                self._finish(slot, now)
 
     def _step_chunked(self) -> int:
         self._admit()
@@ -423,39 +473,41 @@ class ServeEngine:
         self._admit()
         if not self.active:
             return 0
-        t0 = time.perf_counter()
-        logits, self.cache = self._step(self.params, self.cache,
-                                        jnp.asarray(self.last_tok))
-        self.steps_run += 1
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
-        now = time.perf_counter()
-        self._stats["decode_steps"] += 1
-        self._stats["decode_tokens"] += len(self.active)
-        self._stats["decode_ms"].append((now - t0) * 1e3)
-        done = []
-        for slot, req in self.active.items():
-            pos = self.prompt_pos[slot]
-            if pos < len(req.prompt):
-                # still prefilling: feed the next prompt token
-                self.last_tok[slot, 0] = int(req.prompt[pos])
-                self.prompt_pos[slot] = pos + 1
-            else:
-                # generate only while budget remains: a request admitted
-                # with max_new_tokens=0 finishes right after prefill with
-                # out == [] (the decrement used to run after the append,
-                # so every request emitted at least one token)
-                if self.remaining[slot] > 0:
-                    self.last_tok[slot, 0] = int(nxt[slot])
-                    req.out.append(int(nxt[slot]))
-                    if req.t_first is None:
-                        req.t_first = now
-                    self.remaining[slot] -= 1
-                if self.remaining[slot] <= 0:
-                    done.append(slot)
-        for slot in done:
-            self.active[slot].t_done = now
-            del self.active[slot], self.remaining[slot], self.prompt_pos[slot]
-            self._phase.pop(slot, None)
+        with TraceAnnotation("engine.decode", rows=len(self.active),
+                             tb=self.max_len):
+            t0 = time.perf_counter()
+            nxt = self._decode_tokens(self._step, self.last_tok)
+            self.steps_run += 1
+            now = time.perf_counter()
+            self._stats["decode_steps"] += 1
+            self._stats["decode_tokens"] += len(self.active)
+            self._stats["decode_ms"].append((now - t0) * 1e3)
+            done = []
+            for slot, req in self.active.items():
+                pos = self.prompt_pos[slot]
+                if pos < len(req.prompt):
+                    # still prefilling: feed the next prompt token
+                    self.last_tok[slot, 0] = int(req.prompt[pos])
+                    self.prompt_pos[slot] = pos + 1
+                else:
+                    # generate only while budget remains: a request
+                    # admitted with max_new_tokens=0 finishes right after
+                    # prefill with out == [] (the decrement used to run
+                    # after the append, so every request emitted at least
+                    # one token)
+                    if self.remaining[slot] > 0:
+                        self.last_tok[slot, 0] = int(nxt[slot])
+                        req.out.append(int(nxt[slot]))
+                        if req.t_first is None:
+                            req.t_first = now
+                        self.remaining[slot] -= 1
+                    if self.remaining[slot] <= 0:
+                        done.append(slot)
+            for slot in done:
+                self.active[slot].t_done = now
+                del (self.active[slot], self.remaining[slot],
+                     self.prompt_pos[slot])
+                self._phase.pop(slot, None)
         return len(self.active)
 
     def step(self) -> int:

@@ -9,11 +9,12 @@ Two execution modes, chosen per model family at construction:
   the cache container; every engine step advances ONE prefilling slot by
   one chunk *and* every decoding slot by one token (``decode_step`` with
   an ``active`` mask), so a long prompt never stalls the decoding slots.
-  The first generated token falls out of the final prefill chunk's
-  logits — no extra decode step between prefill and generation, which is
-  the TTFT win.  A prompt whose chunk schedule cannot fit the cache
-  (``ceil(P/C)·C > max_len``) falls back to the legacy token drip for
-  that request only.
+  The prefill program takes the greedy token of the chunk's last valid
+  row on the device and returns it alone, not the chunk's logits; the
+  final chunk's token is the first generated token — no extra decode
+  step between prefill and generation, which is the TTFT win.  A prompt
+  whose chunk schedule cannot fit the cache (``ceil(P/C)·C > max_len``)
+  falls back to the legacy token drip for that request only.
 
 * **Legacy drip** (moe / ssm / hybrid) — exactly one token per active
   slot per step through the jitted ``decode_step``, prompts fed one
@@ -47,12 +48,13 @@ step.  Each phase span holds ``.dispatch`` (the jitted call until it
 returns; for decode also the on-device argmax, queued behind it; and
 the start of the transfer to the host), ``.wait`` (until what the host
 fetches is ready on the device) and ``.fetch`` (until it is on the
-host, arg ``bytes``: a chunk's logits, with the host argmax of a
-prompt's last row, or the decode step's tokens).  The step programs
-are named functions, so the device trace shows ``jit_engine_prefill``,
-``jit_engine_decode`` and ``jit_engine_decode_legacy``; inside them the
-cache path carries the scopes ``slot_gather`` / ``slot_scatter``,
-``layer_scan``, ``kv_insert`` and ``kv_extent``.
+host, arg ``bytes``: the one int32 token of a chunk, which is the first
+token of a completed prompt, or the decode step's tokens).  The step
+programs are named functions, so the device trace shows
+``jit_engine_prefill``, ``jit_engine_decode`` and
+``jit_engine_decode_legacy``; inside them the cache path carries the
+scopes ``slot_gather`` / ``slot_scatter``, ``layer_scan``, ``kv_insert``
+and ``kv_extent``.
 
 This is the same ``decode_step`` the dry run lowers for the 256-chip
 mesh.  ``chip_smoke.py`` serves a compressed llama3.2-1b through this
@@ -318,8 +320,10 @@ class ServeEngine:
 
     def _prefill_fn(self, tb: int):
         """Jitted one-slot chunk prefill: gather the slot's batch-of-one
-        cache view, run the chunk, scatter it back.  The slot index is a
-        traced scalar — one compile per extent bucket."""
+        cache view, run the chunk, scatter it back, and return the greedy
+        token of the chunk's last valid row with the cache.  The slot index
+        and the valid row count are traced scalars — one compile per
+        extent bucket."""
         fn = self._prefill_fns.get(tb)
         if fn is None:
             cfg, patterns, dispatch = self.cfg, self.patterns, self.dispatch
@@ -333,12 +337,15 @@ class ServeEngine:
                 logits, sub = prefill_step(
                     p, cfg, sub, toks, patterns=patterns, dispatch=dispatch,
                     n_valid=nv, t_bound=tb, bt=bt, packed_read=pr)
+                # the host needs one row's argmax, so only that token
+                # leaves the device
+                tok = jnp.argmax(logits[0, nv[0] - 1], axis=-1)
                 with jax.named_scope("slot_scatter"):
                     cache = jax.tree_util.tree_map(
                         lambda leaf, s, ax:
                             jax.lax.dynamic_update_slice_in_dim(
                                 leaf, s, slot, axis=ax), cache, sub, axes)
-                return logits, cache
+                return tok, cache
 
             fn = jax.jit(engine_prefill)
             self._prefill_fns[tb] = fn
@@ -362,8 +369,8 @@ class ServeEngine:
         pos = self.prompt_pos[slot]
         nv = min(C, len(req.prompt) - pos)
         tb = self._bucket_t(int(self._len[slot]) + C)
-        # prompt complete: the first generated token IS the final chunk's
-        # last valid row — no separate decode step (TTFT win)
+        # prompt complete: the first generated token IS the token the
+        # final chunk's program returns — no separate decode step (TTFT win)
         done = pos + nv == len(req.prompt)
         with TraceAnnotation("engine.prefill", uid=req.uid, slot=slot,
                              rows=nv, tb=tb):
@@ -372,21 +379,18 @@ class ServeEngine:
             fn = self._prefill_fn(tb)
             t0 = time.perf_counter()
             with TraceAnnotation("engine.prefill.dispatch"):
-                logits, self.cache = fn(self.params, self.cache,
-                                        jnp.asarray(slot, jnp.int32),
-                                        jnp.asarray(toks),
-                                        jnp.asarray([nv], jnp.int32))
+                tok, self.cache = fn(self.params, self.cache,
+                                     jnp.asarray(slot, jnp.int32),
+                                     jnp.asarray(toks),
+                                     jnp.asarray([nv], jnp.int32))
                 # the one transfer to the host starts as soon as the
-                # logits are ready, with no host round trip after the wait
-                logits.copy_to_host_async()
+                # token is ready, with no host round trip after the wait
+                tok.copy_to_host_async()
             with TraceAnnotation("engine.prefill.wait"):
-                jax.block_until_ready(logits)
-            with TraceAnnotation("engine.prefill.fetch",
-                                 bytes=logits.nbytes):
-                logits = np.asarray(logits)
+                jax.block_until_ready(tok)
+            with TraceAnnotation("engine.prefill.fetch", bytes=tok.nbytes):
+                nxt = int(tok)
                 now = time.perf_counter()
-                if done and self.remaining[slot] > 0:
-                    nxt = int(np.argmax(logits[0, nv - 1]))
             self._stats["prefill_steps"] += 1
             self._stats["prefill_tokens"] += nv
             self._stats["prefill_ms"].append((now - t0) * 1e3)
@@ -454,7 +458,7 @@ class ServeEngine:
             return 0
         # snapshot the decode set BEFORE the prefill advances: a slot
         # finishing its prompt this step already got its first token from
-        # the chunk logits and starts decoding next step
+        # the prefill program and starts decoding next step
         dec_slots = sorted(s for s, ph in self._phase.items()
                            if ph == "decode" and s in self.active)
         if self._order:

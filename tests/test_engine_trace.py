@@ -135,7 +135,6 @@ def test_span_counts_follow_the_engine_stats(traced):
 
 def test_span_args(traced):
     eng, steps, spans, _ = traced
-    vocab = _cfg().vocab
     pre = [s for s in spans if s[0] == "engine.prefill"]
     # prompts of 6, 3 and 9 tokens in chunks of 4, served oldest first
     assert [s[3]["rows"] for s in pre] == [4, 2, 3, 4, 4, 1]
@@ -149,9 +148,9 @@ def test_span_args(traced):
     assert [s[3]["rows"] for s in dec] == [
         st["decode_tokens"] for st in steps if st["decode_steps"]]
     assert {s[3]["tb"] for s in dec} == {32}
-    # the bytes moved to the host: a chunk's f32 logits, or one int32
-    # token a slot
-    for name, nbytes in (("engine.prefill.fetch", C * vocab * 4),
+    # the bytes moved to the host: one int32 token a chunk (its last
+    # valid row's greedy token), or one int32 token a slot
+    for name, nbytes in (("engine.prefill.fetch", 4),
                          ("engine.decode.fetch", SLOTS * 4)):
         got = {s[3]["bytes"] for s in spans if s[0] == name}
         assert got == {nbytes}

@@ -209,6 +209,45 @@ def test_chunked_engine_matches_oracle_under_churn():
         assert r.out == solo.out, (r.uid, r.out, solo.out)
 
 
+@pytest.mark.parametrize("P,max_new", [
+    (7, 2),     # ragged final chunk: 3 valid rows of 4
+    (8, 2),     # full final chunk
+    (3, 2),     # the whole prompt shorter than one chunk
+    (6, 0),     # zero budget: the prompt is prefilled, no token emitted
+], ids=["ragged", "full", "short", "no_budget"])
+def test_engine_first_token_is_last_row_argmax(P, max_new):
+    """The prefill program returns only the greedy token of the final
+    chunk's last valid row: the engine's first generated token equals
+    the argmax of ``prefill_step``'s logits at that row, run chunk by
+    chunk on a fresh batch-of-one cache with the engine's read extent."""
+    cfg, C = _cfg(), 4
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    prompt = np.random.default_rng(P).integers(
+        1, cfg.vocab, size=P).astype(np.int32)
+    eng = ServeEngine(params, cfg, batch_slots=2, max_len=32,
+                      kv_cache="int4x2", prefill_chunk=C, dispatch="jnp")
+    req = Request(uid=0, prompt=prompt, max_new_tokens=max_new)
+    eng.submit(req)
+    eng.run()
+    assert eng.stats()["prefill_steps"] == -(-P // C)
+    assert req.t_done is not None
+    if max_new == 0:
+        assert req.out == [] and req.t_first is None
+        return
+
+    cache = init_cache(cfg, 1, 32, kv_cache="int4x2")
+    for s in range(0, P, C):
+        nv = min(C, P - s)
+        toks = np.zeros((1, C), np.int32)
+        toks[0, :nv] = prompt[s:s + nv]
+        logits, cache = prefill_step(
+            params, cfg, cache, jnp.asarray(toks), dispatch="jnp",
+            n_valid=jnp.asarray([nv], jnp.int32),
+            t_bound=eng._bucket_t(s + C), bt=eng._bt)
+    assert len(req.out) == max_new
+    assert req.out[0] == int(np.argmax(np.asarray(logits)[0, nv - 1]))
+
+
 def test_unpack_read_matches_fused_tokens():
     """packed_read='unpack' (full-container decode, the bench baseline)
     and 'fused' (tiled nibble-decode) serve identical tokens."""

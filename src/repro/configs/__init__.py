@@ -14,6 +14,7 @@ _MODULES = {
     "hubert-xlarge": "hubert_xlarge",
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "olmoe-1b-7b": "olmoe_1b_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "xlstm-1.3b": "xlstm_1_3b",
     "zamba2-2.7b": "zamba2_2_7b",
     "phi-3-vision-4.2b": "phi_3_vision_4_2b",
@@ -47,7 +48,11 @@ def reduced_config(arch: str) -> ArchConfig:
     small["n_kv_heads"] = 4 if cfg.n_kv_heads == cfg.n_heads else 2
     if cfg.family == "moe":
         small.update(n_experts=8, top_k=min(cfg.top_k, 4), d_expert=32,
-                     n_shared_experts=min(cfg.n_shared_experts, 1))
+                     n_shared_experts=min(cfg.n_shared_experts, 1),
+                     first_k_dense=min(cfg.first_k_dense, 1))
+    if cfg.mla:
+        small.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16)
     if cfg.family == "ssm":
         small.update(n_layers=cfg.slstm_every, slstm_every=cfg.slstm_every,
                      d_inner=128, d_ff=0, n_kv_heads=4)

@@ -700,7 +700,7 @@ def autotune_model(
     done = set()
     tunable = payload_registry.tunable_kinds()
     for r in cm.report:
-        if r.policy not in tunable:
+        if r.policy not in tunable or r.kind == "expert":
             continue
         K, N = r.shape
         kind = ("conv_" if r.kind == "conv" else "") + r.policy

@@ -104,9 +104,17 @@ AUTOTUNE_POLICY = "autotune"
 
 # Stacked transformer linear leaves the pass may rewrite.  SSM/Mamba blocks
 # reuse some of these names but apply them without a pattern table, so the
-# walk below only descends into attention/MLP subtrees (see _iter_linears).
-_LINEAR_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
-_LINEAR_SUBTREES = ("attn", "mlp", "shared")
+# walk below only descends into attention/MLP/MoE subtrees (see
+# _iter_linears).  ``wkv_a``/``wkv_b`` are latent attention's (MLA) down
+# and up projections; ``router`` and the routed expert stacks
+# ``eg``/``eu``/``ed`` ((L, E, K, N)) are a MoE layer's.
+_LINEAR_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "wkv_a", "wkv_b",
+                "router", "eg", "eu", "ed")
+_LINEAR_SUBTREES = ("attn", "mlp", "shared", "moe")
+# a MoE layer's data-dependent leaves: no block elimination (a pruned
+# router or expert has no static schedule), quant or dense only
+_ROUTED_KEYS = ("router", "eg", "eu", "ed")
+_BLOCK_ROOTS = ("dense_blocks", "blocks")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,7 +153,7 @@ class LayerReport:
     compressed_bytes: int        # int8-container accounting (codes + scales)
     block_density: float
     element_density: float
-    kind: str = "linear"         # "linear" | "conv"
+    kind: str = "linear"         # "linear" | "conv" | "expert" (L*E stack)
     m_scale: int = 1             # matmul rows per batch row (conv: H_out*W_out)
     # bytes the payload actually holds in memory: equals compressed_bytes
     # except for bit-packed 4-bit leaves, whose uint8 containers hold two
@@ -427,11 +435,110 @@ def _iter_linears(tree: Any, path: str = "", in_linear_subtree: bool = False):
                 v, p, in_linear_subtree or k in _LINEAR_SUBTREES)
 
 
+LANE = 128
+
+
+# Linears whose consumer (:mod:`repro.models.blocks`) slices the output
+# to its logical width: latent attention's down projection
+# (576 = 512 + 64 columns in DeepSeek-V2-Lite) and the router (one column
+# an expert, 64 there).
+_SLICED_OUTPUTS = ("wkv_a", "router")
+
+
+def _pad_leaf(leaf: dict, ax: int, pad: int, bias: bool) -> dict:
+    leaf = dict(leaf)
+    w = leaf["w"]
+    widths = [(0, 0)] * w.ndim
+    widths[ax] = (0, pad)
+    leaf["w"] = jnp.pad(w, widths)
+    if bias and "b" in leaf:
+        b = leaf["b"]
+        leaf["b"] = jnp.pad(b, [(0, 0)] * (b.ndim - 1) + [(0, pad)])
+    return leaf
+
+
+def _pad_lane_widths(tree, path: str, rules: CompileRules) -> None:
+    """Zero-pad widths that are not a multiple of ``LANE`` so that every
+    kernel tile of the leaf is a whole number of lanes, in place on a
+    copied spine, where the rules name a policy without block elimination
+    (a block pattern is looked up by the logical shape):
+
+    * a raw MLP's hidden width (DeepSeek-V2-Lite's dense 10,944): the
+      gate/up columns and the down rows that are added are zero, so the
+      MLP computes exactly what it did (act(0) * 0 = 0 feeds zero rows);
+    * the output width of a :data:`_SLICED_OUTPUTS` linear, whose added
+      zero columns the consumer slices off."""
+    if not isinstance(tree, dict):
+        return
+    pols = rules.policies or {}
+
+    def fixed(k):
+        pol = pols.get(f"{path}/{k}", pols.get(k))
+        return pol is not None and pol != AUTOTUNE_POLICY and \
+            not payload_registry.policy_eliminates_blocks(pol)
+
+    def raw(k):
+        return isinstance(tree.get(k), dict) and "w" in tree[k]
+
+    keys = [k for k in ("wg", "wu", "wd") if k in tree]
+    if "wd" in keys and all(raw(k) for k in keys):
+        F = int(tree["wd"]["w"].shape[-2])
+        pad = -F % LANE
+        if pad and all(fixed(k) for k in keys):
+            for k in keys:
+                tree[k] = _pad_leaf(tree[k], -2 if k == "wd" else -1, pad,
+                                    bias=k != "wd")
+        return
+    for k, v in tree.items():
+        if k in _SLICED_OUTPUTS and raw(k):
+            pad = -int(v["w"].shape[-1]) % LANE
+            if pad and fixed(k):
+                tree[k] = _pad_leaf(v, -1, pad, bias=True)
+        else:
+            _pad_lane_widths(v, f"{path}/{k}", rules)
+
+
 def _copy_spine(tree):
     """Copy the dict structure; array leaves are shared, never mutated."""
     if not isinstance(tree, dict):
         return tree
     return {k: _copy_spine(v) for k, v in tree.items()}
+
+
+def _compile_experts(path: str, parent: dict, key: str, rules: CompileRules,
+                     override: Optional[str], report: List[LayerReport],
+                     mask) -> None:
+    """Lower one (L, E, K, N) routed-expert stack in place and report it:
+    ``quant`` on the device (no host round trip of the largest leaves),
+    or ``dense`` kept as is."""
+    from .families.quant import compile_expert_stack
+    if mask is not None:
+        raise ValueError(f"{path}: routed experts take no pruning mask")
+    leaf = parent[key]
+    w = leaf["w"]
+    L, E, K, N = (int(d) for d in w.shape)
+    dense_bytes = int(w.size * w.dtype.itemsize)
+    policy, bits = _decide_policy(path, override, K, N, rules, block=None,
+                                  block_density=1.0, element_density=1.0)
+    if policy not in ("dense", "quant"):
+        raise ValueError(f"{path}: routed experts compile under 'quant' or "
+                         f"'dense', not {policy!r}")
+    out = {k: v for k, v in leaf.items() if k != "w"}
+    if policy == "dense":
+        out["w"] = w
+        comp = cont = dense_bytes
+    else:
+        leaves = compile_expert_stack(w, bits)
+        out.update(leaves)
+        n_s = L * E * N
+        comp = L * E * K * N + 4 * n_s
+        cont = sum(int(v.size * v.dtype.itemsize) for v in leaves.values())
+    parent[key] = out
+    report.append(LayerReport(
+        name=path, policy=policy, shape=(K, N), n_layers=L * E,
+        dense_bytes=dense_bytes, compressed_bytes=int(comp),
+        block_density=1.0, element_density=1.0, kind="expert",
+        container_bytes=int(cont)))
 
 
 def compile_model(
@@ -451,11 +558,20 @@ def compile_model(
     The result's ``params`` drop into ``forward`` / ``decode_step`` /
     ``ServeEngine`` together with ``patterns``.
 
-    Scope note: MoE routed-expert stacks (``eg``/``eu``/``ed``) and the
-    router are NOT lowered — their dispatch is data-dependent (sort-based
-    top-k), so the static-schedule form does not apply yet.  They still
-    appear as dense rows in the report so ``compression`` reflects the
-    whole model, not just the lowered layers.
+    An MLP hidden width, or the output width of latent attention's down
+    projection or the router, that is not a multiple of 128 lanes, and
+    whose leaves the rules put under policies without block elimination,
+    is first zero-padded to one (:func:`_pad_lane_widths`): the same
+    function, and every kernel tile whole.
+
+    Scope note: a MoE layer's router and routed-expert stacks
+    (``eg``/``eu``/``ed``, (L, E, K, N)) are lowered like any linear,
+    under ``quant`` or ``dense`` only: their rows are chosen at run time
+    (dropless top-k), so a static block schedule does not apply.  An
+    expert stack quantises on the device, per (expert, output column),
+    into the int4x2 container the grouped expert kernel reads
+    (:func:`repro.core.families.quant.compile_expert_stack`); its report
+    row counts L x E matrices (``kind`` "expert").
     """
     if cfg.family not in ("dense", "encoder", "vlm", "moe", "hybrid"):
         raise NotImplementedError(
@@ -490,9 +606,12 @@ def compile_model(
         return pols[key]
 
     new_params = _copy_spine(params)
+    for root_name in _BLOCK_ROOTS:
+        _pad_lane_widths(new_params.get(root_name), root_name, rules)
 
     sites: List[Tuple[str, dict, str]] = []
-    roots = [] if cfg.family == "hybrid" else ["blocks"]
+    roots = [] if cfg.family == "hybrid" else [
+        r for r in _BLOCK_ROOTS if r in params]
     if "shared_attn" in params:
         roots.append("shared_attn")
     for root_name in roots:
@@ -511,6 +630,11 @@ def compile_model(
                 f"{path}: leaf is already compiled ({sorted(leaf)}); "
                 "compile_model expects a raw dense parameter tree — use "
                 "decompress_model() first to recompile")
+        if leaf["w"].ndim == 4:       # routed experts: lowered on the device
+            _compile_experts(path, parent, key, rules,
+                             _override_for(path, key), report,
+                             _mask_for(path, key))
+            continue
         w = np.asarray(leaf["w"], np.float32)
         stacked = w.ndim == 3
         stack = w if stacked else w[None]
@@ -523,7 +647,8 @@ def compile_model(
                     f"weight stack {(L, K, N)}")
             if mask.shape[0] == 1 and L > 1:  # (K, N) mask: every layer
                 mask = np.broadcast_to(mask, (L, K, N)).copy()
-        block = _fit_block(K, N, rules.block)
+        block = None if key in _ROUTED_KEYS else _fit_block(K, N,
+                                                                rules.block)
         bitmap = None
         if mask is not None and block is not None:
             bitmap = _mask_bitmap(mask[0], block)
@@ -621,24 +746,6 @@ def compile_model(
             block_density=float(bd), element_density=float(ed),
             container_bytes=int(cont_bytes)))
 
-    # Honest accounting for weights the pass leaves dense on purpose (MoE
-    # routed experts + router: data-dependent dispatch, not lowered) so
-    # CompressedModel.compression reflects the whole model.
-    def _report_dense(path, arr):
-        a = np.asarray(arr)
-        K, N = a.shape[-2:]
-        L = int(np.prod(a.shape[:-2], dtype=int)) if a.ndim > 2 else 1
-        b = int(a.size * a.dtype.itemsize)
-        report.append(LayerReport(
-            name=path, policy="dense", shape=(K, N), n_layers=L,
-            dense_bytes=b, compressed_bytes=b,
-            block_density=1.0, element_density=1.0))
-
-    if cfg.family == "moe":
-        moe = params["blocks"].get("moe", {})
-        for k in ("router", "eg", "eu", "ed"):
-            if isinstance(moe.get(k), dict) and "w" in moe[k]:
-                _report_dense(f"blocks/moe/{k}", moe[k]["w"])
     if cfg.family == "hybrid":
         # the Mamba superblocks (bulk of a hybrid model) are not lowered —
         # account them as one aggregate dense row so compression is honest
@@ -694,7 +801,7 @@ def decompress_model(cm: CompressedModel, *, dtype=jnp.float32) -> Any:
         return out
     shape_of = {r.name: r.shape for r in cm.report}
     out = _copy_spine(cm.params)
-    for root in ("blocks", "shared_attn"):
+    for root in _BLOCK_ROOTS + ("shared_attn",):
         if isinstance(out.get(root), dict):
             for path, parent, k in _iter_linears(out[root], root):
                 pat = cm.patterns.get(shape_of.get(path))

@@ -132,6 +132,9 @@ __all__ = [
     "ATTN_BT_DEFAULT",
     "attn_packed_eligible",
     "attn_packed_dispatch",
+    "grouped_dispatch",
+    "grouped_kernel_eligible",
+    "latent_attn_dispatch",
     "linear_dispatch",
     "payload_dispatch",
     "conv_dispatch",
@@ -692,6 +695,93 @@ def attn_packed_dispatch(
                                        interpret=cfg.run_interpret)
     return tiled_packed_attention(q, k_c, v_c, k_s, v_s, lengths,
                                   bt=bt, packed=packed)
+
+
+# ------------------------------------------- grouped (MoE expert) matmul
+
+
+def grouped_kernel_eligible(K: int, N: int) -> bool:
+    """Does the chip's compiler accept the grouped expert kernel?  Its
+    blocks are legal by construction (:func:`repro.kernels.grouped_matmul.
+    kernel.grouped_tiles`); the int4x2 container needs an even K."""
+    return K % 2 == 0
+
+
+def grouped_dispatch(
+    p: Params,
+    x: jnp.ndarray,            # (M_pad, K) rows in the padded tile layout
+    tile_expert: jnp.ndarray,  # (M_pad / tm,) int32
+    n_used: jnp.ndarray,       # (1,) int32 tiles in use
+    layer: jnp.ndarray,        # (1,) int32 layer of the stack
+    *,
+    tm: int,
+    dispatch: Union[None, str, DispatchConfig] = None,
+    out_dtype=None,
+    leaf: Optional[str] = None,
+) -> jnp.ndarray:
+    """One projection of every routed row through its own expert.
+
+    ``p`` is a compiled expert stack over layers: the int4x2 container
+    ``{"w_qp": (L, E, K/2, N) uint8, "w_s": (L, E, N)}`` runs the grouped
+    kernel (:func:`repro.kernels.grouped_matmul.kernel.
+    grouped_quant_matmul`) or its jnp twin; int8 codes ``{"w_q", "w_s"}``
+    run the twin.  Rows of tiles past ``n_used`` come back zero."""
+    from ..kernels.grouped_matmul.kernel import (grouped_quant_matmul,
+                                                  grouped_quant_matmul_ref)
+    from .quant import unpack_int4
+    cfg = resolve(dispatch)
+    out_dtype = x.dtype if out_dtype is None else out_dtype
+    K = x.shape[-1]
+    if "w_qp" in p:
+        N = int(p["w_qp"].shape[-1])
+        if _use_pallas(cfg, grouped_kernel_eligible(K, N), leaf=leaf,
+                       predicate=f"grouped_kernel_eligible(K={K}, N={N})"):
+            return grouped_quant_matmul(
+                x, p["w_qp"], p["w_s"], tile_expert, n_used, layer, tm=tm,
+                interpret=cfg.run_interpret, out_dtype=out_dtype)
+        codes = unpack_int4(p["w_qp"], K, axis=-2)
+    elif "w_q" in p:
+        codes = p["w_q"]
+    else:
+        raise ValueError(f"grouped_dispatch takes a compiled expert stack, "
+                         f"got leaves {sorted(p)}")
+    return grouped_quant_matmul_ref(x, codes, p["w_s"], tile_expert, n_used,
+                                    layer, tm=tm, out_dtype=out_dtype)
+
+
+# ------------------------------------------------- latent (MLA) cache read
+
+
+def latent_attn_dispatch(
+    q: jnp.ndarray,        # (B, C, H, W) absorbed queries, zero past r + dr
+    c_p: jnp.ndarray,      # (B, T, W/2) uint8 packed latent rows
+    c_s: jnp.ndarray,      # (B, T) f32 row scales
+    lengths: jnp.ndarray,  # (B, C) live length per query row
+    *,
+    scale: float,
+    dispatch: Union[None, str, DispatchConfig] = None,
+    bt: Optional[int] = None,
+    leaf: Optional[str] = None,
+) -> jnp.ndarray:
+    """The latent cache read, f32 (B, C, H, W): the Pallas kernel
+    (:func:`repro.kernels.flash_attention.latent_decode.
+    latent_decode_attention`) on decode reads (C == 1), its tiled jnp
+    twin on prefill chunks and wherever the kernel does not apply."""
+    from ..kernels.flash_attention.latent_decode import (
+        latent_decode_attention,
+        tiled_latent_attention,
+    )
+    cfg = resolve(dispatch)
+    B, C, H, W = q.shape
+    T = c_s.shape[1]
+    bt = bt or ATTN_BT_DEFAULT
+    if C == 1 and _use_pallas(
+            cfg, attn_packed_eligible(W, bt, T), leaf=leaf or "attn.latent",
+            predicate="attn_packed_eligible"):
+        return latent_decode_attention(q, c_p, c_s, lengths[:, 0],
+                                       scale=scale, bt=bt,
+                                       interpret=cfg.run_interpret)
+    return tiled_latent_attention(q, c_p, c_s, lengths, scale=scale, bt=bt)
 
 
 # ------------------------------------------------------------ convolutions

@@ -19,6 +19,8 @@ still intercept the family paths).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -178,7 +180,7 @@ def _decompress(leaf, *, pattern, shape, dtype):
     del pattern, shape
     w_q, w_s = np.asarray(leaf["w_q"]), np.asarray(leaf["w_s"])
     w = w_q.astype(np.float32) * (
-        w_s[..., None, :] if w_q.ndim == 3 else w_s[None, :])
+        w_s[..., None, :] if w_q.ndim >= 3 else w_s[None, :])
     out = {k: v for k, v in leaf.items() if k not in ("w_q", "w_s")}
     out["w"] = jnp.asarray(w, dtype)
     return out
@@ -265,6 +267,29 @@ def _compile_stack(stack, masks, *, pattern, bits, rules):
         leaves = {"w_qp": w_qp, "w_s": w_s}
         return leaves, code_bytes, int(w_qp.size + w_s.size * 4), None
     return {"w_q": w_q, "w_s": w_s}, code_bytes, code_bytes, None
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def compile_expert_stack(w, bits):
+    """Quantise an (L, E, K, N) routed-expert stack where it lives (the
+    device), per (expert, output column) with
+    :func:`repro.core.quant.quantize`'s arithmetic, one layer at a time
+    (``lax.map``: the f32 temporaries of one layer, and no second copy of
+    the result): ``{"w_qp": (L, E, K/2, N) uint8, "w_s": (L, E, N) f32}``
+    at <=4 bits (K even), else int8 ``w_q``."""
+    if bits <= 4 and w.shape[-2] % 2:
+        raise ValueError(f"int4x2 experts need an even K, got {w.shape}")
+    qm = 2 ** (bits - 1) - 1
+
+    def layer(wl):
+        wf = wl.astype(jnp.float32)
+        scale = jnp.maximum(
+            jnp.max(jnp.abs(wf), axis=-2, keepdims=True) / qm, 1e-12)
+        q = jnp.clip(jnp.round(wf / scale), -qm, qm).astype(jnp.int8)
+        return (pack_int4(q, axis=-2) if bits <= 4 else q), scale[..., 0, :]
+
+    codes, scales = jax.lax.map(layer, w)
+    return {("w_qp" if bits <= 4 else "w_q"): codes, "w_s": scales}
 
 
 def _compile_payload(w, mask, *, bits, rules, block):

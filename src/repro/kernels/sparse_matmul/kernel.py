@@ -117,6 +117,17 @@ def field_planes(w: jnp.ndarray, per_byte: int):
             for j in range(per_byte)]
 
 
+def even_odd_lanes(x: jnp.ndarray):
+    """(x[..., 0::2], x[..., 1::2]) as strided slices (an indexing
+    expression can lower to a gather, which the chip's compiler may try
+    to stage whole in fast memory): the activation halves that meet an
+    int4x2 container's low and high code planes."""
+    n = x.shape[-1]
+    lo = jax.lax.slice_in_dim(x, 0, n, stride=2, axis=x.ndim - 1)
+    hi = jax.lax.slice_in_dim(x, 1, n, stride=2, axis=x.ndim - 1)
+    return lo, hi
+
+
 def unpack_fields(w: jnp.ndarray, per_byte: int, axis: int = 0) -> jnp.ndarray:
     """uint8 container -> int8 codes, ``per_byte`` codes per byte along
     ``axis`` (2 for int4x2, 4 for int2x4), in-register.

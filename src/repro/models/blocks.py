@@ -1,5 +1,6 @@
-"""Transformer blocks: GQA attention, MLP, MoE — all linears via the
-LogicSparse datapath dispatch (``layers.linear_init/linear_apply``)."""
+"""Transformer blocks: GQA and latent (MLA) attention, MLP, dropless MoE —
+all linears via the LogicSparse datapath dispatch
+(``layers.linear_init/linear_apply``)."""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
@@ -13,6 +14,7 @@ from .config import ArchConfig
 from .layers import (
     Params,
     apply_rope,
+    apply_rope_interleaved,
     chunked_attention,
     decode_attention,
     layernorm,
@@ -22,6 +24,8 @@ from .layers import (
     prefill_attention,
     rmsnorm,
     rmsnorm_init,
+    yarn_inv_freq,
+    yarn_mscale,
 )
 
 # ------------------------------------------------------------------- helpers
@@ -32,7 +36,9 @@ def _norm_init(cfg: ArchConfig):
 
 
 def norm_apply(cfg: ArchConfig, p: Params, x):
-    return rmsnorm(p, x) if cfg.norm == "rms" else layernorm(p, x)
+    if cfg.norm == "rms":
+        return rmsnorm(p, x, cfg.norm_eps)
+    return layernorm(p, x, cfg.norm_eps)
 
 
 def _dtype(cfg: ArchConfig):
@@ -179,6 +185,8 @@ def _extent(arr, t_bound: Optional[int]):
 
 
 def attn_init(key, cfg: ArchConfig) -> Params:
+    if cfg.mla:
+        return mla_init(key, cfg)
     D, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 4)
     return {
@@ -203,6 +211,10 @@ def attn_apply(
     bt: Optional[int] = None,          # fused-read kv tile rows (None=tuned)
     packed_read: str = "fused",        # quantised read: "fused" | "unpack"
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    if cfg.mla:
+        return mla_apply(p, cfg, x, positions, cache, patterns, dispatch,
+                         n_valid=n_valid, t_bound=t_bound, bt=bt,
+                         packed_read=packed_read)
     B, T, D = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = lin_apply(cfg, p["wq"], x, D, H * Dh, patterns,
@@ -322,6 +334,8 @@ def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int,
     per row).  ``attn_apply`` detects the container from the dict keys at
     trace time, so ``decode_step``'s signature carries no extra mode.
     """
+    if cfg.mla:
+        return mla_cache_init(cfg, batch, max_len, kv_cache=kv_cache)
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
     length = jnp.zeros((batch,), jnp.int32)
     if kv_cache in (None, "float"):
@@ -349,6 +363,167 @@ def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int,
         "v_p": jnp.zeros((batch, max_len, Hkv, (Dh + 1) // 2), jnp.uint8),
         **scales, "length": length,
     }
+
+
+# ------------------------------------------------- latent attention (MLA)
+#
+# Multi-head latent attention as DeepSeek-V2 defines it (no q compression):
+#   q = x Wq -> per head [q_nope (dn) ; q_pe (dr)]
+#   [c_kv ; k_pe] = x Wkv_a, c_kv = RMSNorm(c_kv)        (r + dr, shared)
+#   [k_nope ; v] = c_kv Wkv_b per head                    (dn + dv)
+#   rope on interleaved pairs of q_pe and k_pe (YaRN frequencies)
+#   o = concat_h softmax(scale q_h . k_h) v_h, then Wo
+# computed in the absorbed form: head h's query against the latent is
+# [Wkb_h q_nope_h ; q_pe_h], so a score is that query's dot with the row
+# [c_kv ; k_pe], and the output is Wvb_h applied to the weighted sum of
+# c_kv.  The cache holds the rows [c_kv ; k_pe] alone, one per position:
+# ``"float"`` as activations (``lat``), ``"int4x2"`` as int4 codes two per
+# byte (``c_p``, zero-padded to a 128-byte multiple, the tile the chip's
+# DMA takes) with one f32 scale per (slot, position) (``c_s``).
+
+
+def mla_dims(cfg: ArchConfig) -> Tuple[int, int, int, int]:
+    """(r, dn, dr, dv): latent, no-rope, rope and value head widths."""
+    return (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim)
+
+
+def latent_row_bytes(cfg: ArchConfig) -> int:
+    """Bytes of one packed cached row: (r + dr) / 2, padded to 128."""
+    r, _, dr, _ = mla_dims(cfg)
+    return -(-(r + dr) // 256) * 128
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """(dn + dr)^-1/2, times YaRN's m^2 with m = mscale(factor,
+    mscale_all_dim), as the model's own code scales its scores."""
+    _, dn, dr, _ = mla_dims(cfg)
+    s = (dn + dr) ** -0.5
+    y = cfg.rope_yarn
+    if y is not None and y.mscale_all_dim:
+        s *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
+def mla_inv_freq(cfg: ArchConfig) -> np.ndarray:
+    _, _, dr, _ = mla_dims(cfg)
+    if cfg.rope_yarn is not None:
+        return yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_yarn)
+    return (1.0 / (cfg.rope_theta ** (np.arange(0, dr, 2, dtype=np.float32)
+                                      / dr))).astype(np.float32)
+
+
+def mla_init(key, cfg: ArchConfig) -> Params:
+    D, H = cfg.d_model, cfg.n_heads
+    r, dn, dr, dv = mla_dims(cfg)
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": lin_init(ks[0], cfg, D, H * (dn + dr)),
+        "wkv_a": lin_init(ks[1], cfg, D, r + dr),
+        "kv_norm": rmsnorm_init(r),
+        "wkv_b": lin_init(ks[2], cfg, r, H * (dn + dv)),
+        "wo": lin_init(ks[3], cfg, H * dv, D),
+    }
+
+
+def dense_weight(p: Params, K: int) -> jnp.ndarray:
+    """A linear leaf's (K, N) weight in f32: the raw ``w``, or a quant
+    leaf's codes times its column scales (int8 or int4x2 container)."""
+    if "w" in p:
+        return p["w"].astype(jnp.float32)
+    from ..core.quant import unpack_int4
+    if "w_qp" in p:
+        codes = unpack_int4(p["w_qp"], K, axis=-2)
+    elif "w_q" in p:
+        codes = p["w_q"]
+    else:
+        raise ValueError(f"no dense form for linear leaves {sorted(p)}")
+    return codes.astype(jnp.float32) * p["w_s"].astype(jnp.float32)[None, :]
+
+
+def mla_apply(p: Params, cfg: ArchConfig, x, positions, cache=None,
+              patterns=None, dispatch=None, *, n_valid=None, t_bound=None,
+              bt=None, packed_read="fused"):
+    """Latent attention; the cache contract is :func:`attn_apply`'s."""
+    B, T, D = x.shape
+    H = cfg.n_heads
+    r, dn, dr, dv = mla_dims(cfg)
+    q = lin_apply(cfg, p["wq"], x, D, H * (dn + dr), patterns,
+                  dispatch).reshape(B, T, H, dn + dr)
+    # compile_model pads this output to whole lanes (576 -> 640)
+    kv = lin_apply(cfg, p["wkv_a"], x, D, r + dr, patterns,
+                   dispatch)[..., :r + dr]
+    c = rmsnorm(p["kv_norm"], kv[..., :r], cfg.norm_eps)
+    inv = mla_inv_freq(cfg)
+    q_pe = apply_rope_interleaved(q[..., dn:], positions, inv)
+    k_pe = apply_rope_interleaved(kv[..., None, r:], positions, inv)[:, :, 0]
+    wkv_b = dense_weight(p["wkv_b"], r).reshape(r, H, dn + dv)
+    q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :dn].astype(jnp.float32),
+                       wkv_b[..., :dn])
+    qa = jnp.concatenate([q_lat, q_pe.astype(jnp.float32)], axis=-1)
+    lat = jnp.concatenate([c, k_pe], axis=-1)               # (B, T, r + dr)
+    scale = mla_softmax_scale(cfg)
+    if cache is None:
+        rows = lat[:, :, None, :]
+        o = chunked_attention(qa, rows, rows, causal=cfg.causal, scale=scale)
+        new_cache = None
+    else:
+        idx = cache["length"]
+        nv = jnp.full((B,), T, jnp.int32) if n_valid is None \
+            else n_valid.astype(jnp.int32)
+        row = jnp.arange(T, dtype=jnp.int32)
+        lengths = jnp.maximum(idx[:, None] + jnp.minimum(row + 1, nv[:, None]),
+                              1)
+        if "lat" in cache:
+            lat_c = _kv_insert(cache["lat"], lat, idx)
+            new_cache = {"lat": lat_c}
+            rows = _extent(lat_c, t_bound)[:, :, None, :]
+            if T == 1:
+                o = decode_attention(qa, rows, rows, lengths[:, 0],
+                                     scale=scale)
+            else:
+                o = prefill_attention(qa, rows, rows, lengths, scale=scale)
+        else:
+            if packed_read != "fused":
+                raise ValueError(
+                    f"the packed latent cache is read fused only, not "
+                    f"{packed_read!r}")
+            from ..core.dispatch import latent_attn_dispatch
+            from ..core.quant import pack_int4
+            Wp = cache["c_p"].shape[-1]
+            codes, sc = _kv_quant(lat[:, :, None, :])
+            packed = pack_int4(codes[:, :, 0], axis=-1)
+            packed = jnp.pad(packed, ((0, 0), (0, 0),
+                                      (0, Wp - packed.shape[-1])))
+            c_p = _kv_insert(cache["c_p"], packed, idx)
+            c_s = _kv_insert(cache["c_s"], sc[:, :, 0], idx)
+            new_cache = {"c_p": c_p, "c_s": c_s}
+            qp = jnp.pad(qa, ((0, 0), (0, 0), (0, 0), (0, 2 * Wp - (r + dr))))
+            o = latent_attn_dispatch(
+                qp, _extent(c_p, t_bound), _extent(c_s, t_bound), lengths,
+                scale=scale, dispatch=dispatch, bt=bt, leaf="attn.latent")
+        new_cache["length"] = idx + nv
+    o = jnp.einsum("bthr,rhv->bthv", o[..., :r].astype(jnp.float32),
+                   wkv_b[..., dn:]).reshape(B, T, H * dv).astype(x.dtype)
+    return lin_apply(cfg, p["wo"], o, H * dv, D, patterns, dispatch), \
+        new_cache
+
+
+def mla_cache_init(cfg: ArchConfig, batch: int, max_len: int,
+                   kv_cache: str = "float") -> Dict:
+    r, _, dr, _ = mla_dims(cfg)
+    length = jnp.zeros((batch,), jnp.int32)
+    if kv_cache in (None, "float"):
+        return {"lat": jnp.zeros((batch, max_len, r + dr), _dtype(cfg)),
+                "length": length}
+    if kv_cache != "int4x2":
+        raise ValueError(
+            f"latent attention caches 'float' or 'int4x2' rows, not "
+            f"{kv_cache!r}")
+    return {"c_p": jnp.zeros((batch, max_len, latent_row_bytes(cfg)),
+                             jnp.uint8),
+            "c_s": jnp.zeros((batch, max_len), jnp.float32),
+            "length": length}
 
 
 # ----------------------------------------------------------------------- mlp
@@ -409,57 +584,92 @@ def _stack_init(key, E, K, N, dt):
     return {"w": (jax.random.normal(key, (E, K, N)) / np.sqrt(K)).astype(dt)}
 
 
-def moe_apply(p, cfg, x, patterns=None, dispatch=None):
+def moe_apply(p, cfg, x, patterns=None, dispatch=None, valid=None,
+              layer=None):
+    """Dropless MoE; returns ``(y, counts)`` where ``counts`` is the int32
+    pair (experts that received rows, routed rows) of this layer.
+    ``layer`` is given where the compiled expert stacks in ``p`` still
+    hold every layer (see ``model._scan_groups``)."""
     with jax.named_scope("moe_apply"):
-        return _moe_apply(p, cfg, x, patterns, dispatch)
+        return _moe_apply(p, cfg, x, patterns, dispatch, valid, layer)
 
 
-def _moe_apply(p: Params, cfg: ArchConfig, x: jnp.ndarray,
-               patterns=None, dispatch=None) -> jnp.ndarray:
-    """Sort-based top-k dispatch with static capacity (drop policy).
+def _moe_apply(p: Params, cfg: ArchConfig, x: jnp.ndarray, patterns=None,
+               dispatch=None, valid=None, layer=None):
+    """Sort-based dropless top-k routing.
 
-    Gather/scatter indices are data-dependent but shapes are static, so the
-    step compiles to fixed-size ops (EP-shardable; GSPMD lowers the
-    expert-parallel exchange to all-to-all when E is mesh-sharded).
+    Every (row, expert) assignment is kept: the assignments are sorted by
+    expert, each expert's rows go through its three projections as one
+    group, and the results are scatter-added weighted by their gates.
+    Rows with ``valid`` false (idle decode slots, chunk tails) are routed
+    to no expert, so they cost nothing and change no other row's result;
+    a row's routing does not depend on how many rows share the step, so
+    a prefill chunk routes exactly as the token drip does.
     """
     B, T, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
     S = B * T
     xt = x.reshape(S, D)
-    logits = linear_apply(p["router"], xt.astype(jnp.float32))  # (S, E)
+    # compile_model pads this output to whole lanes (64 -> 128)
+    logits = lin_apply(cfg, p["router"], xt.astype(jnp.float32), D, E,
+                       patterns, dispatch)[..., :E].astype(jnp.float32)
     gates = jax.nn.softmax(logits, axis=-1)
     gate_k, ids_k = jax.lax.top_k(gates, K)                     # (S, K)
-    gate_k = gate_k / jnp.maximum(gate_k.sum(-1, keepdims=True), 1e-9)
-
-    C = int(np.ceil(S * K / E * cfg.capacity_factor))
-    C = max(8, min(C, S))
-    flat_ids = ids_k.reshape(-1)                                # (S*K,)
-    order = jnp.argsort(flat_ids)                               # stable
-    sorted_ids = flat_ids[order]
-    # rank of each entry within its expert run
-    seg_start = jnp.searchsorted(sorted_ids, jnp.arange(E))     # (E,)
-    rank = jnp.arange(S * K) - seg_start[sorted_ids]
-    keep = rank < C
-    dest = jnp.where(keep, sorted_ids * C + rank, E * C)        # E*C = drop slot
-    src_tok = order // K
-
-    buf = jnp.zeros((E * C + 1, D), xt.dtype)
-    buf = buf.at[dest].add(xt[src_tok])
-    eb = buf[: E * C].reshape(E, C, D)
-
-    g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", eb.astype(jnp.float32),
-                               p["eg"]["w"].astype(jnp.float32)))
-    u = jnp.einsum("ecd,edf->ecf", eb.astype(jnp.float32),
-                   p["eu"]["w"].astype(jnp.float32))
-    yo = jnp.einsum("ecf,efd->ecd", (g * u).astype(xt.dtype),
-                    p["ed"]["w"]).reshape(E * C, D)
-
-    gathered = jnp.where(keep[:, None], yo[jnp.minimum(dest, E * C - 1)], 0.0)
-    w = gate_k.reshape(-1)[order]
-    y = jnp.zeros((S, D), xt.dtype).at[src_tok].add(
-        (gathered * w[:, None]).astype(xt.dtype))
+    if cfg.norm_topk_prob:
+        gate_k = gate_k / jnp.maximum(gate_k.sum(-1, keepdims=True), 1e-9)
+    gate_k = gate_k * cfg.routed_scaling_factor
+    ok = jnp.ones((S,), bool) if valid is None else valid.reshape(S)
+    flat = jnp.where(ok[:, None], ids_k, E).reshape(-1)         # E: no expert
+    order = jnp.argsort(flat, stable=True)
+    routed = flat[order] < E
+    group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+    src = order // K
+    xs = jnp.where(routed[:, None], xt[src], 0).astype(xt.dtype)
+    ys = _experts(p, cfg, xs, group_sizes, dispatch, layer)
+    w = jnp.where(routed, gate_k.reshape(-1)[order], 0.0)
+    y = jnp.zeros((S, D), jnp.float32).at[src].add(
+        ys.astype(jnp.float32) * w[:, None]).astype(xt.dtype)
     if "shared" in p:
         y = y + mlp_apply(p["shared"], cfg, xt,
                           d_ff=cfg.d_expert * cfg.n_shared_experts,
                           patterns=patterns, dispatch=dispatch)
-    return y.reshape(B, T, D)
+    counts = jnp.stack([jnp.sum(group_sizes > 0), jnp.sum(group_sizes)]
+                       ).astype(jnp.int32)
+    return y.reshape(B, T, D), counts
+
+
+def _experts(p: Params, cfg: ArchConfig, xs, group_sizes, dispatch,
+             layer=None):
+    """SwiGLU of every expert over its group of the expert-sorted rows
+    ``xs``; rows past ``sum(group_sizes)`` come back zero.  Raw stacks
+    (``w`` (E, K, N)) run ``ragged_dot``; compiled stacks run the grouped
+    kernel over rows padded to whole tiles of one expert each, reading
+    layer ``layer`` of (L, E, ...) stacks (or a one-layer (E, ...) stack
+    where ``layer`` is None)."""
+    eg, eu, ed = p["eg"], p["eu"], p["ed"]
+    dt = xs.dtype
+    if "w" in eg:
+        def proj(h, leaf):
+            return jax.lax.ragged_dot(h, leaf["w"].astype(dt), group_sizes,
+                                      preferred_element_type=jnp.float32)
+        h = jax.nn.silu(proj(xs, eg)) * proj(xs, eu)
+        return proj(h.astype(dt), ed).astype(dt)
+    from ..core.dispatch import grouped_dispatch
+    from ..kernels.grouped_matmul.kernel import TM_DEFAULT, tile_layout
+    if layer is None:
+        eg, eu, ed = ({k: v[None] for k, v in leaf.items()}
+                      for leaf in (eg, eu, ed))
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    R = xs.shape[0]
+    dest, tiles, n_used, m_pad = tile_layout(group_sizes, R, TM_DEFAULT)
+    xp = jnp.zeros((m_pad, xs.shape[1]), dt).at[dest].set(xs, mode="drop")
+
+    def proj(h, leaf, name):
+        return grouped_dispatch(leaf, h, tiles, n_used, layer,
+                                tm=TM_DEFAULT, dispatch=dispatch,
+                                leaf=f"moe.{name}")
+    h = (jax.nn.silu(proj(xp, eg, "eg").astype(jnp.float32))
+         * proj(xp, eu, "eu").astype(jnp.float32)).astype(dt)
+    return jnp.take(proj(h, ed, "ed"), dest, axis=0, mode="fill",
+                    fill_value=0)

@@ -4,7 +4,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["ArchConfig", "SHAPES", "ShapeSpec"]
+__all__ = ["ArchConfig", "SHAPES", "ShapeSpec", "YarnRope"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -24,6 +24,21 @@ SHAPES = {
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling (``rope_scaling`` of type ``yarn`` in a Hugging
+    Face config): the inverse frequencies ramp from extrapolated to
+    interpolated by ``factor`` between the ``beta_fast`` and ``beta_slow``
+    rotation counts over ``original_max_position_embeddings``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str               # dense | moe | ssm | hybrid | encoder | vlm
@@ -40,13 +55,25 @@ class ArchConfig:
     qkv_bias: bool = False
     rope_theta: float = 500000.0
     tie_embeddings: bool = False
+    norm_eps: float = 1e-5    # every RMSNorm / LayerNorm of the model
+    rope_yarn: Optional[YarnRope] = None
+
+    # multi-head latent attention (MLA): kv_lora_rank > 0 selects it; the
+    # cache then holds the normalised latent c_kv and the shared roped key
+    # k_pe of each position instead of per-head K/V
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
     d_expert: int = 0
-    capacity_factor: float = 1.25
+    first_k_dense: int = 0    # leading layers with a dense MLP of width d_ff
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
 
     # SSM / hybrid
     ssm_variant: str = ""     # mlstm | mamba2
@@ -77,6 +104,10 @@ class ArchConfig:
             object.__setattr__(self, "d_inner", 2 * self.d_model)
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def supports_decode(self) -> bool:
         return self.family != "encoder"
 
@@ -97,11 +128,20 @@ class ArchConfig:
             out.append(s)
         return out
 
+    def attn_param_count(self) -> int:
+        D, H, Hkv, Dh = self.d_model, self.n_heads, self.n_kv_heads, \
+            self.head_dim
+        if self.mla:
+            r, dn, dr, dv = (self.kv_lora_rank, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim, self.v_head_dim)
+            return (D * H * (dn + dr) + D * (r + dr) + r * H * (dn + dv)
+                    + H * dv * D)
+        return D * (H * Dh) + 2 * D * (Hkv * Dh) + (H * Dh) * D
+
     def param_count(self) -> int:
         """Analytic dense parameter count (for 6ND and memory napkin math)."""
         D, F, V, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        H, Hkv, Dh = self.n_heads, self.n_kv_heads, self.head_dim
-        attn = D * (H * Dh) + 2 * D * (Hkv * Dh) + (H * Dh) * D
+        attn = self.attn_param_count()
         if self.act == "swiglu":
             mlp = 3 * D * F
         else:
@@ -113,6 +153,10 @@ class ArchConfig:
             e_mlp = 3 * D * self.d_expert
             per_layer = attn + (self.n_experts + self.n_shared_experts) * e_mlp \
                 + D * self.n_experts  # router
+            per_layer_lead = attn + mlp
+            lead = self.first_k_dense
+            return (lead * per_layer_lead + (L - lead) * per_layer
+                    + V * D * (1 if self.tie_embeddings else 2))
         elif self.family == "ssm":
             di = self.d_inner
             per_layer = 4 * D * di + di * D  # qkv/in + gates + out (approx)
@@ -130,9 +174,10 @@ class ArchConfig:
         if self.family != "moe":
             return self.param_count()
         D, L = self.d_model, self.n_layers
-        H, Hkv, Dh = self.n_heads, self.n_kv_heads, self.head_dim
-        attn = D * (H * Dh) + 2 * D * (Hkv * Dh) + (H * Dh) * D
+        attn = self.attn_param_count()
         e_mlp = 3 * D * self.d_expert
         per_layer = attn + (self.top_k + self.n_shared_experts) * e_mlp
+        lead = self.first_k_dense
         emb = self.vocab * D * (1 if self.tie_embeddings else 2)
-        return L * per_layer + emb
+        return (L - lead) * per_layer + lead * (attn + 3 * D * self.d_ff) \
+            + emb

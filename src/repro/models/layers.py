@@ -24,6 +24,7 @@ per linear shape, so (L, P, bk, bn) leaves stay scannable.
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -145,6 +146,48 @@ def rope_freqs(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor for a length ``scale``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies (:class:`repro.models.config.YarnRope`),
+    as Hugging Face's ``_compute_yarn_parameters`` computes them: each
+    frequency blends its extrapolated and its ``factor``-interpolated
+    value along a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations."""
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra, inter = 1.0 / pos, 1.0 / (yarn.factor * pos)
+
+    def corr_dim(rot):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp           # share of the extrapolated frequency
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                           inv_freq) -> jnp.ndarray:
+    """Rope on interleaved pairs (x[2i], x[2i+1]), the complex-multiply
+    form: x: (..., T, H, d); positions: (..., T); inv_freq (d/2,)."""
+    ang = positions[..., :, None, None].astype(jnp.float32) * jnp.asarray(
+        inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    y = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return y.reshape(x.shape).astype(x.dtype)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """x: (..., T, H, Dh); positions: (..., T)."""
     dh = x.shape[-1]
@@ -174,6 +217,7 @@ def _chunked_attention(
     causal: bool,
     q_offset: int = 0,
     kv_chunk: int = 1024,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Memory-efficient (online-softmax) attention: scan over KV chunks.
 
@@ -185,7 +229,7 @@ def _chunked_attention(
     Tk, Hkv = k.shape[1], k.shape[2]
     assert H % Hkv == 0
     G = H // Hkv
-    scale = 1.0 / np.sqrt(Dh)
+    scale = 1.0 / np.sqrt(Dh) if scale is None else scale
     nchunks = max(1, -(-Tk // kv_chunk))
     Tk_pad = nchunks * kv_chunk
     if Tk_pad != Tk:
@@ -240,18 +284,19 @@ def _decode_attention(
     k_cache: jnp.ndarray,  # (B, T, Hkv, Dh)
     v_cache: jnp.ndarray,  # (B, T, Hkv, Dh)
     length: jnp.ndarray,   # (B,) valid lengths
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     B, _, H, Dh = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = H // Hkv
-    scale = 1.0 / np.sqrt(Dh)
+    scale = 1.0 / np.sqrt(Dh) if scale is None else scale
     qf = (q * scale).astype(jnp.float32).reshape(B, Hkv, G, Dh)
     s = jnp.einsum("bHgd,btHd->bHgt", qf, k_cache.astype(jnp.float32))
     mask = jnp.arange(T)[None, :] < length[:, None]  # (B, T)
     s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bHgt,btHd->bHgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(B, 1, H, Dh).astype(q.dtype)
+    return o.reshape(B, 1, H, v_cache.shape[-1]).astype(q.dtype)
 
 
 def prefill_attention(*args, **kwargs):
@@ -264,6 +309,7 @@ def _prefill_attention(
     k_cache: jnp.ndarray,  # (B, T, Hkv, Dh)
     v_cache: jnp.ndarray,  # (B, T, Hkv, Dh)
     lengths: jnp.ndarray,  # (B, C) valid length per chunk row
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """:func:`decode_attention` batched over a chunk axis.
 
@@ -276,11 +322,11 @@ def _prefill_attention(
     B, C, H, Dh = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = H // Hkv
-    scale = 1.0 / np.sqrt(Dh)
+    scale = 1.0 / np.sqrt(Dh) if scale is None else scale
     qf = (q * scale).astype(jnp.float32).reshape(B, C, Hkv, G, Dh)
     s = jnp.einsum("bcHgd,btHd->bcHgt", qf, k_cache.astype(jnp.float32))
     mask = jnp.arange(T)[None, None, :] < lengths[:, :, None]  # (B, C, T)
     s = jnp.where(mask[:, :, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bcHgt,btHd->bcHgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(B, C, H, Dh).astype(q.dtype)
+    return o.reshape(B, C, H, v_cache.shape[-1]).astype(q.dtype)

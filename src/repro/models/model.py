@@ -13,7 +13,7 @@ Families: dense | encoder | vlm | moe | ssm | hybrid.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,10 +48,23 @@ from .ssm import (
 # ---------------------------------------------------------------------- init
 
 
-def _block_init(key, cfg: ArchConfig) -> Params:
+def layer_groups(cfg: ArchConfig) -> Tuple[Tuple[str, str, int], ...]:
+    """(params key, MLP kind, layers) of each scanned layer group, in
+    order: a model with ``first_k_dense`` leading dense-MLP layers
+    (DeepSeek-V2's) scans them as ``dense_blocks`` before ``blocks``."""
+    kind = "moe" if cfg.family == "moe" else "mlp"
+    L = n_superblocks(cfg)
+    if cfg.first_k_dense:
+        return (("dense_blocks", "mlp", cfg.first_k_dense),
+                ("blocks", kind, L - cfg.first_k_dense))
+    return (("blocks", kind, L),)
+
+
+def _block_init(key, cfg: ArchConfig, kind: str = None) -> Params:
     """One repeated block for the homogeneous families."""
     ks = jax.random.split(key, 4)
-    if cfg.family in ("dense", "encoder", "vlm"):
+    if cfg.family in ("dense", "encoder", "vlm") or (
+            cfg.family == "moe" and kind == "mlp"):
         return {
             "ln1": _norm_init(cfg), "attn": attn_init(ks[0], cfg),
             "ln2": _norm_init(cfg), "mlp": mlp_init(ks[1], cfg),
@@ -92,13 +105,15 @@ def n_superblocks(cfg: ArchConfig) -> int:
 def init_params(key, cfg: ArchConfig) -> Params:
     kE, kB, kH, kS = jax.random.split(key, 4)
     dt = _dtype(cfg)
-    L = n_superblocks(cfg)
-    blocks = jax.vmap(lambda k: _block_init(k, cfg))(jax.random.split(kB, L))
     params: Params = {
         "embed": {"w": (jax.random.normal(kE, (cfg.vocab, cfg.d_model)) * 0.02).astype(dt)},
-        "blocks": blocks,
-        "final_norm": _norm_init(cfg),
     }
+    for i, (name, kind, n) in enumerate(layer_groups(cfg)):
+        kg = kB if i == len(layer_groups(cfg)) - 1 else jax.random.fold_in(
+            kB, i + 1)
+        params[name] = jax.vmap(lambda k: _block_init(k, cfg, kind))(
+            jax.random.split(kg, n))
+    params["final_norm"] = _norm_init(cfg)
     if not cfg.tie_embeddings:
         params["head"] = {
             "w": (jax.random.normal(kH, (cfg.d_model, cfg.vocab)) * 0.02).astype(dt)
@@ -121,18 +136,74 @@ def init_params(key, cfg: ArchConfig) -> Params:
 
 def _dense_block(p, cfg, h, positions, cache=None, patterns=None,
                  dispatch=None, n_valid=None, t_bound=None, bt=None,
-                 packed_read="fused"):
+                 packed_read="fused", valid=None, layer=None):
+    """Attention then MLP or MoE (by the leaves of ``p``); returns
+    ``(h, cache, counts)``, ``counts`` the MoE layer's (experts hit,
+    routed rows), None for an MLP layer.  ``valid`` marks the rows a MoE
+    layer routes (all where None)."""
     a, new_cache = attn_apply(p["attn"], cfg, norm_apply(cfg, p["ln1"], h),
                               positions, cache, patterns=patterns,
                               dispatch=dispatch, n_valid=n_valid,
                               t_bound=t_bound, bt=bt,
                               packed_read=packed_read)
     h = h + a
-    key = "moe" if cfg.family == "moe" else "mlp"
-    f = moe_apply if cfg.family == "moe" else mlp_apply
-    h = h + f(p[key], cfg, norm_apply(cfg, p["ln2"], h), patterns=patterns,
-              dispatch=dispatch)
-    return h, new_cache
+    x = norm_apply(cfg, p["ln2"], h)
+    if "moe" in p:
+        y, counts = moe_apply(p["moe"], cfg, x, patterns=patterns,
+                              dispatch=dispatch, valid=valid, layer=layer)
+        return h + y, new_cache, counts
+    return h + mlp_apply(p["mlp"], cfg, x, patterns=patterns,
+                         dispatch=dispatch), new_cache, None
+
+
+def _valid_rows(n_valid, T: int):
+    """(B, T) rows a step routes: the first ``n_valid[b]`` of each."""
+    if n_valid is None:
+        return None
+    return jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None]
+
+
+_EXPERTS = ("eg", "eu", "ed")
+
+
+def _hoist_experts(group):
+    """(the group's params less its compiled expert stacks, those stacks
+    whole or None).  The stacks stay out of the scanned inputs: a scan
+    slices each layer's inputs out by copy, which for experts is every
+    expert's weights, where the grouped kernel reads only the experts a
+    step routes to from the whole stack at the layer's index."""
+    moe = group.get("moe")
+    if not moe or "w" in moe["eg"]:
+        return group, None
+    rest = {k: v for k, v in moe.items() if k not in _EXPERTS}
+    return {**group, "moe": rest}, {k: moe[k] for k in _EXPERTS}
+
+
+def _scan_groups(params, cfg: ArchConfig, h, cache, block):
+    """Scan ``block(p_layer, h, c_layer, layer) -> (h, c, counts)`` over
+    each layer group; returns (h, new cache, summed MoE counts or None).
+    A cache of several groups is a dict keyed like the params."""
+    groups = layer_groups(cfg)
+    split = len(groups) > 1
+    new_cache, total = {}, None
+    for name, _, n in groups:
+        c = None if cache is None else (cache[name] if split else cache)
+        scanned, experts = _hoist_experts(params[name])
+
+        def body(hh, xs, experts=experts):
+            p_layer, c_layer, i = xs
+            if experts is not None:
+                p_layer = {**p_layer, "moe": {**p_layer["moe"], **experts}}
+            out, nc, counts = block(p_layer, hh, c_layer, i)
+            return out, (nc, counts)
+
+        index = None if experts is None else jnp.arange(n, dtype=jnp.int32)
+        h, (nc, counts) = jax.lax.scan(body, h, (scanned, c, index))
+        new_cache[name] = nc
+        if counts is not None:
+            total = counts.sum(0) if total is None else total + counts.sum(0)
+    out_cache = new_cache if split else new_cache[groups[0][0]]
+    return h, out_cache, total
 
 
 def _ssm_superblock(p, cfg, h, cache=None):
@@ -206,8 +277,8 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict, *,
 
     if cfg.family in ("dense", "encoder", "vlm", "moe"):
         def body(h, p_layer):
-            out, _ = _dense_block(p_layer, cfg, h, positions,
-                                  patterns=patterns, dispatch=dispatch)
+            out, _, _ = _dense_block(p_layer, cfg, h, positions,
+                                     patterns=patterns, dispatch=dispatch)
             return out, None
     elif cfg.family == "ssm":
         def body(h, p_layer):
@@ -233,7 +304,8 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict, *,
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    h, _ = jax.lax.scan(body, h, params["blocks"])
+    for name, _, _ in layer_groups(cfg):
+        h, _ = jax.lax.scan(body, h, params[name])
     h = norm_apply(cfg, params["final_norm"], h)
     if cfg.tie_embeddings:
         logits = jnp.dot(h, params["embed"]["w"].T.astype(h.dtype))
@@ -268,9 +340,11 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
     activations, ``"int4"``/``"int4x2"`` store per-position int4 codes +
     scales (the bit-packed form holds two codes per byte along Dh).  SSM
     state caches are unaffected — they are O(1) per slot, not per token.
+    A latent-attention (MLA) model caches its latent rows instead of per-head
+    K/V (:func:`repro.models.blocks.mla_cache_init`).  A model of several
+    layer groups (:func:`layer_groups`) holds one stack per group, in a dict
+    keyed like the params.
     """
-    L = n_superblocks(cfg)
-
     def one(_):
         if cfg.family in ("dense", "vlm", "moe"):
             return attn_cache_init(cfg, batch, max_len, kv_cache=kv_cache)
@@ -290,7 +364,9 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
             }
         raise ValueError(f"{cfg.family} has no decode cache")
 
-    return jax.vmap(one)(jnp.arange(L))
+    groups = layer_groups(cfg)
+    stacks = {name: jax.vmap(one)(jnp.arange(n)) for name, _, n in groups}
+    return stacks if len(groups) > 1 else stacks[groups[0][0]]
 
 
 def cache_batch_axes(cfg: ArchConfig, kv_cache: str = "float") -> Params:
@@ -309,7 +385,11 @@ def cache_batch_axes(cfg: ArchConfig, kv_cache: str = "float") -> Params:
         return jax.tree_util.tree_map(lambda _: ax, tree)
 
     if cfg.family in ("dense", "vlm", "moe"):
-        return const(attn_cache_init(cfg, 1, 1, kv_cache=kv_cache), 1)
+        one = const(attn_cache_init(cfg, 1, 1, kv_cache=kv_cache), 1)
+        groups = layer_groups(cfg)
+        if len(groups) > 1:
+            return {name: one for name, _, _ in groups}
+        return one
     if cfg.family == "ssm":
         return {
             "slstm": const(slstm_cache_init(cfg, 1), 1),
@@ -323,9 +403,23 @@ def cache_batch_axes(cfg: ArchConfig, kv_cache: str = "float") -> Params:
     raise ValueError(f"{cfg.family} has no decode cache")
 
 
+def _cache_length(cfg: ArchConfig, cache):
+    """(B,) cached length per slot, the same in every layer."""
+    first = cache[layer_groups(cfg)[0][0]] if cfg.first_k_dense else cache
+    return first["length"][0]
+
+
+def _head(params, cfg: ArchConfig, h, patterns, dispatch):
+    h = norm_apply(cfg, params["final_norm"], h)
+    if cfg.tie_embeddings:
+        return jnp.dot(h, params["embed"]["w"].T.astype(h.dtype))
+    return linear_apply(params["head"], h, pattern=(patterns or {}).get(
+        (cfg.d_model, cfg.vocab)), dispatch=dispatch)
+
+
 def decode_step(params: Params, cfg: ArchConfig, cache, tokens: jnp.ndarray,
                 *, patterns=None, dispatch=None, active=None, t_bound=None,
-                bt=None, packed_read="fused") -> Tuple[jnp.ndarray, Any]:
+                bt=None, packed_read="fused", moe_counts=False):
     """One token per sequence: tokens (B, 1) -> logits (B, 1, V), new cache.
 
     Position comes from the per-layer cache lengths (attention) or is
@@ -338,11 +432,14 @@ def decode_step(params: Params, cfg: ArchConfig, cache, tokens: jnp.ndarray,
     garbage row beyond its (unadvanced) length, so interleaved engines can
     step a partially-occupied batch without corrupting idle slots.  Only
     the attention families support it (an SSM/hybrid recurrent state
-    cannot skip a step).  ``t_bound`` statically bounds the attention
+    cannot skip a step); a MoE layer routes an inactive row to no expert.
+    ``t_bound`` statically bounds the attention
     cache read extent, ``bt`` pins the fused read's kv tile rows, and
     ``packed_read`` selects the quantised read ("fused" tiled
     nibble-decode vs the "unpack" full-container baseline) — see
-    :func:`repro.models.blocks.attn_apply`.
+    :func:`repro.models.blocks.attn_apply`.  ``moe_counts`` (MoE only)
+    adds a third result: the int32 pair (experts that received rows,
+    summed over MoE layers; routed rows).
     """
     h = params["embed"]["w"][tokens]
     B = h.shape[0]
@@ -351,62 +448,55 @@ def decode_step(params: Params, cfg: ArchConfig, cache, tokens: jnp.ndarray,
             f"decode_step active= mask is attention-only — the {cfg.family} "
             "family's recurrent state advances on every step and cannot "
             "mask a slot out")
-    if active is not None and cfg.family == "moe":
-        raise ValueError(
-            "decode_step active= mask is unsupported for moe — a masked "
-            "garbage row still competes for expert capacity and can "
-            "displace live tokens' routing")
+    counts = None
     if cfg.family in ("dense", "vlm", "moe"):
-        pos0 = cache["length"][0]  # (B,) same across layers
-        positions = pos0[:, None]
+        positions = _cache_length(cfg, cache)[:, None]
         nv = None if active is None else active.astype(jnp.int32)
+        valid = _valid_rows(nv, 1)
 
-        def body(h, xs):
-            p_layer, c_layer = xs
-            out, new_c = _dense_block(p_layer, cfg, h, positions, c_layer,
-                                      patterns=patterns, dispatch=dispatch,
-                                      n_valid=nv, t_bound=t_bound, bt=bt,
-                                      packed_read=packed_read)
-            return out, new_c
-    elif cfg.family == "ssm":
-        positions = None
+        def block(p_layer, hh, c_layer, layer):
+            return _dense_block(p_layer, cfg, hh, positions, c_layer,
+                                patterns=patterns, dispatch=dispatch,
+                                n_valid=nv, t_bound=t_bound, bt=bt,
+                                packed_read=packed_read, valid=valid,
+                                layer=layer)
 
-        def body(h, xs):
-            p_layer, c_layer = xs
-            out, new_c = _ssm_superblock(p_layer, cfg, h, c_layer)
-            return out, new_c
-    elif cfg.family == "hybrid":
-        pos0 = cache["attn"]["length"][0]
-        positions = pos0[:, None]
-        shared = params["shared_attn"]
-
-        def body(h, xs):
-            p_layer, c_layer = xs
-            out, new_c = _hybrid_superblock(p_layer, shared, cfg, h,
-                                            positions, c_layer,
-                                            patterns=patterns,
-                                            dispatch=dispatch,
-                                            t_bound=t_bound, bt=bt,
-                                            packed_read=packed_read)
-            return out, new_c
+        with jax.named_scope("layer_scan"):
+            h, new_cache, counts = _scan_groups(params, cfg, h, cache, block)
     else:
-        raise ValueError(cfg.family)
+        if cfg.family == "ssm":
+            def body(h, xs):
+                p_layer, c_layer = xs
+                out, new_c = _ssm_superblock(p_layer, cfg, h, c_layer)
+                return out, new_c
+        elif cfg.family == "hybrid":
+            pos0 = cache["attn"]["length"][0]
+            positions = pos0[:, None]
+            shared = params["shared_attn"]
 
-    with jax.named_scope("layer_scan"):
-        h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
-    h = norm_apply(cfg, params["final_norm"], h)
-    if cfg.tie_embeddings:
-        logits = jnp.dot(h, params["embed"]["w"].T.astype(h.dtype))
-    else:
-        logits = linear_apply(params["head"], h, pattern=(patterns or {}).get(
-            (cfg.d_model, cfg.vocab)), dispatch=dispatch)
+            def body(h, xs):
+                p_layer, c_layer = xs
+                out, new_c = _hybrid_superblock(p_layer, shared, cfg, h,
+                                                positions, c_layer,
+                                                patterns=patterns,
+                                                dispatch=dispatch,
+                                                t_bound=t_bound, bt=bt,
+                                                packed_read=packed_read)
+                return out, new_c
+        else:
+            raise ValueError(cfg.family)
+        with jax.named_scope("layer_scan"):
+            h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
+    logits = _head(params, cfg, h, patterns, dispatch)
+    if moe_counts:
+        return logits, new_cache, counts
     return logits, new_cache
 
 
 def prefill_step(params: Params, cfg: ArchConfig, cache,
                  tokens: jnp.ndarray, *, patterns=None, dispatch=None,
                  n_valid=None, t_bound=None, bt=None,
-                 packed_read="fused") -> Tuple[jnp.ndarray, Any]:
+                 packed_read="fused", moe_counts=False):
     """One prompt chunk per sequence: tokens (B, C) -> logits (B, C, V).
 
     Runs C prompt positions through the cached attention path in one
@@ -421,40 +511,37 @@ def prefill_step(params: Params, cfg: ArchConfig, cache,
 
     ``n_valid`` is an optional (B,) count of real rows in the chunk
     (ragged tails of a batched prompt); rows beyond it write garbage
-    past the advanced length (never read) and their logits are
-    meaningless.  The final real row's logits are the first generated
-    token's — no separate decode step is needed for it.
+    past the advanced length (never read), are routed to no expert in a
+    MoE layer, and their logits are meaningless.  The final real row's
+    logits are the first generated token's — no separate decode step is
+    needed for it.  ``moe_counts`` is :func:`decode_step`'s.
 
-    Only the attention-only families chunk: an SSM/hybrid state must
-    advance token-by-token, and a MoE chunk changes the router's static
-    expert capacity (a function of the token count), so a chunk would
-    route differently from the drip.
+    The attention families chunk (dense, vlm, moe: dropless routing does
+    not depend on the token count); an SSM/hybrid state must advance
+    token-by-token.
     """
-    if cfg.family not in ("dense", "vlm"):
+    if cfg.family not in ("dense", "vlm", "moe"):
         raise ValueError(
-            f"prefill_step supports the attention-only families "
-            f"('dense', 'vlm'), not {cfg.family!r} — serve other families "
-            "through per-token decode_step")
+            f"prefill_step supports the attention families "
+            f"('dense', 'vlm', 'moe'), not {cfg.family!r} — serve other "
+            "families through per-token decode_step")
     h = params["embed"]["w"][tokens]
     B, C = tokens.shape[:2]
-    pos0 = cache["length"][0]  # (B,) same across layers
+    pos0 = _cache_length(cfg, cache)  # (B,) same across layers
     positions = pos0[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     nv = None if n_valid is None else n_valid.astype(jnp.int32)
+    valid = _valid_rows(nv, C)
 
-    def body(h, xs):
-        p_layer, c_layer = xs
-        out, new_c = _dense_block(p_layer, cfg, h, positions, c_layer,
-                                  patterns=patterns, dispatch=dispatch,
-                                  n_valid=nv, t_bound=t_bound, bt=bt,
-                                  packed_read=packed_read)
-        return out, new_c
+    def block(p_layer, hh, c_layer, layer):
+        return _dense_block(p_layer, cfg, hh, positions, c_layer,
+                            patterns=patterns, dispatch=dispatch,
+                            n_valid=nv, t_bound=t_bound, bt=bt,
+                            packed_read=packed_read, valid=valid,
+                            layer=layer)
 
     with jax.named_scope("layer_scan"):
-        h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
-    h = norm_apply(cfg, params["final_norm"], h)
-    if cfg.tie_embeddings:
-        logits = jnp.dot(h, params["embed"]["w"].T.astype(h.dtype))
-    else:
-        logits = linear_apply(params["head"], h, pattern=(patterns or {}).get(
-            (cfg.d_model, cfg.vocab)), dispatch=dispatch)
+        h, new_cache, counts = _scan_groups(params, cfg, h, cache, block)
+    logits = _head(params, cfg, h, patterns, dispatch)
+    if moe_counts:
+        return logits, new_cache, counts
     return logits, new_cache

@@ -2,7 +2,7 @@
 
 Two execution modes, chosen per model family at construction:
 
-* **Chunked interleave** (attention-only families: dense, vlm) — prompts
+* **Chunked interleave** (the attention families: dense, vlm, moe) — prompts
   run through :func:`repro.models.model.prefill_step` in fixed-size
   chunks (``prefill_chunk`` tokens, the engine's per-step token budget),
   quantise-packing each chunk's K/V vectorised and writing straight into
@@ -16,11 +16,12 @@ Two execution modes, chosen per model family at construction:
   whose chunk schedule cannot fit the cache (``ceil(P/C)·C > max_len``)
   falls back to the legacy token drip for that request only.
 
-* **Legacy drip** (moe / ssm / hybrid) — exactly one token per active
-  slot per step through the jitted ``decode_step``, prompts fed one
-  token at a time.  Recurrent state must advance token-by-token and a
-  MoE router's static capacity depends on the token count, so these
-  families keep the original path verbatim.
+* **Legacy drip** (ssm / hybrid) — exactly one token per active slot per
+  step through the jitted ``decode_step``, prompts fed one token at a
+  time.  Recurrent state must advance token-by-token, so these families
+  keep the original path verbatim.  (A MoE layer routes dropless: a row's
+  experts do not depend on how many rows share the step, and a masked
+  row goes to no expert, so MoE models chunk like the dense ones.)
 
 Prefill operates on a gathered batch-of-one view of the slot's cache
 (``dynamic_slice_in_dim`` over the explicit batch-axis spec), so a chunk
@@ -54,7 +55,12 @@ programs are named functions, so the device trace shows
 ``jit_engine_prefill``, ``jit_engine_decode`` and
 ``jit_engine_decode_legacy``; inside them the cache path carries the
 scopes ``slot_gather`` / ``slot_scatter``, ``layer_scan``, ``kv_insert``
-and ``kv_extent``.
+and ``kv_extent``.  A MoE model's step programs also return, in the same
+fetch as their tokens, two int32 counters: the experts that received
+rows, summed over MoE layers, and the routed (row, expert) assignments;
+``stats()`` lists them per step program (``moe_experts_hit``,
+``moe_routed_rows``), and each step records them in an ``engine.moe``
+span (args ``phase``, ``experts_hit``, ``routed_rows``) after its fetch.
 
 This is the same ``decode_step`` the dry run lowers for the 256-chip
 mesh.  ``chip_smoke.py`` serves a compressed llama3.2-1b through this
@@ -77,7 +83,7 @@ from ..models.model import (cache_batch_axes, decode_step, init_cache,
                             prefill_step)
 
 # families whose prompts run through the chunked prefill path
-_CHUNKED_FAMILIES = ("dense", "vlm")
+_CHUNKED_FAMILIES = ("dense", "vlm", "moe")
 
 
 @dataclasses.dataclass
@@ -169,6 +175,7 @@ class ServeEngine:
         self.prefill_chunk = max(1, int(prefill_chunk))
         self.packed_read = packed_read
         self._chunked = cfg.family in _CHUNKED_FAMILIES
+        self._moe = cfg.family == "moe"
         # kv tile rows of the fused read — resolved ONCE (tuned entry when
         # available, default otherwise) and pinned, so every step of a
         # sequence walks the same tiles whatever its extent bucket
@@ -200,6 +207,8 @@ class ServeEngine:
         self._stats = {"prefill_steps": 0, "decode_steps": 0,
                        "prefill_tokens": 0, "decode_tokens": 0,
                        "prefill_ms": [], "decode_ms": []}
+        if self._moe:
+            self._stats.update(moe_experts_hit=[], moe_routed_rows=[])
         self._decode_fns: Dict[int, object] = {}   # t_bound -> jitted step
         self._prefill_fns: Dict[int, object] = {}
 
@@ -235,10 +244,8 @@ class ServeEngine:
         """Per-phase counters: step counts, token counts, and per-step
         wall-clock (ms) lists — benches/tests read phase timings here
         instead of re-deriving them from the outside."""
-        out = dict(self._stats)
-        out["prefill_ms"] = list(self._stats["prefill_ms"])
-        out["decode_ms"] = list(self._stats["decode_ms"])
-        return out
+        return {k: list(v) if isinstance(v, list) else v
+                for k, v in self._stats.items()}
 
     def tokens_processed(self) -> int:
         """Total tokens pushed through the model (prefill + decode) —
@@ -314,6 +321,16 @@ class ServeEngine:
                 return decode_step(p, cfg, c, t, patterns=patterns,
                                    dispatch=dispatch, active=a, t_bound=tb,
                                    bt=bt, packed_read=pr)
+
+            if self._moe:
+                def engine_decode(p, c, t, a):  # noqa: F811
+                    # the tokens and the MoE counters in one int32 fetch
+                    logits, c, n = decode_step(
+                        p, cfg, c, t, patterns=patterns, dispatch=dispatch,
+                        active=a, t_bound=tb, bt=bt, packed_read=pr,
+                        moe_counts=True)
+                    tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                    return jnp.concatenate([tok, n]), c
             fn = jax.jit(engine_decode)
             self._decode_fns[tb] = fn
         return fn
@@ -328,18 +345,24 @@ class ServeEngine:
         if fn is None:
             cfg, patterns, dispatch = self.cfg, self.patterns, self.dispatch
             bt, pr, axes = self._bt, self.packed_read, self._batch_axes
+            moe = self._moe
 
             def engine_prefill(p, cache, slot, toks, nv):
                 with jax.named_scope("slot_gather"):
                     sub = jax.tree_util.tree_map(
                         lambda leaf, ax: jax.lax.dynamic_slice_in_dim(
                             leaf, slot, 1, axis=ax), cache, axes)
-                logits, sub = prefill_step(
+                out = prefill_step(
                     p, cfg, sub, toks, patterns=patterns, dispatch=dispatch,
-                    n_valid=nv, t_bound=tb, bt=bt, packed_read=pr)
+                    n_valid=nv, t_bound=tb, bt=bt, packed_read=pr,
+                    moe_counts=moe)
+                logits, sub = out[:2]
                 # the host needs one row's argmax, so only that token
-                # leaves the device
+                # leaves the device (with a MoE model's counters)
                 tok = jnp.argmax(logits[0, nv[0] - 1], axis=-1)
+                if moe:
+                    tok = jnp.concatenate([tok.astype(jnp.int32)[None],
+                                           out[2]])
                 with jax.named_scope("slot_scatter"):
                     cache = jax.tree_util.tree_map(
                         lambda leaf, s, ax:
@@ -389,8 +412,11 @@ class ServeEngine:
             with TraceAnnotation("engine.prefill.wait"):
                 jax.block_until_ready(tok)
             with TraceAnnotation("engine.prefill.fetch", bytes=tok.nbytes):
-                nxt = int(tok)
+                got = np.asarray(tok)
                 now = time.perf_counter()
+            nxt = int(got.reshape(-1)[0])
+            if self._moe:
+                self._note_moe("prefill", got[1:])
             self._stats["prefill_steps"] += 1
             self._stats["prefill_tokens"] += nv
             self._stats["prefill_ms"].append((now - t0) * 1e3)
@@ -406,20 +432,34 @@ class ServeEngine:
                     self.remaining[slot] -= 1
                 self._finish(slot, now)
 
+    def _note_moe(self, phase: str, counts) -> None:
+        """Record a MoE step program's (experts hit, routed rows)."""
+        hit, rows = int(counts[0]), int(counts[1])
+        self._stats["moe_experts_hit"].append(hit)
+        self._stats["moe_routed_rows"].append(rows)
+        with TraceAnnotation("engine.moe", phase=phase, experts_hit=hit,
+                             routed_rows=rows):
+            pass
+
     def _decode_tokens(self, fn, *args):
         """Run one decode step program on the host ``args`` and bring the
         greedy next token of every slot to the host."""
         with TraceAnnotation("engine.decode.dispatch"):
-            logits, self.cache = fn(self.params, self.cache,
-                                    *map(jnp.asarray, args))
+            out, self.cache = fn(self.params, self.cache,
+                                 *map(jnp.asarray, args))
             # queued behind the step, and the transfer behind it: no host
-            # round trip in between
-            tok = jnp.argmax(logits[:, 0], axis=-1)
+            # round trip in between (a MoE step program took its argmax
+            # itself, beside its counters)
+            tok = out if self._moe else jnp.argmax(out[:, 0], axis=-1)
             tok.copy_to_host_async()
         with TraceAnnotation("engine.decode.wait"):
             jax.block_until_ready(tok)
         with TraceAnnotation("engine.decode.fetch", bytes=tok.nbytes):
-            return np.asarray(tok, np.int32)
+            got = np.asarray(tok, np.int32)
+        if self._moe:
+            self._note_moe("decode", got[self.slots:])
+            return got[:self.slots]
+        return got
 
     def _step_decode(self, dec_slots: List[int]):
         """One generated (or dripped prompt) token for every decoding

@@ -124,6 +124,59 @@ def test_block_sparse_matmul_compiles_at_llama_widths(one_chip, container, M,
     _assert_kernel(compiled, f"block_sparse_matmul[{container}]")
 
 
+# DeepSeek-V2-Lite widths: hidden 2048, 64 experts of 1408, latent rows of
+# 512 + 64 codes (288 bytes, padded to 384)
+@pytest.mark.parametrize("K,N", [(2048, 1408), (1408, 2048)])
+@pytest.mark.parametrize("rows", [96, 768])        # decode, 128-row chunk
+def test_grouped_quant_matmul_compiles_at_deepseek_widths(one_chip, K, N,
+                                                          rows):
+    from repro.kernels.grouped_matmul.kernel import (TM_DEFAULT,
+                                                      grouped_quant_matmul)
+    E, tm = 64, TM_DEFAULT
+    m_pad = -(-(rows + min(E, rows) * (tm - 1)) // tm) * tm
+    assert disp.grouped_kernel_eligible(K, N)
+    compiled = _compile(
+        lambda x, w, s, t, n, ly: grouped_quant_matmul(x, w, s, t, n, ly,
+                                                       tm=tm),
+        _spec(one_chip, (m_pad, K), jnp.bfloat16),
+        _spec(one_chip, (5, E, K // 2, N), jnp.uint8),
+        _spec(one_chip, (5, E, N), jnp.float32),
+        _spec(one_chip, (m_pad // tm,), jnp.int32),
+        _spec(one_chip, (1,), jnp.int32),
+        _spec(one_chip, (1,), jnp.int32))
+    _assert_kernel(compiled, "grouped_quant_matmul")
+
+
+def test_latent_decode_attention_compiles(one_chip):
+    from repro.kernels.flash_attention.latent_decode import (
+        latent_decode_attention)
+    bt = disp.ATTN_BT_DEFAULT
+    compiled = _compile(
+        lambda q, c, s, n: latent_decode_attention(q, c, s, n, scale=0.1,
+                                                   bt=bt),
+        _spec(one_chip, (16, 1, 16, 768), jnp.float32),
+        _spec(one_chip, (16, 8192, 384), jnp.uint8),
+        _spec(one_chip, (16, 8192), jnp.float32),
+        _spec(one_chip, (16,), jnp.int32))
+    _assert_kernel(compiled, "latent_decode_attention")
+
+
+@pytest.mark.parametrize("N", [640, 128])     # MLA down-proj, router, padded
+def test_quant_matmul_int4x2_compiles_for_a_lane_padded_n(one_chip, N):
+    """The MLA down projection (576) and the router (64) reach the kernel
+    zero-padded to whole lanes by ``compile_model``, on its default
+    tiles."""
+    assert disp.quant_kernel_eligible(2048, N)
+    bk, bn = disp.quant_tiles(2048, N)
+    compiled = _compile(
+        lambda x, w, s: quant_matmul(x, w, s, bm=16, bn=bn, bk=bk,
+                                     packed="int4x2"),
+        _spec(one_chip, (16, 2048), jnp.bfloat16),
+        _spec(one_chip, (1024, N), jnp.uint8),
+        _spec(one_chip, (N,), jnp.float32))
+    _assert_kernel(compiled, "quant_matmul[int4x2, lane-padded N]")
+
+
 @pytest.mark.parametrize("q_dtype", [jnp.bfloat16, jnp.float32])
 def test_packed_decode_attention_compiles(one_chip, q_dtype):
     bt = disp.ATTN_BT_DEFAULT

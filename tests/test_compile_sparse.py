@@ -338,16 +338,21 @@ def test_hybrid_blocks_reported_dense():
     assert 0.0 < cm.compression < 5.0  # diluted by the dense SSM bulk
 
 
+_ROUTED = ("router", "eg", "eu", "ed")
+
+
 def test_moe_expert_stacks_reported_dense():
-    """Routed experts stay dense (data-dependent dispatch) but must appear
-    in the report so compression covers the whole model."""
+    """Routed experts and the router kept dense by the rules stay raw and
+    appear as dense rows, so compression covers the whole model."""
     cfg = _cfg(family="moe", n_experts=4, top_k=2, d_expert=64,
                n_shared_experts=1)
     params = init_params(jax.random.PRNGKey(2), cfg)
-    cm = compile_model(params, cfg, rules=_rules())
+    cm = compile_model(params, cfg, rules=_rules(
+        policies={k: "dense" for k in _ROUTED}))
     rep = {r.name: r for r in cm.report}
-    for k in ("router", "eg", "eu", "ed"):
+    for k in _ROUTED:
         assert rep[f"blocks/moe/{k}"].policy == "dense"
+        assert "w" in cm.params["blocks"]["moe"][k]
     expert_bytes = sum(rep[f"blocks/moe/{k}"].dense_bytes
                        for k in ("eg", "eu", "ed"))
     assert expert_bytes > 0 and cm.dense_bytes > expert_bytes
@@ -356,6 +361,36 @@ def test_moe_expert_stacks_reported_dense():
     lowered_ratio = (sum(r.dense_bytes for r in lowered_only)
                      / sum(r.compressed_bytes for r in lowered_only))
     assert cm.compression < lowered_ratio
+
+
+def test_moe_expert_stacks_reported_quant():
+    """Under ``quant`` the expert stacks lower to the int4x2 container
+    per (expert, output column) and are reported as L x E quant matrices
+    with their real bytes; the router lowers like any linear; the
+    decompressed experts are each expert's own int4 quantisation."""
+    from repro.core.quant import quantize
+    cfg = _cfg(family="moe", n_experts=4, top_k=2, d_expert=64,
+               n_shared_experts=1)
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    cm = compile_model(params, cfg, rules=_rules(
+        quant_bits=4, policies={k: "quant" for k in _ROUTED}))
+    rep = {r.name: r for r in cm.report}
+    L, E, D, F = cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_expert
+    for k, (K, N) in (("eg", (D, F)), ("eu", (D, F)), ("ed", (F, D))):
+        r = rep[f"blocks/moe/{k}"]
+        assert (r.policy, r.kind, r.shape, r.n_layers) == (
+            "quant", "expert", (K, N), L * E)
+        leaf = cm.params["blocks"]["moe"][k]
+        assert leaf["w_qp"].shape == (L, E, K // 2, N)
+        assert leaf["w_s"].shape == (L, E, N)
+        assert r.container_bytes == L * E * (K * N // 2 + 4 * N)
+    assert rep["blocks/moe/router"].policy == "quant"
+    dense = decompress_model(cm)
+    w = np.asarray(params["blocks"]["moe"]["ed"]["w"], np.float32)[1, 2]
+    want = np.asarray(quantize(w, 4, axis=1).values, np.float32) * \
+        np.asarray(quantize(w, 4, axis=1).scales)[None, :]
+    got = np.asarray(dense["blocks"]["moe"]["ed"]["w"], np.float32)[1, 2]
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
 
 
 def test_recompile_rejected_with_clear_error():

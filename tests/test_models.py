@@ -28,8 +28,7 @@ FAMILIES = {
     "encoder": tiny("encoder", causal=False, norm="ln", act="gelu",
                     frontend="frame"),
     "vlm": tiny("vlm", frontend="patch", n_prefix_tokens=4),
-    "moe": tiny("moe", n_experts=8, n_shared_experts=1, top_k=2, d_expert=32,
-                capacity_factor=100.0),
+    "moe": tiny("moe", n_experts=8, n_shared_experts=1, top_k=2, d_expert=32),
     "ssm": tiny("ssm", slstm_every=2, n_kv_heads=4, d_ff=0, d_inner=128),
     "hybrid": tiny("hybrid", attn_every=2, ssm_state=16, n_kv_heads=4,
                    d_ff=0, d_inner=128),

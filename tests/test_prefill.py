@@ -157,9 +157,11 @@ def test_fused_kernel_bitwise_matches_twin(bt):
 
 
 def test_prefill_step_rejects_unsupported_family():
-    """Recurrent/capacity-coupled families cannot skip tokens — the
-    chunked entry point must refuse them loudly."""
-    cfg = dataclasses.replace(_cfg(), family="moe", n_experts=4, top_k=2)
+    """Recurrent families cannot skip tokens — the chunked entry point
+    must refuse them loudly (a MoE chunk is accepted: dropless routing
+    does not depend on the token count)."""
+    from repro.configs import reduced_config
+    cfg = reduced_config("xlstm-1.3b")
     params = init_params(jax.random.PRNGKey(0), cfg)
     cache = init_cache(cfg, 1, 16)
     with pytest.raises(ValueError, match="prefill_step supports"):
@@ -169,8 +171,9 @@ def test_prefill_step_rejects_unsupported_family():
 
 def test_decode_step_rejects_active_mask_for_stateful_families():
     """`active` masking relies on garbage rows being overwritten in the
-    KV cache; recurrent state and MoE capacity have no such escape."""
-    cfg = dataclasses.replace(_cfg(), family="moe", n_experts=4, top_k=2)
+    KV cache; a recurrent state has no such escape."""
+    from repro.configs import reduced_config
+    cfg = reduced_config("xlstm-1.3b")
     params = init_params(jax.random.PRNGKey(0), cfg)
     cache = init_cache(cfg, 2, 16)
     with pytest.raises(ValueError, match="active"):

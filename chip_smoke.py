@@ -160,11 +160,14 @@ def main() -> None:
     if missing:
         fail(f"compiled decode step lacks kernels {missing}")
     print(f"decode step kernels present: {', '.join(KERNELS)}")
-    jax.block_until_ready(step_fn(engine.params, engine.cache, toks, active))
+    # the step program takes the cache donated: thread it through
+    out, engine.cache = step_fn(engine.params, engine.cache, toks, active)
+    jax.block_until_ready(out)
     n_steady = 20
     t0 = time.perf_counter()
     for _ in range(n_steady):
-        out = step_fn(engine.params, engine.cache, toks, active)
+        out, engine.cache = step_fn(engine.params, engine.cache, toks,
+                                    active)
     jax.block_until_ready(out)
     steady_ms = (time.perf_counter() - t0) / n_steady * 1e3
     print(f"steady decode step ({SLOTS} slots, {MAX_LEN}-position read): "

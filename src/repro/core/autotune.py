@@ -559,6 +559,7 @@ def autotune_attn(
     """
     from ..kernels.flash_attention.decode_packed import (
         packed_decode_attention,
+        scale_rows,
         tiled_packed_attention,
     )
     from .quant import pack_int4
@@ -575,13 +576,15 @@ def autotune_attn(
     interpret = not on_tpu
     measurable_pallas = on_tpu or options.measure_interpret
 
+    # one layer of the serving cache, in its stored layout
     rng = np.random.default_rng(seed)
-    codes_k = rng.integers(-7, 8, size=(B, T, Hkv, Dh)).astype(np.int8)
-    codes_v = rng.integers(-7, 8, size=(B, T, Hkv, Dh)).astype(np.int8)
-    k_p = pack_int4(jnp.asarray(codes_k), axis=-1)
-    v_p = pack_int4(jnp.asarray(codes_v), axis=-1)
-    k_s = jnp.asarray(rng.uniform(0.01, 0.2, (B, T, Hkv)), jnp.float32)
-    v_s = jnp.asarray(rng.uniform(0.01, 0.2, (B, T, Hkv)), jnp.float32)
+    codes_k = rng.integers(-7, 8, size=(1, B, T, Hkv, Dh)).astype(np.int8)
+    codes_v = rng.integers(-7, 8, size=(1, B, T, Hkv, Dh)).astype(np.int8)
+    k_p = pack_int4(jnp.asarray(codes_k), axis=-1).reshape(1, B, T, -1)
+    v_p = pack_int4(jnp.asarray(codes_v), axis=-1).reshape(1, B, T, -1)
+    Hs = scale_rows(Hkv)
+    k_s = jnp.asarray(rng.uniform(0.01, 0.2, (1, B, Hs, T)), jnp.float32)
+    v_s = jnp.asarray(rng.uniform(0.01, 0.2, (1, B, Hs, T)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), x_dtype)
     lengths = jnp.full((B, 1), T, jnp.int32)
 
@@ -606,22 +609,20 @@ def autotune_attn(
 
         def twin(bt=bt):
             return [tiled_packed_attention(
-                q, k_p[:, :e], v_p[:, :e], k_s[:, :e], v_s[:, :e],
-                jnp.minimum(lengths, e), bt=bt, packed=True)
+                q, k_p, v_p, k_s, v_s, jnp.minimum(lengths, e), 0,
+                t_bound=e, bt=bt, packed=True)
                 for e in extents]
 
         us = _time_fn(twin, options.iters, options.warmup)
         measured.append((TunedConfig(use_pallas=False, bm=bt), us))
         n_timed += 1
         from .dispatch import attn_packed_eligible
-        if measurable_pallas and all(attn_packed_eligible(Dh, bt, e)
-                                     for e in extents):
+        if measurable_pallas and attn_packed_eligible(Dh, bt, T):
 
             def kern(bt=bt):
                 return [packed_decode_attention(
-                    q, k_p[:, :e], v_p[:, :e], k_s[:, :e], v_s[:, :e],
-                    jnp.minimum(lengths[:, 0], e), bt=bt,
-                    interpret=interpret)
+                    q, k_p, v_p, k_s, v_s, jnp.minimum(lengths[:, 0], e), 0,
+                    t_bound=e, bt=bt, interpret=interpret)
                     for e in extents]
 
             us = _time_fn(kern, options.iters, options.warmup)

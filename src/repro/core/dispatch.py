@@ -300,13 +300,15 @@ ATTN_BT_DEFAULT = 128
 
 
 def attn_packed_eligible(Dh: int, bt: int, T: int) -> bool:
-    """Does the chip's compiler accept the packed-decode attention kernel?
+    """Does the chip's compiler accept the packed-decode attention kernel
+    on a ``T``-position cache buffer?
 
-    The per-row scales ride as (1, Hkv, bt) blocks of a head-major
-    (B, Hkv, T_pad) view: bt obeys the lane rule unless one tile covers
-    the whole extent.  The head dim must be even (two codes per byte, no pad
-    nibble)."""
-    return Dh % 2 == 0 and (bt % 128 == 0 or T <= bt)
+    The per-row scales ride as (Hkv, bt) blocks of the head-major
+    (L, B, Hkv, T) leaf, so bt obeys the lane rule, and its tiles must
+    divide the buffer the kernel reads in place (nothing is padded),
+    unless one tile covers the whole buffer.  The head dim must be even
+    (two codes per byte, no pad nibble)."""
+    return Dh % 2 == 0 and (T <= bt or (bt % 128 == 0 and T % bt == 0))
 
 
 class DispatchFallbackWarning(UserWarning):
@@ -644,19 +646,22 @@ def payload_dispatch(
 
 def attn_packed_dispatch(
     q: jnp.ndarray,        # (B, C, H, Dh) — decode C=1, prefill chunk C>1
-    k_c: jnp.ndarray,      # packed uint8 / int8 codes, (B, T, Hkv, ·)
+    k_c: jnp.ndarray,      # packed uint8 / int8 codes, (L, B, T, Hkv·W)
     v_c: jnp.ndarray,
-    k_s: jnp.ndarray,      # (B, T, Hkv) f32 per-row scales
+    k_s: jnp.ndarray,      # (L, B, Hs, T) f32 per-row scales, head-major
     v_s: jnp.ndarray,
     lengths: jnp.ndarray,  # (B, C) live length per query row
+    layer,                 # int32 scalar: the stack's layer to read
     *,
     packed: bool,
     dispatch: Union[None, str, DispatchConfig] = None,
     bt: Optional[int] = None,
+    t_bound: Optional[int] = None,
     leaf: Optional[str] = None,
 ) -> jnp.ndarray:
     """The quantised-KV-cache attention read: codes → attention output,
-    without ever materialising the dequantised cache.
+    without ever materialising the dequantised cache, read from the
+    stacked cache at ``layer`` to the static extent ``t_bound``.
 
     The Pallas leg (:func:`repro.kernels.flash_attention.decode_packed.
     packed_decode_attention`) streams the packed uint8 tiles HBM→VMEM
@@ -678,8 +683,9 @@ def attn_packed_dispatch(
     )
     cfg = resolve(dispatch)
     B, C, H, Dh = q.shape
-    T = k_s.shape[1]
-    entry = _tuned_entry(cfg, "attn_packed", M=B, K=T, N=H * Dh,
+    T = k_s.shape[-1]
+    extent = T if t_bound is None else min(t_bound, T)
+    entry = _tuned_entry(cfg, "attn_packed", M=B, K=extent, N=H * Dh,
                          x_dtype=q.dtype, leaf=leaf)
     if bt is None:
         bt = (entry.bm if entry is not None and entry.bm else None) \
@@ -691,10 +697,11 @@ def attn_packed_dispatch(
             cfg, entry, attn_packed_eligible(Dh, bt, T),
             leaf=leaf or "attn.kv", predicate="attn_packed_eligible"):
         return packed_decode_attention(q, k_c, v_c, k_s, v_s,
-                                       lengths[:, 0], bt=bt,
+                                       lengths[:, 0], layer,
+                                       t_bound=t_bound, bt=bt,
                                        interpret=cfg.run_interpret)
-    return tiled_packed_attention(q, k_c, v_c, k_s, v_s, lengths,
-                                  bt=bt, packed=packed)
+    return tiled_packed_attention(q, k_c, v_c, k_s, v_s, lengths, layer,
+                                  t_bound=t_bound, bt=bt, packed=packed)
 
 
 # ------------------------------------------- grouped (MoE expert) matmul
@@ -754,16 +761,19 @@ def grouped_dispatch(
 
 def latent_attn_dispatch(
     q: jnp.ndarray,        # (B, C, H, W) absorbed queries, zero past r + dr
-    c_p: jnp.ndarray,      # (B, T, W/2) uint8 packed latent rows
-    c_s: jnp.ndarray,      # (B, T) f32 row scales
+    c_p: jnp.ndarray,      # (L, B, T, W/2) uint8 packed latent rows
+    c_s: jnp.ndarray,      # (L, B, 1, T) f32 row scales
     lengths: jnp.ndarray,  # (B, C) live length per query row
+    layer,                 # int32 scalar: the stack's layer to read
     *,
     scale: float,
     dispatch: Union[None, str, DispatchConfig] = None,
     bt: Optional[int] = None,
+    t_bound: Optional[int] = None,
     leaf: Optional[str] = None,
 ) -> jnp.ndarray:
-    """The latent cache read, f32 (B, C, H, W): the Pallas kernel
+    """The latent cache read, f32 (B, C, H, W), from the stacked cache at
+    ``layer`` to the static extent ``t_bound``: the Pallas kernel
     (:func:`repro.kernels.flash_attention.latent_decode.
     latent_decode_attention`) on decode reads (C == 1), its tiled jnp
     twin on prefill chunks and wherever the kernel does not apply."""
@@ -773,15 +783,16 @@ def latent_attn_dispatch(
     )
     cfg = resolve(dispatch)
     B, C, H, W = q.shape
-    T = c_s.shape[1]
+    T = c_s.shape[-1]
     bt = bt or ATTN_BT_DEFAULT
     if C == 1 and _use_pallas(
             cfg, attn_packed_eligible(W, bt, T), leaf=leaf or "attn.latent",
             predicate="attn_packed_eligible"):
-        return latent_decode_attention(q, c_p, c_s, lengths[:, 0],
-                                       scale=scale, bt=bt,
+        return latent_decode_attention(q, c_p, c_s, lengths[:, 0], layer,
+                                       scale=scale, t_bound=t_bound, bt=bt,
                                        interpret=cfg.run_interpret)
-    return tiled_latent_attention(q, c_p, c_s, lengths, scale=scale, bt=bt)
+    return tiled_latent_attention(q, c_p, c_s, lengths, layer, scale=scale,
+                                  t_bound=t_bound, bt=bt)
 
 
 # ------------------------------------------------------------ convolutions

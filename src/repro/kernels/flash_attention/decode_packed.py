@@ -13,9 +13,15 @@ The unpacked f32 cache copy never exists.
 Two entry points:
 
 * :func:`packed_decode_attention` — the Pallas kernel, single query row
-  per slot (decode).  Grid ``(B, n_t)`` with the kv-tile index
-  innermost: each step streams one contiguous ``(bt, Hkv·Dh/2)`` tile
-  of the slot's packed rows and walks its kv heads statically.  Each
+  per slot (decode).  It reads the serving cache where it lies: the
+  whole layer stack of packed rows, ``(L, B, T, Hkv·Dh/2)`` uint8, stays
+  in HBM, and the layer is a scalar-prefetched index, so a layer loop
+  hands it the stack without slicing a layer out (a Pallas call takes no
+  fused slice: a sliced operand is a copy).  Grid ``(B, n_t)`` with the
+  kv-tile index innermost, ``n_t = ceil(t_bound / bt)`` for the static
+  read extent ``t_bound``: each step streams one contiguous
+  ``(bt, Hkv·Dh/2)`` tile of the slot's packed rows and walks its kv
+  heads statically.  Each
   head's bytes decode into an even-dim and an odd-dim code plane (the
   exact int4 codes, in 32-bit lanes) and meet ``q`` split the same way,
   so the kernel never interleaves lanes; the wrapper re-interleaves the
@@ -24,16 +30,19 @@ Two entry points:
   the output is emitted at the last tile.  Dead tiles (``it·bt >= L``)
   are skipped entirely — no DMA is issued and the softmax state is
   untouched, so results are invariant to the cache extent at fixed
-  ``bt``.  The per-row scales ride as (Hkv, bt) blocks of a head-major
-  ``(B, Hkv, T)`` view, so ``bt`` must be a multiple of 128 unless one
-  tile covers the whole extent.
+  ``bt``.  The per-row scales are stored head-major, ``(L, B, Hkv, T)``
+  f32, and ride as (Hkv, bt) blocks at the layer's index (a dead tile
+  keeps the last live tile's block, so it issues no copy), so ``bt``
+  must be a multiple of 128 dividing ``T`` unless one tile covers the
+  whole buffer.
 * :func:`tiled_packed_attention` — the jnp twin, additionally batched
   over a chunk axis C with per-row lengths (the prefill read).  It walks
   the same tiles in the same order with the same masking and dead-tile
-  skip; it matches the kernel to float tolerance (a Mosaic kernel and
+  skip, reading each tile of the same stored layout at the layer's
+  index; it matches the kernel to float tolerance (a Mosaic kernel and
   its XLA twin do not promise bitwise equality), asserted by tests on
   every dispatch leg.  With ``packed=False`` the twin reads int8 codes
-  directly (the unpacked ``int4`` cache mode).
+  ``(L, B, T, Hkv·Dh)`` directly (the unpacked ``int4`` cache mode).
 
 Both paths compute f32 straight from codes: the row scales multiply the
 scores (``q·codes_k · s_k``) and the probabilities (``p · s_v``) rather
@@ -53,7 +62,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.quant import unpack_int4
 from ..sparse_matmul.kernel import field_planes
 
-__all__ = ["packed_decode_attention", "tiled_packed_attention"]
+__all__ = ["packed_decode_attention", "tiled_packed_attention",
+           "scale_rows"]
 
 NEG_INF = -1e30
 
@@ -61,20 +71,24 @@ NEG_INF = -1e30
 _QK_DIMS = (((1,), (1,)), ((), ()))
 
 
-def _decode_kernel(len_ref, qlo_ref, qhi_ref, ks_ref, vs_ref, kp_hbm, vp_hbm,
-                   olo_ref, ohi_ref, kbuf, vbuf, ksem, vsem, m_ref, l_ref,
-                   acc_lo, acc_hi, *, bt: int, n_t: int, Hkv: int, Dhp: int):
+def _decode_kernel(len_ref, layer_ref, qlo_ref, qhi_ref, ks_ref, vs_ref,
+                   kp_hbm, vp_hbm, olo_ref, ohi_ref, kbuf, vbuf, ksem, vsem,
+                   m_ref, l_ref, acc_lo, acc_hi, *, bt: int, n_t: int,
+                   Hkv: int, Dhp: int):
     b = pl.program_id(0)
     it = pl.program_id(1)
+    layer = layer_ref[0]
     length = len_ref[b]
 
     def _copies(j, slot):
         # one contiguous (bt, Hkv*Dh/2) row tile per slot: every kv head
         # of those positions (a single-head window would slice a tiled dim)
-        return (pltpu.make_async_copy(kp_hbm.at[b, pl.ds(j * bt, bt)],
-                                      kbuf.at[slot], ksem.at[slot]),
-                pltpu.make_async_copy(vp_hbm.at[b, pl.ds(j * bt, bt)],
-                                      vbuf.at[slot], vsem.at[slot]))
+        return (pltpu.make_async_copy(
+                    kp_hbm.at[layer, b, pl.ds(j * bt, bt)], kbuf.at[slot],
+                    ksem.at[slot]),
+                pltpu.make_async_copy(
+                    vp_hbm.at[layer, b, pl.ds(j * bt, bt)], vbuf.at[slot],
+                    vsem.at[slot]))
 
     @pl.when(it == 0)
     def _init():
@@ -138,92 +152,125 @@ def _decode_kernel(len_ref, qlo_ref, qhi_ref, ks_ref, vs_ref, kp_hbm, vp_hbm,
         ohi_ref[0] = acc_hi[...] * inv
 
 
-def _pad_t(arr, t_pad):
-    if arr.shape[1] == t_pad:
-        return arr
-    pad = [(0, 0)] * arr.ndim
-    pad[1] = (0, t_pad - arr.shape[1])
-    return jnp.pad(arr, pad)
+def scale_rows(Hkv: int) -> int:
+    """Rows of a stored head-major scale leaf ``(L, B, rows, T)`` for
+    ``Hkv`` kv heads: the heads, rounded up to the f32 sublane tile (8)
+    unless they fill a smaller tile (1, 2 or 4).  The chip's default
+    layout of an f32 array whose second-minor dim pads its tile moves the
+    slot axis there instead, and the kernel's row-major operand would
+    then cost a relayout copy of the whole stack a call; the rows past
+    ``Hkv`` stay zero and are never read."""
+    return Hkv if Hkv in (1, 2, 4) or Hkv % 8 == 0 else -(-Hkv // 8) * 8
 
 
-@functools.partial(jax.jit, static_argnames=("bt", "interpret"))
+def read_tiles(T: int, t_bound, bt: int):
+    """(tile rows, tiles) of a read of a ``T``-position cache buffer to
+    the static extent ``t_bound`` (all of it where None): one tile of the
+    whole buffer where it is shorter than ``bt``."""
+    extent = T if t_bound is None else max(1, min(t_bound, T))
+    bt = min(bt, T)
+    return bt, -(-extent // bt)
+
+
+def kernel_tiles(T: int, t_bound, bt: int):
+    """:func:`read_tiles` for a kernel, which reads the stored buffer in
+    place and never pads it: refuses tiles that would run past it."""
+    bt, n_t = read_tiles(T, t_bound, bt)
+    if n_t * bt > T:
+        raise ValueError(
+            f"a {bt}-row kv tile does not divide the {T}-position cache "
+            f"read to extent {t_bound}: the last tile would run past it")
+    return bt, n_t
+
+
+def last_live_tile(length, bt: int):
+    """The tile that holds a slot's last live row (tile 0 when empty)."""
+    return jnp.maximum(length - 1, 0) // bt
+
+
+@functools.partial(jax.jit, static_argnames=("t_bound", "bt", "interpret"))
 def packed_decode_attention(
-    q: jnp.ndarray,     # (B, 1, H, Dh)
-    k_p: jnp.ndarray,   # (B, T, Hkv, Dh/2) uint8 packed codes
-    v_p: jnp.ndarray,   # (B, T, Hkv, Dh/2) uint8
-    k_s: jnp.ndarray,   # (B, T, Hkv) f32 per-row scales
-    v_s: jnp.ndarray,   # (B, T, Hkv) f32
+    q: jnp.ndarray,       # (B, 1, H, Dh)
+    k_p: jnp.ndarray,     # (L, B, T, Hkv*Dh/2) uint8 packed codes, stacked
+    v_p: jnp.ndarray,     # (L, B, T, Hkv*Dh/2) uint8
+    k_s: jnp.ndarray,     # (L, B, Hs, T) f32 per-row scales, head-major,
+    v_s: jnp.ndarray,     #   Hs >= Hkv rows (the first Hkv are read)
     length: jnp.ndarray,  # (B,) live cache length per slot
+    layer,                # int32 scalar: the stack's layer to read
     *,
+    t_bound: int = None,
     bt: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, C, H, Dh = q.shape
     assert C == 1, "kernel path is decode-only (one query row per slot)"
-    T, Hkv, Dhp = k_p.shape[1], k_p.shape[2], k_p.shape[3]
-    if Dh % 2 or Dhp * 2 != Dh:
+    T, W = k_p.shape[2], k_p.shape[3]
+    Dhp = Dh // 2
+    Hkv = W // max(Dhp, 1)
+    if Dh % 2 or Dhp * Hkv != W:
         raise ValueError(
             f"packed decode attention needs an even head dim packed two "
-            f"codes per byte, got Dh={Dh} with {Dhp} bytes per row")
-    assert H % Hkv == 0
+            f"codes per byte, got Dh={Dh} with {W} bytes per row")
+    Hs = k_s.shape[2]
+    assert H % Hkv == 0 and Hs >= Hkv, (H, Hkv, Hs)
     G = H // Hkv
-    n_t = max(1, -(-T // bt))
-    t_pad = n_t * bt
-
-    # packed rows flatten to (B, T, Hkv*Dh/2) — a free reshape — and the
-    # scales go head-major, (B, Hkv, T), so a (1, Hkv, bt) block is legal
-    k_p = _pad_t(k_p, t_pad).reshape(B, t_pad, Hkv * Dhp)
-    v_p = _pad_t(v_p, t_pad).reshape(B, t_pad, Hkv * Dhp)
-    k_s = _pad_t(k_s, t_pad).transpose(0, 2, 1)
-    v_s = _pad_t(v_s, t_pad).transpose(0, 2, 1)
+    bt, n_t = kernel_tiles(T, t_bound, bt)
 
     scale = 1.0 / np.sqrt(Dh)
     qf = (q.astype(jnp.float32) * scale)[:, 0].reshape(B, Hkv, G, Dh)
 
+    def s_map(b, it, lens, ly):
+        return ly[0], b, 0, jnp.minimum(it, last_live_tile(lens[b], bt))
+
     half = jax.ShapeDtypeStruct((B, Hkv, G, Dhp), jnp.float32)
-    slot_spec = pl.BlockSpec((1, Hkv, G, Dhp), lambda b, it: (b, 0, 0, 0))
-    s_spec = pl.BlockSpec((1, Hkv, bt), lambda b, it: (b, 0, it))
+    slot_spec = pl.BlockSpec((1, Hkv, G, Dhp),
+                             lambda b, it, lens, ly: (b, 0, 0, 0))
+    s_spec = pl.BlockSpec((None, 1, Hs, bt), s_map)
     o_lo, o_hi = pl.pallas_call(
         functools.partial(_decode_kernel, bt=bt, n_t=n_t, Hkv=Hkv, Dhp=Dhp),
-        grid=(B, n_t),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # lengths (B,)
-            slot_spec,                                       # q, even dims
-            slot_spec,                                       # q, odd dims
-            s_spec,                                          # k scales
-            s_spec,                                          # v scales
-            pl.BlockSpec(memory_space=pl.ANY),               # k packed (HBM)
-            pl.BlockSpec(memory_space=pl.ANY),               # v packed (HBM)
-        ],
-        out_specs=[slot_spec, slot_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                           # lengths, layer
+            grid=(B, n_t),
+            in_specs=[
+                slot_spec,                                   # q, even dims
+                slot_spec,                                   # q, odd dims
+                s_spec,                                      # k scales
+                s_spec,                                      # v scales
+                pl.BlockSpec(memory_space=pl.ANY),           # k packed (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),           # v packed (HBM)
+            ],
+            out_specs=[slot_spec, slot_spec],
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, W), jnp.uint8),      # k tile double buffer
+                pltpu.VMEM((2, bt, W), jnp.uint8),      # v tile double buffer
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),       # m
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),       # l
+                pltpu.VMEM((Hkv, G, Dhp), jnp.float32),     # acc, even dims
+                pltpu.VMEM((Hkv, G, Dhp), jnp.float32),     # acc, odd dims
+            ],
+        ),
         out_shape=[half, half],
-        scratch_shapes=[
-            pltpu.VMEM((2, bt, Hkv * Dhp), jnp.uint8),  # k tile double buffer
-            pltpu.VMEM((2, bt, Hkv * Dhp), jnp.uint8),  # v tile double buffer
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((Hkv, G, 1), jnp.float32),       # m
-            pltpu.VMEM((Hkv, G, 1), jnp.float32),       # l
-            pltpu.VMEM((Hkv, G, Dhp), jnp.float32),     # acc, even dims
-            pltpu.VMEM((Hkv, G, Dhp), jnp.float32),     # acc, odd dims
-        ],
         interpret=interpret,
         name="logicsparse_packed_decode_attention",
-    )(length.astype(jnp.int32), qf[..., 0::2], qf[..., 1::2], k_s, v_s,
-      k_p, v_p)
+    )(length.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      qf[..., 0::2], qf[..., 1::2], k_s, v_s, k_p, v_p)
     out = jnp.stack([o_lo, o_hi], axis=-1)      # re-interleave head dims
     return out.reshape(B, 1, H, Dh).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bt", "packed"))
+@functools.partial(jax.jit, static_argnames=("t_bound", "bt", "packed"))
 def tiled_packed_attention(
     q: jnp.ndarray,        # (B, C, H, Dh) query rows (decode C=1, prefill C>1)
-    k_c: jnp.ndarray,      # packed uint8 (B, T, Hkv, ceil(Dh/2)) or int8 codes
-    v_c: jnp.ndarray,      #   (B, T, Hkv, Dh) when packed=False
-    k_s: jnp.ndarray,      # (B, T, Hkv) f32
-    v_s: jnp.ndarray,      # (B, T, Hkv) f32
+    k_c: jnp.ndarray,      # packed uint8 (L, B, T, Hkv*ceil(Dh/2)), or int8
+    v_c: jnp.ndarray,      #   codes (L, B, T, Hkv*Dh) when packed=False
+    k_s: jnp.ndarray,      # (L, B, Hs, T) f32, head-major, Hs >= Hkv
+    v_s: jnp.ndarray,      #   (the first Hkv rows are read)
     lengths: jnp.ndarray,  # (B, C) live length per query row
+    layer,                 # int32 scalar: the stack's layer to read
     *,
+    t_bound: int = None,
     bt: int = 128,
     packed: bool = True,
 ) -> jnp.ndarray:
@@ -231,20 +278,18 @@ def tiled_packed_attention(
 
     Tile-by-tile online softmax in the op order of
     :func:`packed_decode_attention` (scales on the scores and the
-    probabilities); a tile that is dead for a given (b, c) row leaves
-    that row's (m, l, acc) state untouched via a ``where`` select,
-    mirroring the kernel's ``pl.when`` skip.
+    probabilities), each tile read from the stored layout at the layer's
+    index; a tile that is dead for a given (b, c) row leaves that row's
+    (m, l, acc) state untouched via a ``where`` select, mirroring the
+    kernel's ``pl.when`` skip.  The last tile of a buffer that ``bt``
+    does not divide holds the rows left.
     """
     B, C, H, Dh = q.shape
-    T, Hkv = k_c.shape[1], k_c.shape[2]
+    T, W = k_c.shape[2], k_c.shape[3]
+    Hkv = W // ((Dh + 1) // 2 if packed else Dh)
     G = H // Hkv
-    n_t = max(1, -(-T // bt))
-    t_pad = n_t * bt
-
-    k_c = _pad_t(k_c, t_pad)
-    v_c = _pad_t(v_c, t_pad)
-    k_s = _pad_t(k_s, t_pad)
-    v_s = _pad_t(v_s, t_pad)
+    bt, n_t = read_tiles(T, t_bound, bt)
+    layer = jnp.asarray(layer, jnp.int32)
 
     scale = 1.0 / np.sqrt(Dh)
     qf = (q.astype(jnp.float32) * scale).reshape(B, C, Hkv, G, Dh)
@@ -253,23 +298,30 @@ def tiled_packed_attention(
     l = jnp.zeros((B, C, Hkv, G), jnp.float32)
     acc = jnp.zeros((B, C, Hkv, G, Dh), jnp.float32)
 
+    def rows_of(a, start, n):
+        return jax.lax.dynamic_slice(
+            a, (layer, 0, start, 0), (1, B, n, a.shape[3]))[0]
+
+    def scales_of(a, start, n):
+        # (B, Hkv, n) -> (B, 1, Hkv, 1, n): broadcast over (c, g)
+        return jax.lax.dynamic_slice(
+            a, (layer, 0, 0, start), (1, B, Hkv, n))[0][:, None, :, None]
+
     for it in range(n_t):
-        tile_k = jax.lax.slice_in_dim(k_c, it * bt, (it + 1) * bt, axis=1)
-        tile_v = jax.lax.slice_in_dim(v_c, it * bt, (it + 1) * bt, axis=1)
+        n = min(bt, T - it * bt)
+        tile_k = rows_of(k_c, it * bt, n).reshape(B, n, Hkv, W // Hkv)
+        tile_v = rows_of(v_c, it * bt, n).reshape(B, n, Hkv, W // Hkv)
         if packed:
             codes_k = unpack_int4(tile_k, Dh, axis=-1)
             codes_v = unpack_int4(tile_v, Dh, axis=-1)
         else:
             codes_k, codes_v = tile_k, tile_v
-        ks = jax.lax.slice_in_dim(k_s, it * bt, (it + 1) * bt, axis=1)
-        vs = jax.lax.slice_in_dim(v_s, it * bt, (it + 1) * bt, axis=1)
-        # (B, bt, Hkv) -> (B, 1, Hkv, 1, bt): broadcast over (c, g)
-        ks = ks.transpose(0, 2, 1)[:, None, :, None, :]
-        vs = vs.transpose(0, 2, 1)[:, None, :, None, :]
+        ks = scales_of(k_s, it * bt, n)
+        vs = scales_of(v_s, it * bt, n)
         s = jnp.einsum("bcHgd,btHd->bcHgt", qf, codes_k.astype(jnp.float32),
                        preferred_element_type=jnp.float32) * ks
-        kpos = it * bt + jnp.arange(bt, dtype=jnp.int32)
-        valid = kpos[None, None, :] < lengths[:, :, None]  # (B, C, bt)
+        kpos = it * bt + jnp.arange(n, dtype=jnp.int32)
+        valid = kpos[None, None, :] < lengths[:, :, None]  # (B, C, n)
         s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])

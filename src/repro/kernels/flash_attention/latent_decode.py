@@ -12,8 +12,11 @@ G = H query heads, and each row serves as the key (all ``r + dr``
 lanes) and as the value.
 
 * :func:`latent_decode_attention` — the Pallas kernel, one query row per
-  slot.  Grid ``(B, n_t)``, kv tiles innermost, the tiling, dead-tile
-  skip and double-buffered DMA of
+  slot.  It reads the serving cache where it lies, as the packed K/V
+  read does: the whole layer stack of rows ``(L, B, T, W/2)`` in HBM and
+  the scales ``(L, B, 1, T)`` at a scalar-prefetched layer index.  Grid
+  ``(B, n_t)``, kv tiles innermost, the tiling, dead-tile skip and
+  double-buffered DMA of
   :mod:`repro.kernels.flash_attention.decode_packed`: each live tile of
   ``bt`` rows is copied from HBM once and decoded into its even- and
   odd-lane code planes, which serve the scores and the weighted sum
@@ -37,7 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.quant import unpack_int4
 from ..sparse_matmul.kernel import even_odd_lanes, field_planes
-from .decode_packed import NEG_INF, _pad_t
+from .decode_packed import (NEG_INF, kernel_tiles, last_live_tile,
+                            read_tiles)
 
 __all__ = ["latent_decode_attention", "tiled_latent_attention"]
 
@@ -45,14 +49,16 @@ __all__ = ["latent_decode_attention", "tiled_latent_attention"]
 _QK_DIMS = (((1,), (1,)), ((), ()))
 
 
-def _kernel(len_ref, qlo_ref, qhi_ref, s_ref, c_hbm, olo_ref, ohi_ref, buf,
-            sem, m_ref, l_ref, acc_lo, acc_hi, *, bt: int, n_t: int):
+def _kernel(len_ref, layer_ref, qlo_ref, qhi_ref, s_ref, c_hbm, olo_ref,
+            ohi_ref, buf, sem, m_ref, l_ref, acc_lo, acc_hi, *, bt: int,
+            n_t: int):
     b = pl.program_id(0)
     it = pl.program_id(1)
+    layer = layer_ref[0]
     length = len_ref[b]
 
     def _copy(j, slot):
-        return pltpu.make_async_copy(c_hbm.at[b, pl.ds(j * bt, bt)],
+        return pltpu.make_async_copy(c_hbm.at[layer, b, pl.ds(j * bt, bt)],
                                      buf.at[slot], sem.at[slot])
 
     @pl.when(it == 0)
@@ -104,14 +110,17 @@ def _kernel(len_ref, qlo_ref, qhi_ref, s_ref, c_hbm, olo_ref, ohi_ref, buf,
         ohi_ref[0] = acc_hi[...] * inv
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bt", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "t_bound", "bt", "interpret"))
 def latent_decode_attention(
     q: jnp.ndarray,       # (B, 1, H, W) absorbed queries, W = r + dr
-    c_p: jnp.ndarray,     # (B, T, W/2) uint8 packed latent rows
-    c_s: jnp.ndarray,     # (B, T) f32 per-row scales
+    c_p: jnp.ndarray,     # (L, B, T, W/2) uint8 packed latent rows, stacked
+    c_s: jnp.ndarray,     # (L, B, 1, T) f32 per-row scales
     length: jnp.ndarray,  # (B,) live cache length per slot
+    layer,                # int32 scalar: the stack's layer to read
     *,
     scale: float,
+    t_bound: int = None,
     bt: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -119,80 +128,85 @@ def latent_decode_attention(
     lanes, f32 (B, 1, H, W)."""
     B, C, H, W = q.shape
     assert C == 1, "kernel path is decode-only (one query row per slot)"
-    T, Wp = c_p.shape[1], c_p.shape[2]
+    T, Wp = c_p.shape[2], c_p.shape[3]
     if W % 2 or Wp * 2 != W:
         raise ValueError(
             f"latent decode attention needs an even row width packed two "
             f"codes per byte, got W={W} with {Wp} bytes per row")
-    n_t = max(1, -(-T // bt))
-    t_pad = n_t * bt
-    c_p = _pad_t(c_p, t_pad)
-    c_s = _pad_t(c_s, t_pad).reshape(B, 1, t_pad)
+    bt, n_t = kernel_tiles(T, t_bound, bt)
     qf = q.astype(jnp.float32)[:, 0] * scale                 # (B, H, W)
 
+    def s_map(b, it, lens, ly):
+        return ly[0], b, 0, jnp.minimum(it, last_live_tile(lens[b], bt))
+
     half = jax.ShapeDtypeStruct((B, H, Wp), jnp.float32)
-    q_spec = pl.BlockSpec((1, H, Wp), lambda b, it: (b, 0, 0))
+    q_spec = pl.BlockSpec((1, H, Wp), lambda b, it, lens, ly: (b, 0, 0))
     o_lo, o_hi = pl.pallas_call(
         functools.partial(_kernel, bt=bt, n_t=n_t),
-        grid=(B, n_t),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # lengths (B,)
-            q_spec,                                          # q, even lanes
-            q_spec,                                          # q, odd lanes
-            pl.BlockSpec((1, 1, bt), lambda b, it: (b, 0, it)),  # scales
-            pl.BlockSpec(memory_space=pl.ANY),               # rows (HBM)
-        ],
-        out_specs=[q_spec, q_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                           # lengths, layer
+            grid=(B, n_t),
+            in_specs=[
+                q_spec,                                      # q, even lanes
+                q_spec,                                      # q, odd lanes
+                pl.BlockSpec((None, 1, 1, bt), s_map),       # scales
+                pl.BlockSpec(memory_space=pl.ANY),           # rows (HBM)
+            ],
+            out_specs=[q_spec, q_spec],
+            scratch_shapes=[
+                pltpu.VMEM((2, bt, Wp), jnp.uint8),   # row tile double buffer
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, 1), jnp.float32),      # m
+                pltpu.VMEM((H, 1), jnp.float32),      # l
+                pltpu.VMEM((H, Wp), jnp.float32),     # acc, even lanes
+                pltpu.VMEM((H, Wp), jnp.float32),     # acc, odd lanes
+            ],
+        ),
         out_shape=[half, half],
-        scratch_shapes=[
-            pltpu.VMEM((2, bt, Wp), jnp.uint8),   # row tile double buffer
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((H, 1), jnp.float32),      # m
-            pltpu.VMEM((H, 1), jnp.float32),      # l
-            pltpu.VMEM((H, Wp), jnp.float32),     # acc, even lanes
-            pltpu.VMEM((H, Wp), jnp.float32),     # acc, odd lanes
-        ],
         interpret=interpret,
         name="logicsparse_latent_decode_attention",
-    )(length.astype(jnp.int32), *even_odd_lanes(qf), c_s, c_p)
+    )(length.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      *even_odd_lanes(qf), c_s, c_p)
     out = jnp.stack([o_lo, o_hi], axis=-1)          # re-interleave lanes
     return out.reshape(B, 1, H, W)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bt"))
+@functools.partial(jax.jit, static_argnames=("scale", "t_bound", "bt"))
 def tiled_latent_attention(
     q: jnp.ndarray,        # (B, C, H, W)
-    c_p: jnp.ndarray,      # (B, T, W/2) uint8
-    c_s: jnp.ndarray,      # (B, T) f32
+    c_p: jnp.ndarray,      # (L, B, T, W/2) uint8
+    c_s: jnp.ndarray,      # (L, B, 1, T) f32
     lengths: jnp.ndarray,  # (B, C) live length per query row
+    layer,                 # int32 scalar: the stack's layer to read
     *,
     scale: float,
+    t_bound: int = None,
     bt: int = 128,
 ) -> jnp.ndarray:
     """jnp twin of the kernel, batched over the chunk axis C: the same
-    online softmax tile by tile, a dead tile leaving a row's state as it
-    was.  Returns f32 (B, C, H, W)."""
+    online softmax tile by tile, each tile read from the stored layout
+    at the layer's index, a dead tile leaving a row's state as it was.
+    Returns f32 (B, C, H, W)."""
     B, C, H, W = q.shape
-    T = c_p.shape[1]
-    n_t = max(1, -(-T // bt))
-    t_pad = n_t * bt
-    c_p = _pad_t(c_p, t_pad)
-    c_s = _pad_t(c_s, t_pad)
+    T, Wp = c_p.shape[2], c_p.shape[3]
+    bt, n_t = read_tiles(T, t_bound, bt)
+    layer = jnp.asarray(layer, jnp.int32)
     qf = q.astype(jnp.float32) * scale
 
     m = jnp.full((B, C, H), NEG_INF, jnp.float32)
     l = jnp.zeros((B, C, H), jnp.float32)
     acc = jnp.zeros((B, C, H, W), jnp.float32)
     for it in range(n_t):
-        codes = unpack_int4(
-            jax.lax.slice_in_dim(c_p, it * bt, (it + 1) * bt, axis=1), W,
-            axis=-1).astype(jnp.float32)                     # (B, bt, W)
-        rs = jax.lax.slice_in_dim(c_s, it * bt, (it + 1) * bt,
-                                  axis=1)[:, None, None, :]  # (B,1,1,bt)
+        n = min(bt, T - it * bt)
+        rows = jax.lax.dynamic_slice(c_p, (layer, 0, it * bt, 0),
+                                     (1, B, n, Wp))[0]
+        codes = unpack_int4(rows, W, axis=-1).astype(jnp.float32)  # (B,n,W)
+        rs = jax.lax.dynamic_slice(c_s, (layer, 0, 0, it * bt),
+                                   (1, B, 1, n))[0][:, :, None, :]  # (B,1,1,n)
         s = jnp.einsum("bchw,btw->bcht", qf, codes,
                        preferred_element_type=jnp.float32) * rs
-        kpos = it * bt + jnp.arange(bt, dtype=jnp.int32)
-        valid = kpos[None, None, :] < lengths[:, :, None]   # (B, C, bt)
+        kpos = it * bt + jnp.arange(n, dtype=jnp.int32)
+        valid = kpos[None, None, :] < lengths[:, :, None]   # (B, C, n)
         s = jnp.where(valid[:, :, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])

@@ -132,10 +132,15 @@ def patch_embed_apply(p, x, *, bias=None, dispatch=None, activation=None,
 
 # KV-cache storage containers (attn_cache_init kv_cache=):
 #   "float"  — (B, T, Hkv, Dh) activations at cfg.param_dtype (the seed form)
-#   "int4"   — int8 codes in [-7, 7] + per-(slot, pos, head) f32 scales
-#   "int4x2" — the codes bit-packed two-per-byte along Dh (the weights' PR 5
-#              container applied to activations-at-rest); exact round trip,
-#              so "int4" and "int4x2" hold the same codes
+#   "int4"   — int8 codes in [-7, 7], (B, T, Hkv·Dh), + per-(slot, pos, head)
+#              f32 scales stored head-major, (B, Hs, T)
+#   "int4x2" — the codes bit-packed two-per-byte along Dh, (B, T,
+#              Hkv·ceil(Dh/2)) (the weights' int4x2 container applied to
+#              activations-at-rest); exact round trip, so "int4" and
+#              "int4x2" hold the same codes
+# The quantised forms are stored in the layout the packed decode kernel
+# reads (a row's kv heads side by side, scales head-major with Hs =
+# scale_rows(Hkv) rows), so the kernel reads the cache where it lies.
 KV_CACHE_MODES = ("float", "int4", "int4x2")
 
 
@@ -153,30 +158,33 @@ def _kv_quant(u: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return codes, scale
 
 
-def _kv_insert(cache_kv, upd, idx):
-    """Insert rows at per-sequence position ``idx`` (vmap over B).
+def _kv_insert(stack, upd, layer, idx, axis: int = 2):
+    """Write each slot's new rows into layer ``layer`` of a stacked cache
+    leaf, in place: one ``dynamic_update_slice`` a slot at ``(layer,
+    slot, idx[slot])``, ``axis`` the leaf's position axis.
 
-    ``upd``'s second axis may hold one decode row or a whole prefill
-    chunk — ``dynamic_update_slice`` writes the T rows contiguously from
-    ``idx``, exactly the cells T sequential single-row inserts would
-    write.  Works for any trailing layout: codes (T, Hkv, Dh), packed
-    bytes (T, Hkv, ceil(Dh/2)) and scales (T, Hkv) all update at
-    (i, 0[, 0]).
+    ``stack`` is (L, B, ...) and ``upd`` one layer's (B, ...) with one
+    decode row or a whole prefill chunk on the position axis —
+    ``dynamic_update_slice`` writes the rows contiguously from ``idx``,
+    exactly the cells sequential single-row inserts would write.  The
+    stack is the layer loop's carry, so XLA writes into its buffer
+    rather than into a copy of a layer slice.
     """
-    def one(c, u, i):
-        start = (i,) + (0,) * (c.ndim - 1)
-        return jax.lax.dynamic_update_slice(c, u, start)
     with jax.named_scope("kv_insert"):
-        return jax.vmap(one)(cache_kv, upd, idx)
+        upd = upd.astype(stack.dtype)
+        for b in range(upd.shape[0]):
+            start = [layer, b] + [0] * (stack.ndim - 2)
+            start[axis] = idx[b]
+            stack = jax.lax.dynamic_update_slice(stack, upd[None, b:b + 1],
+                                                 start)
+        return stack
 
 
 def _extent(arr, t_bound: Optional[int]):
-    """Slice a cache leaf to a static position bound (axis 1).
+    """Slice one layer's cache leaf to a static position bound (axis 1).
 
-    The quantised read's online softmax skips dead tiles, so at a fixed
-    kv tile size the result is invariant to the extent — the engine uses
-    this to run bucketed (shorter) reads early in a sequence without
-    changing a single bit of the output.
+    Only the float containers' reads take it; the quantised reads take
+    ``t_bound`` as their tile count and read the stored buffer in place.
     """
     if t_bound is not None and t_bound < arr.shape[1]:
         with jax.named_scope("kv_extent"):
@@ -210,11 +218,28 @@ def attn_apply(
     t_bound: Optional[int] = None,     # static cache-read extent (axis 1)
     bt: Optional[int] = None,          # fused-read kv tile rows (None=tuned)
     packed_read: str = "fused",        # quantised read: "fused" | "unpack"
+    layer=None,                        # layer of a stacked cache to use
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """Attention over ``x``; with a ``cache`` the cached step of decode
+    (T == 1) or of a prefill chunk (T > 1).
+
+    ``cache`` is the whole layer stack of the model's cache, each leaf
+    (L, B, ...), and ``layer`` the layer to read and write: the new rows
+    are written into the stack in place and the quantised reads take the
+    stack at the layer's index, so a layer loop that carries the stack
+    copies no layer of it.  With ``layer`` None, ``cache`` is one layer's
+    (B, ...) leaves, used as a stack of one."""
+    if cache is not None and layer is None:
+        out, new = attn_apply(
+            p, cfg, x, positions, jax.tree_util.tree_map(
+                lambda a: a[None], cache), patterns, dispatch,
+            n_valid=n_valid, t_bound=t_bound, bt=bt,
+            packed_read=packed_read, layer=0)
+        return out, jax.tree_util.tree_map(lambda a: a[0], new)
     if cfg.mla:
         return mla_apply(p, cfg, x, positions, cache, patterns, dispatch,
                          n_valid=n_valid, t_bound=t_bound, bt=bt,
-                         packed_read=packed_read)
+                         packed_read=packed_read, layer=layer)
     B, T, D = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = lin_apply(cfg, p["wq"], x, D, H * Dh, patterns,
@@ -256,7 +281,7 @@ def attn_apply(
                 f"unknown packed_read {packed_read!r} — 'fused' (tiled "
                 "nibble-decode read) or 'unpack' (full-container decode "
                 "baseline)")
-        idx = cache["length"]  # (B,)
+        idx = cache["length"][layer]  # (B,)
         nv = jnp.full((B,), T, jnp.int32) if n_valid is None \
             else n_valid.astype(jnp.int32)
         row = jnp.arange(T, dtype=jnp.int32)
@@ -267,72 +292,84 @@ def attn_apply(
         lengths = idx[:, None] + jnp.minimum(row + 1, nv[:, None])
         lengths = jnp.maximum(lengths, 1)
         if "k" in cache:
-            k_cache = _kv_insert(cache["k"], k, idx)
-            v_cache = _kv_insert(cache["v"], v, idx)
-            kx, vx = _extent(k_cache, t_bound), _extent(v_cache, t_bound)
+            k_cache = _kv_insert(cache["k"], k, layer, idx)
+            v_cache = _kv_insert(cache["v"], v, layer, idx)
+            kx = _extent(k_cache[layer], t_bound)
+            vx = _extent(v_cache[layer], t_bound)
             if T == 1:
                 o = decode_attention(q, kx, vx, lengths[:, 0])
             else:
                 o = prefill_attention(q, kx, vx, lengths)
-            new_cache = {"k": k_cache, "v": v_cache, "length": idx + nv}
+            new_cache = {"k": k_cache, "v": v_cache}
         else:
             from ..core.dispatch import attn_packed_dispatch
             from ..core.quant import pack_int4, unpack_int4
-            Dh_ = k.shape[-1]
             kq, ks = _kv_quant(k)
             vq, vs = _kv_quant(v)
-            k_s = _kv_insert(cache["k_s"], ks, idx)
-            v_s = _kv_insert(cache["v_s"], vs, idx)
-            if "k_p" in cache:  # int4x2: two codes per byte along Dh
-                k_st = _kv_insert(cache["k_p"], pack_int4(kq, axis=-1), idx)
-                v_st = _kv_insert(cache["v_p"], pack_int4(vq, axis=-1), idx)
-                packed = True
-                new_cache = {"k_p": k_st, "v_p": v_st}
-            else:               # int4: int8 container, same codes
-                k_st = _kv_insert(cache["k_q"], kq, idx)
-                v_st = _kv_insert(cache["v_q"], vq, idx)
-                packed = False
-                new_cache = {"k_q": k_st, "v_q": v_st}
+            # scales head-major, (B, Hkv, T) rows of the (B, Hs, T) leaf
+            k_s = _kv_insert(cache["k_s"], ks.transpose(0, 2, 1), layer,
+                             idx, axis=3)
+            v_s = _kv_insert(cache["v_s"], vs.transpose(0, 2, 1), layer,
+                             idx, axis=3)
+            packed = "k_p" in cache
+            if packed:  # int4x2: two codes per byte along Dh
+                kq, vq = pack_int4(kq, axis=-1), pack_int4(vq, axis=-1)
+            # a row's kv heads side by side: (B, T, Hkv * bytes a head)
+            k_st = _kv_insert(cache["k_p" if packed else "k_q"],
+                              kq.reshape(B, T, -1), layer, idx)
+            v_st = _kv_insert(cache["v_p" if packed else "v_q"],
+                              vq.reshape(B, T, -1), layer, idx)
+            new_cache = ({"k_p": k_st, "v_p": v_st} if packed
+                         else {"k_q": k_st, "v_q": v_st})
             if packed_read == "unpack":
                 # pre-fused baseline: decode the FULL container history to
                 # the compute dtype, then the plain attention read (kept as
                 # the bench comparison variant — this is the O(L·Dh)
                 # materialisation the fused read exists to kill)
-                k_codes = unpack_int4(k_st, Dh_, axis=-1) if packed else k_st
-                v_codes = unpack_int4(v_st, Dh_, axis=-1) if packed else v_st
-                dt = _dtype(cfg)
-                k_cache = (k_codes.astype(jnp.float32)
-                           * k_s[..., None]).astype(dt)
-                v_cache = (v_codes.astype(jnp.float32)
-                           * v_s[..., None]).astype(dt)
+                Tc = k_st.shape[2]
+
+                def dequant(st, sc):
+                    codes = st[layer].reshape(B, Tc, Hkv, -1)
+                    if packed:
+                        codes = unpack_int4(codes, Dh, axis=-1)
+                    rows = sc[layer][:, :Hkv].transpose(0, 2, 1)
+                    return (codes.astype(jnp.float32)
+                            * rows[..., None]).astype(_dtype(cfg))
+
+                k_cache, v_cache = dequant(k_st, k_s), dequant(v_st, v_s)
                 if T == 1:
                     o = decode_attention(q, k_cache, v_cache, lengths[:, 0])
                 else:
                     o = prefill_attention(q, k_cache, v_cache, lengths)
             else:
                 # fused tiled read: codes -> attention without the f32
-                # cache copy (and without the old intermediate cast to
-                # _dtype(cfg) — scores come straight from codes x scales)
+                # cache copy, from the stack at the layer's index
                 o = attn_packed_dispatch(
-                    q, _extent(k_st, t_bound), _extent(v_st, t_bound),
-                    _extent(k_s, t_bound), _extent(v_s, t_bound),
-                    lengths, packed=packed, dispatch=dispatch, bt=bt,
+                    q, k_st, v_st, k_s, v_s, lengths, layer, packed=packed,
+                    dispatch=dispatch, bt=bt, t_bound=t_bound,
                     leaf="attn.kv")
-            new_cache.update({"k_s": k_s, "v_s": v_s, "length": idx + nv})
+            new_cache.update({"k_s": k_s, "v_s": v_s})
+        new_cache["length"] = jax.lax.dynamic_update_index_in_dim(
+            cache["length"], idx + nv, layer, 0)
     o = o.reshape(B, T, H * Dh)
     return lin_apply(cfg, p["wo"], o, H * Dh, D, patterns, dispatch), new_cache
 
 
 def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int,
                     kv_cache: str = "float") -> Dict:
-    """Decode KV cache in one of the :data:`KV_CACHE_MODES` containers.
+    """One layer's decode KV cache in one of the :data:`KV_CACHE_MODES`
+    containers (``init_cache`` stacks the layers in front).
 
-    All three forms share the ``length`` bookkeeping and the (B, T, Hkv)
-    leading layout; the quantised forms add per-(slot, pos, head) f32
-    scales (``k_s``/``v_s``) next to the code container (``k_q``/``v_q``
-    int8, or ``k_p``/``v_p`` uint8 bit-packed along Dh — ceil(Dh/2) bytes
-    per row).  ``attn_apply`` detects the container from the dict keys at
-    trace time, so ``decode_step``'s signature carries no extra mode.
+    All three forms share the ``length`` bookkeeping and the slot-major
+    leading axis; the quantised forms store a row's codes for every kv
+    head side by side (``k_q``/``v_q`` int8, (B, T, Hkv·Dh), or
+    ``k_p``/``v_p`` uint8 bit-packed along Dh, (B, T, Hkv·ceil(Dh/2)))
+    with per-(slot, pos, head) f32 scales head-major (``k_s``/``v_s``,
+    (B, Hs, T), ``Hs =`` :func:`~repro.kernels.flash_attention.
+    decode_packed.scale_rows` of Hkv): the layout the packed decode
+    kernel reads.  ``attn_apply`` detects the container from the dict
+    keys at trace time, so ``decode_step``'s signature carries no extra
+    mode.
     """
     if cfg.mla:
         return mla_cache_init(cfg, batch, max_len, kv_cache=kv_cache)
@@ -348,19 +385,22 @@ def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int,
         raise ValueError(
             f"unknown kv_cache container {kv_cache!r} — valid: "
             f"{KV_CACHE_MODES}")
+    from ..kernels.flash_attention.decode_packed import scale_rows
+    Hs = scale_rows(Hkv)
     scales = {
-        "k_s": jnp.zeros((batch, max_len, Hkv), jnp.float32),
-        "v_s": jnp.zeros((batch, max_len, Hkv), jnp.float32),
+        "k_s": jnp.zeros((batch, Hs, max_len), jnp.float32),
+        "v_s": jnp.zeros((batch, Hs, max_len), jnp.float32),
     }
     if kv_cache == "int4":
         return {
-            "k_q": jnp.zeros((batch, max_len, Hkv, Dh), jnp.int8),
-            "v_q": jnp.zeros((batch, max_len, Hkv, Dh), jnp.int8),
+            "k_q": jnp.zeros((batch, max_len, Hkv * Dh), jnp.int8),
+            "v_q": jnp.zeros((batch, max_len, Hkv * Dh), jnp.int8),
             **scales, "length": length,
         }
+    row = Hkv * ((Dh + 1) // 2)
     return {  # int4x2: two codes per uint8 byte along Dh
-        "k_p": jnp.zeros((batch, max_len, Hkv, (Dh + 1) // 2), jnp.uint8),
-        "v_p": jnp.zeros((batch, max_len, Hkv, (Dh + 1) // 2), jnp.uint8),
+        "k_p": jnp.zeros((batch, max_len, row), jnp.uint8),
+        "v_p": jnp.zeros((batch, max_len, row), jnp.uint8),
         **scales, "length": length,
     }
 
@@ -379,7 +419,8 @@ def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int,
 # c_kv.  The cache holds the rows [c_kv ; k_pe] alone, one per position:
 # ``"float"`` as activations (``lat``), ``"int4x2"`` as int4 codes two per
 # byte (``c_p``, zero-padded to a 128-byte multiple, the tile the chip's
-# DMA takes) with one f32 scale per (slot, position) (``c_s``).
+# DMA takes) with one f32 scale per (slot, position) (``c_s``, (B, 1, T):
+# the packed K/V cache's head-major scale layout with one head).
 
 
 def mla_dims(cfg: ArchConfig) -> Tuple[int, int, int, int]:
@@ -443,8 +484,9 @@ def dense_weight(p: Params, K: int) -> jnp.ndarray:
 
 def mla_apply(p: Params, cfg: ArchConfig, x, positions, cache=None,
               patterns=None, dispatch=None, *, n_valid=None, t_bound=None,
-              bt=None, packed_read="fused"):
-    """Latent attention; the cache contract is :func:`attn_apply`'s."""
+              bt=None, packed_read="fused", layer):
+    """Latent attention; the cache contract is :func:`attn_apply`'s (a
+    stacked cache and the layer to use)."""
     B, T, D = x.shape
     H = cfg.n_heads
     r, dn, dr, dv = mla_dims(cfg)
@@ -468,16 +510,16 @@ def mla_apply(p: Params, cfg: ArchConfig, x, positions, cache=None,
         o = chunked_attention(qa, rows, rows, causal=cfg.causal, scale=scale)
         new_cache = None
     else:
-        idx = cache["length"]
+        idx = cache["length"][layer]
         nv = jnp.full((B,), T, jnp.int32) if n_valid is None \
             else n_valid.astype(jnp.int32)
         row = jnp.arange(T, dtype=jnp.int32)
         lengths = jnp.maximum(idx[:, None] + jnp.minimum(row + 1, nv[:, None]),
                               1)
         if "lat" in cache:
-            lat_c = _kv_insert(cache["lat"], lat, idx)
+            lat_c = _kv_insert(cache["lat"], lat, layer, idx)
             new_cache = {"lat": lat_c}
-            rows = _extent(lat_c, t_bound)[:, :, None, :]
+            rows = _extent(lat_c[layer], t_bound)[:, :, None, :]
             if T == 1:
                 o = decode_attention(qa, rows, rows, lengths[:, 0],
                                      scale=scale)
@@ -495,14 +537,17 @@ def mla_apply(p: Params, cfg: ArchConfig, x, positions, cache=None,
             packed = pack_int4(codes[:, :, 0], axis=-1)
             packed = jnp.pad(packed, ((0, 0), (0, 0),
                                       (0, Wp - packed.shape[-1])))
-            c_p = _kv_insert(cache["c_p"], packed, idx)
-            c_s = _kv_insert(cache["c_s"], sc[:, :, 0], idx)
+            c_p = _kv_insert(cache["c_p"], packed, layer, idx)
+            # one scale a row, head-major with its one head: (B, 1, T)
+            c_s = _kv_insert(cache["c_s"], sc.transpose(0, 2, 1), layer,
+                             idx, axis=3)
             new_cache = {"c_p": c_p, "c_s": c_s}
             qp = jnp.pad(qa, ((0, 0), (0, 0), (0, 0), (0, 2 * Wp - (r + dr))))
             o = latent_attn_dispatch(
-                qp, _extent(c_p, t_bound), _extent(c_s, t_bound), lengths,
-                scale=scale, dispatch=dispatch, bt=bt, leaf="attn.latent")
-        new_cache["length"] = idx + nv
+                qp, c_p, c_s, lengths, layer, scale=scale, dispatch=dispatch,
+                bt=bt, t_bound=t_bound, leaf="attn.latent")
+        new_cache["length"] = jax.lax.dynamic_update_index_in_dim(
+            cache["length"], idx + nv, layer, 0)
     o = jnp.einsum("bthr,rhv->bthv", o[..., :r].astype(jnp.float32),
                    wkv_b[..., dn:]).reshape(B, T, H * dv).astype(x.dtype)
     return lin_apply(cfg, p["wo"], o, H * dv, D, patterns, dispatch), \
@@ -522,7 +567,7 @@ def mla_cache_init(cfg: ArchConfig, batch: int, max_len: int,
             f"{kv_cache!r}")
     return {"c_p": jnp.zeros((batch, max_len, latent_row_bytes(cfg)),
                              jnp.uint8),
-            "c_s": jnp.zeros((batch, max_len), jnp.float32),
+            "c_s": jnp.zeros((batch, 1, max_len), jnp.float32),
             "length": length}
 
 

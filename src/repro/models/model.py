@@ -139,13 +139,16 @@ def _dense_block(p, cfg, h, positions, cache=None, patterns=None,
                  packed_read="fused", valid=None, layer=None):
     """Attention then MLP or MoE (by the leaves of ``p``); returns
     ``(h, cache, counts)``, ``counts`` the MoE layer's (experts hit,
-    routed rows), None for an MLP layer.  ``valid`` marks the rows a MoE
-    layer routes (all where None)."""
+    routed rows), None for an MLP layer.  ``cache`` is the whole layer
+    stack and ``layer`` this block's index in it (see
+    :func:`repro.models.blocks.attn_apply`).  ``valid`` marks the rows a
+    MoE layer routes (all where None)."""
     a, new_cache = attn_apply(p["attn"], cfg, norm_apply(cfg, p["ln1"], h),
                               positions, cache, patterns=patterns,
                               dispatch=dispatch, n_valid=n_valid,
                               t_bound=t_bound, bt=bt,
-                              packed_read=packed_read)
+                              packed_read=packed_read,
+                              layer=None if cache is None else layer)
     h = h + a
     x = norm_apply(cfg, p["ln2"], h)
     if "moe" in p:
@@ -180,9 +183,16 @@ def _hoist_experts(group):
 
 
 def _scan_groups(params, cfg: ArchConfig, h, cache, block):
-    """Scan ``block(p_layer, h, c_layer, layer) -> (h, c, counts)`` over
+    """Scan ``block(p_layer, h, cache, layer) -> (h, cache, counts)`` over
     each layer group; returns (h, new cache, summed MoE counts or None).
-    A cache of several groups is a dict keyed like the params."""
+    A cache of several groups is a dict keyed like the params.
+
+    The attention cache is carried through the scan whole, never scanned:
+    a scan slices each layer's inputs out of its stacked ``xs`` by copy
+    and writes each layer's outputs back by another, where each block
+    writes its new rows into the carried stack in place at its layer
+    index and reads the stack there (the expert stacks stay out of the
+    scanned inputs for the same reason, :func:`_hoist_experts`)."""
     groups = layer_groups(cfg)
     split = len(groups) > 1
     new_cache, total = {}, None
@@ -190,15 +200,16 @@ def _scan_groups(params, cfg: ArchConfig, h, cache, block):
         c = None if cache is None else (cache[name] if split else cache)
         scanned, experts = _hoist_experts(params[name])
 
-        def body(hh, xs, experts=experts):
-            p_layer, c_layer, i = xs
+        def body(carry, xs, experts=experts):
+            hh, cc = carry
+            p_layer, i = xs
             if experts is not None:
                 p_layer = {**p_layer, "moe": {**p_layer["moe"], **experts}}
-            out, nc, counts = block(p_layer, hh, c_layer, i)
-            return out, (nc, counts)
+            out, cc, counts = block(p_layer, hh, cc, i)
+            return (out, cc), counts
 
-        index = None if experts is None else jnp.arange(n, dtype=jnp.int32)
-        h, (nc, counts) = jax.lax.scan(body, h, (scanned, c, index))
+        (h, nc), counts = jax.lax.scan(
+            body, (h, c), (scanned, jnp.arange(n, dtype=jnp.int32)))
         new_cache[name] = nc
         if counts is not None:
             total = counts.sum(0) if total is None else total + counts.sum(0)

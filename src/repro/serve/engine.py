@@ -32,8 +32,17 @@ next real write.  Cache reads are bucketed to a power-of-two extent
 read skips dead tiles, so every bucket walks the same live tiles and
 bucketing changes compile shapes, not the tiles attended.
 
+Cache dataflow: the decode step programs take the cache donated, and
+the layer loop carries it whole (``model._scan_groups``), so a decode
+step writes each new row once, in place, into the one stacked buffer,
+and the packed attention kernels read that buffer where it lies; no
+layer of the cache is copied.  A caller that keeps a reference to
+``engine.cache`` across a decode step finds it deleted.
+
 Accounting: ``stats()`` reports prefill/decode step counts, token
-counts and the host-clock milliseconds of each step program from its
+counts, the decode steps whose cache donation was honoured
+(``decode_in_place``; equal to ``decode_steps`` unless XLA refused a
+donation) and the host-clock milliseconds of each step program from its
 dispatch until its output is on the host; ``tokens_processed()`` the
 total token throughput numerator; and each :class:`Request` carries
 ``t_submit`` / ``t_first`` / ``t_done`` stamps (TTFT = t_first −
@@ -44,9 +53,10 @@ span, so under ``jax.profiler.trace`` the host's time lands on the
 device trace's clock (with no profiler running a span costs about a
 microsecond).  ``engine.admit`` (arg ``admitted``) wraps admission and
 slot resets; ``engine.prefill`` (args ``uid``, ``slot``, ``rows``,
-``tb``) one chunk; ``engine.decode`` (args ``rows``, ``tb``) one decode
-step.  Each phase span holds ``.dispatch`` (the jitted call until it
-returns; for decode also the on-device argmax, queued behind it; and
+``tb``) one chunk; ``engine.decode`` (args ``rows``, ``tb``,
+``in_place``: the step's cache donation was honoured) one decode step.
+Each phase span holds ``.dispatch`` (the jitted call until it returns;
+for decode also the on-device argmax, queued behind it; and
 the start of the transfer to the host), ``.wait`` (until what the host
 fetches is ready on the device) and ``.fetch`` (until it is on the
 host, arg ``bytes``: the one int32 token of a chunk, which is the first
@@ -54,10 +64,11 @@ token of a completed prompt, or the decode step's tokens).  The step
 programs are named functions, so the device trace shows
 ``jit_engine_prefill``, ``jit_engine_decode`` and
 ``jit_engine_decode_legacy``; inside them the cache path carries the
-scopes ``slot_gather`` / ``slot_scatter``, ``layer_scan``, ``kv_insert``
-and ``kv_extent``.  A MoE model's step programs also return, in the same
-fetch as their tokens, two int32 counters: the experts that received
-rows, summed over MoE layers, and the routed (row, expert) assignments;
+scopes ``slot_gather`` / ``slot_scatter``, ``layer_scan`` and
+``kv_insert`` (and ``kv_extent`` where a float cache is read).  A MoE
+model's step programs also return, in the same fetch as their tokens,
+two int32 counters: the experts that received rows, summed over MoE
+layers, and the routed (row, expert) assignments;
 ``stats()`` lists them per step program (``moe_experts_hit``,
 ``moe_routed_rows``), and each step records them in an ``engine.moe``
 span (args ``phase``, ``experts_hit``, ``routed_rows``) after its fetch.
@@ -206,7 +217,8 @@ class ServeEngine:
         self._order: List[int] = []          # prefill FIFO (admission order)
         self._stats = {"prefill_steps": 0, "decode_steps": 0,
                        "prefill_tokens": 0, "decode_tokens": 0,
-                       "prefill_ms": [], "decode_ms": []}
+                       "prefill_ms": [], "decode_ms": [],
+                       "decode_in_place": 0}
         if self._moe:
             self._stats.update(moe_experts_hit=[], moe_routed_rows=[])
         self._decode_fns: Dict[int, object] = {}   # t_bound -> jitted step
@@ -215,7 +227,7 @@ class ServeEngine:
         def engine_decode_legacy(p, c, t):
             return decode_step(p, cfg, c, t, patterns=patterns,
                                dispatch=dispatch)
-        self._step = jax.jit(engine_decode_legacy)
+        self._step = jax.jit(engine_decode_legacy, donate_argnums=(1,))
 
     def submit(self, req: Request):
         if len(req.prompt) == 0:
@@ -331,7 +343,9 @@ class ServeEngine:
                         moe_counts=True)
                     tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
                     return jnp.concatenate([tok, n]), c
-            fn = jax.jit(engine_decode)
+            # the cache is donated: the step writes its rows into the
+            # cache's own buffers, with no fresh output copy of it
+            fn = jax.jit(engine_decode, donate_argnums=(1,))
             self._decode_fns[tb] = fn
         return fn
 
@@ -441,9 +455,14 @@ class ServeEngine:
                              routed_rows=rows):
             pass
 
-    def _decode_tokens(self, fn, *args):
+    def _decode_tokens(self, fn, span, *args):
         """Run one decode step program on the host ``args`` and bring the
-        greedy next token of every slot to the host."""
+        greedy next token of every slot to the host.  Counts the step in
+        ``decode_in_place`` where the program took every cache buffer it
+        was given (the donation was honoured: XLA only warns where it
+        refuses one), and sets the ``engine.decode`` ``span``'s
+        ``in_place`` arg to the same fact."""
+        given = jax.tree_util.tree_leaves(self.cache)
         with TraceAnnotation("engine.decode.dispatch"):
             out, self.cache = fn(self.params, self.cache,
                                  *map(jnp.asarray, args))
@@ -456,6 +475,9 @@ class ServeEngine:
             jax.block_until_ready(tok)
         with TraceAnnotation("engine.decode.fetch", bytes=tok.nbytes):
             got = np.asarray(tok, np.int32)
+        in_place = all(leaf.is_deleted() for leaf in given)
+        self._stats["decode_in_place"] += in_place
+        span.set_metadata(in_place=in_place)
         if self._moe:
             self._note_moe("decode", got[self.slots:])
             return got[:self.slots]
@@ -467,10 +489,11 @@ class ServeEngine:
         act = np.zeros(self.slots, np.int32)
         act[dec_slots] = 1
         tb = self._bucket_t(max(int(self._len[s]) for s in dec_slots) + 1)
-        with TraceAnnotation("engine.decode", rows=len(dec_slots), tb=tb):
+        with TraceAnnotation("engine.decode", rows=len(dec_slots),
+                             tb=tb) as span:
             fn = self._decode_fn(tb)
             t0 = time.perf_counter()
-            nxt = self._decode_tokens(fn, self.last_tok, act)
+            nxt = self._decode_tokens(fn, span, self.last_tok, act)
             now = time.perf_counter()
             self._stats["decode_steps"] += 1
             self._stats["decode_tokens"] += len(dec_slots)
@@ -518,9 +541,9 @@ class ServeEngine:
         if not self.active:
             return 0
         with TraceAnnotation("engine.decode", rows=len(self.active),
-                             tb=self.max_len):
+                             tb=self.max_len) as span:
             t0 = time.perf_counter()
-            nxt = self._decode_tokens(self._step, self.last_tok)
+            nxt = self._decode_tokens(self._step, span, self.last_tok)
             self.steps_run += 1
             now = time.perf_counter()
             self._stats["decode_steps"] += 1

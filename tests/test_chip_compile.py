@@ -15,6 +15,7 @@ test workers only the worker given this file loads the TPU library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from repro.core import dispatch as disp
 from repro.core.sparsity import pattern_from_bitmap
 from repro.kernels.fc_stack import fc_stack_matmul
 from repro.kernels.flash_attention.decode_packed import (
-    packed_decode_attention)
+    packed_decode_attention, scale_rows)
 from repro.kernels.quant_matmul.kernel import quant_matmul
 from repro.kernels.sparse_matmul.kernel import (block_sparse_matmul,
                                                 block_sparse_matmul_decode)
@@ -148,16 +149,17 @@ def test_grouped_quant_matmul_compiles_at_deepseek_widths(one_chip, K, N,
 
 
 def test_latent_decode_attention_compiles(one_chip):
+    """The latent read on an 8-layer stacked cache at a layer index."""
     from repro.kernels.flash_attention.latent_decode import (
         latent_decode_attention)
     bt = disp.ATTN_BT_DEFAULT
     compiled = _compile(
-        lambda q, c, s, n: latent_decode_attention(q, c, s, n, scale=0.1,
-                                                   bt=bt),
+        lambda q, c, s, n, ly: latent_decode_attention(
+            q, c, s, n, ly, scale=0.1, t_bound=4096, bt=bt),
         _spec(one_chip, (16, 1, 16, 768), jnp.float32),
-        _spec(one_chip, (16, 8192, 384), jnp.uint8),
-        _spec(one_chip, (16, 8192), jnp.float32),
-        _spec(one_chip, (16,), jnp.int32))
+        _spec(one_chip, (8, 16, 8192, 384), jnp.uint8),
+        _spec(one_chip, (8, 16, 1, 8192), jnp.float32),
+        _spec(one_chip, (16,), jnp.int32), _spec(one_chip, (), jnp.int32))
     _assert_kernel(compiled, "latent_decode_attention")
 
 
@@ -177,17 +179,24 @@ def test_quant_matmul_int4x2_compiles_for_a_lane_padded_n(one_chip, N):
     _assert_kernel(compiled, "quant_matmul[int4x2, lane-padded N]")
 
 
+def _packed_stack(sharding, T_, L=2):
+    """Stored-layout specs of an L-layer packed K or V cache leaf and its
+    head-major scales."""
+    return (_spec(sharding, (L, B, T_, HKV * DH // 2), jnp.uint8),
+            _spec(sharding, (L, B, scale_rows(HKV), T_), jnp.float32))
+
+
 @pytest.mark.parametrize("q_dtype", [jnp.bfloat16, jnp.float32])
 def test_packed_decode_attention_compiles(one_chip, q_dtype):
     bt = disp.ATTN_BT_DEFAULT
     assert disp.attn_packed_eligible(DH, bt, T)
-    packed = _spec(one_chip, (B, T, HKV, DH // 2), jnp.uint8)
-    scales = _spec(one_chip, (B, T, HKV), jnp.float32)
+    packed, scales = _packed_stack(one_chip, T)
     compiled = _compile(
-        lambda q, kp, vp, ks, vs, n: packed_decode_attention(
-            q, kp, vp, ks, vs, n, bt=bt),
+        lambda q, kp, vp, ks, vs, n, ly: packed_decode_attention(
+            q, kp, vp, ks, vs, n, ly, t_bound=T // 2, bt=bt),
         _spec(one_chip, (B, 1, HKV * G, DH), q_dtype), packed, packed,
-        scales, scales, _spec(one_chip, (B,), jnp.int32))
+        scales, scales, _spec(one_chip, (B,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))
     _assert_kernel(compiled, "packed_decode_attention")
 
 
@@ -226,16 +235,17 @@ def test_quant_kernel_eligible_matches_compiler(one_chip, K, N):
     assert ok == eligible, (K, N, ok, eligible)
 
 
-@pytest.mark.parametrize("bt,T_", [(128, 512), (64, 512), (64, 64)])
+@pytest.mark.parametrize("bt,T_", [(128, 512), (64, 512), (64, 64),
+                                   (128, 320)])
 def test_attn_packed_eligible_matches_compiler(one_chip, bt, T_):
     eligible = disp.attn_packed_eligible(DH, bt, T_)
-    packed = _spec(one_chip, (B, T_, HKV, DH // 2), jnp.uint8)
-    scales = _spec(one_chip, (B, T_, HKV), jnp.float32)
+    packed, scales = _packed_stack(one_chip, T_)
     ok = _compiles(
-        lambda q, kp, vp, ks, vs, n: packed_decode_attention(
-            q, kp, vp, ks, vs, n, bt=bt),
+        lambda q, kp, vp, ks, vs, n, ly: packed_decode_attention(
+            q, kp, vp, ks, vs, n, ly, bt=bt),
         _spec(one_chip, (B, 1, HKV * G, DH), jnp.float32), packed, packed,
-        scales, scales, _spec(one_chip, (B,), jnp.int32))
+        scales, scales, _spec(one_chip, (B,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))
     assert ok == eligible, (bt, T_, ok, eligible)
 
 
@@ -299,3 +309,89 @@ def test_compressed_decode_step_compiles_with_every_kernel(one_chip,
                  "logicsparse_packed_decode_attention"):
         assert name in txt, f"{name} missing from the compiled step"
     assert "tpu_custom_call" in txt
+
+
+# -------------------------------- the engine's decode program, in place
+
+
+_HLO_OP = re.compile(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w-]+)\((.*)")
+
+
+def _elems(dims: str) -> int:
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def test_engine_decode_program_updates_the_cache_in_place(one_chip,
+                                                          monkeypatch):
+    """The engine's decode step program at qwen1.5-4b's KV shape (20 kv
+    heads of 128, 16 slots), two layers, compiled for the chip: every
+    cache leaf is donated and aliased input to output, and no copy,
+    dynamic-slice or dynamic-update-slice moves as much as one layer of a
+    code or scale leaf (a scanned cache copies each layer slice in and
+    out, and a kernel that cannot read the stored layout adds a relayout
+    copy)."""
+    from repro.models.config import ArchConfig
+    from repro.models.model import init_params
+    from repro.serve.engine import ServeEngine
+    monkeypatch.setenv(disp.STRICT_ENV, "1")
+    slots, max_len = 16, 512
+    cfg = ArchConfig(name="kv", family="dense", n_layers=2, d_model=128,
+                     n_heads=20, n_kv_heads=20, head_dim=128, d_ff=256,
+                     vocab=256, param_dtype="bfloat16", remat=False)
+    eng = ServeEngine(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                      batch_slots=slots, max_len=max_len, kv_cache="int4x2",
+                      dispatch=disp.DispatchConfig(mode="pallas",
+                                                   interpret=False))
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    compiled = eng._decode_fn(max_len).lower(
+        shaped(eng.params), shaped(eng.cache),
+        _spec(one_chip, (slots, 1), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32)).compile()
+    txt = compiled.as_text()
+    assert "logicsparse_packed_decode_attention" in txt
+
+    # each cache leaf is an entry parameter aliased to an output
+    leaves = jax.tree_util.tree_leaves(eng.cache)
+    wanted = {(str(jnp.dtype(a.dtype)), ",".join(map(str, a.shape)))
+              for a in leaves}
+    short = {"uint8": "u8", "float32": "f32", "int32": "s32"}
+    wanted = {(short[d], s) for d, s in wanted}
+    entry = txt[txt.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    params = {int(m.group(3)): (m.group(1), m.group(2)) for m in re.finditer(
+        r"= (\w+)\[([\d,]*)\]\S* parameter\((\d+)\)", entry)}
+    cache_params = {i for i, sh in params.items() if sh in wanted}
+    assert len(cache_params) == len(leaves), (params, wanted)
+    aliased = {int(i) for i in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", txt.split("\n", 1)[0])}
+    assert cache_params <= aliased, (cache_params, aliased)
+
+    # nothing moves a layer of a code or scale leaf
+    layer_elems = {}
+    for a in leaves:
+        if a.ndim > 2:
+            d = short[str(jnp.dtype(a.dtype))]
+            layer_elems[d] = min(layer_elems.get(d, 1 << 62),
+                                 int(np.prod(a.shape[1:])))
+    shapes = {}
+    for line in txt.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            shapes[m.group(1)] = (m.group(2), m.group(3))
+    moved = []
+    for line in txt.splitlines():
+        m = _HLO_OP.match(line)
+        if not m or m.group(4) not in ("copy", "dynamic-slice",
+                                       "dynamic-update-slice"):
+            continue
+        dt, dims = m.group(2), m.group(3)
+        if m.group(4) == "dynamic-update-slice":
+            dt, dims = shapes[m.group(5).split(",")[1].strip().lstrip("%")]
+        if dt in layer_elems and _elems(dims) >= layer_elems[dt]:
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)

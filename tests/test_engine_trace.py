@@ -21,7 +21,7 @@ SLOTS = 2
 PHASES = ("prefill", "decode")
 PARTS = ("dispatch", "wait", "fetch")
 STATS_KEYS = {"prefill_steps", "decode_steps", "prefill_tokens",
-              "decode_tokens", "prefill_ms", "decode_ms"}
+              "decode_tokens", "prefill_ms", "decode_ms", "decode_in_place"}
 
 
 def _cfg():
@@ -148,6 +148,9 @@ def test_span_args(traced):
     assert [s[3]["rows"] for s in dec] == [
         st["decode_tokens"] for st in steps if st["decode_steps"]]
     assert {s[3]["tb"] for s in dec} == {32}
+    # every decode step took its cache donated and updated it in place
+    assert all(s[3]["in_place"] for s in dec)
+    assert len(dec) == eng.stats()["decode_in_place"]
     # the bytes moved to the host: one int32 token a chunk (its last
     # valid row's greedy token), or one int32 token a slot
     for name, nbytes in (("engine.prefill.fetch", 4),
@@ -185,10 +188,10 @@ def test_named_programs_and_scopes(params):
             eng.params, eng.cache, slot, chunk, nv),
         "jit_engine_decode_legacy": eng._step.lower(
             eng.params, eng.cache, toks)}
-    scopes = {"jit_engine_decode": ("layer_scan", "kv_insert", "kv_extent"),
+    # the packed cache is read in place at each extent: no kv_extent slice
+    scopes = {"jit_engine_decode": ("layer_scan", "kv_insert"),
               "jit_engine_prefill": ("slot_gather", "slot_scatter",
-                                     "layer_scan", "kv_insert",
-                                     "kv_extent"),
+                                     "layer_scan", "kv_insert"),
               "jit_engine_decode_legacy": ("layer_scan", "kv_insert")}
     for name, low in lowered.items():
         text = low.as_text(debug_info=True)

@@ -212,31 +212,76 @@ def test_grouped_quant_matmul_matches_twin(sizes):
         np.testing.assert_allclose(rows[i], want, rtol=0, atol=1e-4)
 
 
+def _latent_oracle(q, rows, lengths, scale):
+    """Plain f64 softmax of ``scale * q . rows`` over each slot's live
+    dequantised rows (B, T, W), weighted sum of the rows: (B, H, W)."""
+    out = []
+    for b in range(q.shape[0]):
+        L = int(lengths[b])
+        s = scale * np.asarray(q, np.float64)[b, 0] @ rows[b, :L].T
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out.append((p / p.sum(-1, keepdims=True)) @ rows[b, :L])
+    return np.stack(out)
+
+
 def test_latent_decode_attention_matches_twin():
     """The latent kernel (interpret mode) equals its tiled twin and a
     plain softmax over the dequantised rows, at live lengths that end
-    inside, at and past a kv tile."""
+    inside, at and past a kv tile.  The kernel reads the stored buffer in
+    place, so its tiles divide it (384 positions, three tiles of 128)."""
     rng = np.random.default_rng(6)
-    B, T, H, W, scale = 3, 300, 4, 256, 0.2
+    B, T, H, W, scale = 3, 384, 4, 256, 0.2
     codes = rng.integers(-7, 8, (B, T, W)).astype(np.int8)
     c_p = pack_int4(jnp.asarray(codes), axis=-1)
     c_s = jnp.asarray(rng.uniform(0.01, 0.2, (B, T)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, 1, H, W)), jnp.float32)
     lengths = jnp.asarray([1, 128, 300], jnp.int32)
-    got = latent_decode_attention(q, c_p, c_s, lengths, scale=scale, bt=128,
-                                  interpret=True)
-    twin = tiled_latent_attention(q, c_p, c_s, lengths[:, None],
-                                  scale=scale, bt=128)
+    # one layer in the stored layout: rows (1, B, T, W/2), scales
+    # (1, B, 1, T)
+    got = latent_decode_attention(q, c_p[None], c_s[None, :, None], lengths,
+                                  0, scale=scale, bt=128, interpret=True)
+    twin = tiled_latent_attention(q, c_p[None], c_s[None, :, None],
+                                  lengths[:, None], 0, scale=scale, bt=128)
     rows = codes.astype(np.float32) * np.asarray(c_s)[..., None]
-    for b in range(B):
-        L = int(lengths[b])
-        s = scale * np.asarray(q)[b, 0] @ rows[b, :L].T
-        p = np.exp(s - s.max(-1, keepdims=True))
-        want = (p / p.sum(-1, keepdims=True)) @ rows[b, :L]
-        np.testing.assert_allclose(np.asarray(got)[b, 0], want, rtol=0,
-                                   atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got)[:, 0],
+                               _latent_oracle(q, rows, lengths, scale),
+                               rtol=0, atol=1e-4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(twin), rtol=0,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("T,t_bound,bt", [
+    (256, None, 128),     # the whole buffer, two tiles
+    (256, 130, 128),      # an extent just past a tile
+    (256, 64, 128),       # an extent below the tile
+    (256, 96, 32),        # small tiles
+    (96, None, 128),      # a buffer shorter than the tile: one tile of it
+])
+def test_latent_read_of_a_stacked_cache_at_every_layer(T, t_bound, bt):
+    """Kernel and twin read a stacked (L, B, T, W/2) latent cache at each
+    layer index and agree with each other and with a plain softmax over
+    that layer's dequantised rows, whatever the extent and tile."""
+    rng = np.random.default_rng(T + bt)
+    L, B, H, W, scale = 3, 3, 4, 256, 0.2
+    codes = rng.integers(-7, 8, (L, B, T, W)).astype(np.int8)
+    c_p = pack_int4(jnp.asarray(codes), axis=-1)
+    c_s = jnp.asarray(rng.uniform(0.01, 0.2, (L, B, 1, T)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, W)), jnp.float32)
+    extent = T if t_bound is None else t_bound
+    lengths = jnp.asarray([1, min(37, extent), extent], jnp.int32)
+    for layer in range(L):
+        got = latent_decode_attention(q, c_p, c_s, lengths, layer,
+                                      scale=scale, t_bound=t_bound, bt=bt,
+                                      interpret=True)
+        twin = tiled_latent_attention(q, c_p, c_s, lengths[:, None], layer,
+                                      scale=scale, t_bound=t_bound, bt=bt)
+        rows = codes[layer].astype(np.float64) \
+            * np.asarray(c_s, np.float64)[layer, :, 0, :, None]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(twin),
+                                   rtol=0, atol=1e-5, err_msg=str(layer))
+        np.testing.assert_allclose(np.asarray(twin)[:, 0],
+                                   _latent_oracle(q, rows, lengths, scale),
+                                   rtol=0, atol=1e-4, err_msg=str(layer))
 
 
 def test_engine_serves_moe_on_chunked_interleave(model):

@@ -38,6 +38,8 @@ from repro.models.config import ArchConfig
 from repro.models.lenet import init_lenet, lenet_forward
 from repro.models.model import forward, init_params
 
+from _kvcache import softmax_oracle, stored_layout
+
 # the CI matrix legs the parity tests sweep (plus forced pallas below)
 DISPATCH_LEGS = ("auto", "jnp", "autotune")
 
@@ -449,3 +451,77 @@ def test_autotune_model_tunes_packed_leaves(tmp_path):
     packed_keys = [k for k in table.entries
                    if f"container={PACKED_CONTAINER}" in k]
     assert packed_keys, "packed leaves must tune under container-tagged keys"
+
+
+# ---------------------------------------------- the stacked packed KV cache
+
+
+def _per_head_cache(rng, L, B, T, Hkv, Dh):
+    """K or V of L layers in the per-head layout: packed codes (L, B, T,
+    Hkv, Dh/2) and scales (L, B, T, Hkv)."""
+    codes = rng.integers(-7, 8, (L, B, T, Hkv, Dh)).astype(np.int8)
+    return (pack_int4(jnp.asarray(codes), axis=-1),
+            jnp.asarray(rng.uniform(0.01, 0.2, (L, B, T, Hkv)), jnp.float32))
+
+
+@pytest.mark.parametrize("T,t_bound,bt", [
+    (256, None, 128),     # the whole buffer, two tiles
+    (256, 130, 128),      # an extent just past a tile
+    (256, 64, 128),       # an extent below the tile
+    (256, 96, 32),        # small tiles
+    (96, None, 128),      # a buffer shorter than the tile: one tile of it
+])
+@pytest.mark.parametrize("Hkv", [2, 3])   # 3 kv heads: padded scale rows
+def test_packed_read_of_a_stacked_cache_at_every_layer(T, t_bound, bt, Hkv):
+    """The packed decode kernel (interpret mode) and its jnp twin read a
+    stacked (L, B, T, Hkv * Dh/2) cache at each layer index: they agree
+    with each other, and the twin on the stored layout agrees with a
+    plain f64 softmax over the same values in the per-head layout."""
+    from repro.kernels.flash_attention.decode_packed import (
+        packed_decode_attention, tiled_packed_attention)
+    rng = np.random.default_rng(T + bt + Hkv)
+    L, B, G, Dh = 3, 3, 2, 8
+    k_p, k_s = _per_head_cache(rng, L, B, T, Hkv, Dh)
+    v_p, v_s = _per_head_cache(rng, L, B, T, Hkv, Dh)
+    (kc, ks), (vc, vs) = stored_layout(k_p, k_s), stored_layout(v_p, v_s)
+    q = jnp.asarray(rng.normal(size=(B, 1, Hkv * G, Dh)), jnp.float32)
+    extent = T if t_bound is None else t_bound
+    lengths = jnp.asarray([1, min(37, extent), extent], jnp.int32)
+    for layer in range(L):
+        got = packed_decode_attention(q, kc, vc, ks, vs, lengths, layer,
+                                      t_bound=t_bound, bt=bt, interpret=True)
+        twin = tiled_packed_attention(q, kc, vc, ks, vs, lengths[:, None],
+                                      layer, t_bound=t_bound, bt=bt)
+        oracle = softmax_oracle(q, k_p[layer], v_p[layer], k_s[layer],
+                                 v_s[layer], lengths)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(twin),
+                                   rtol=0, atol=1e-5, err_msg=str(layer))
+        np.testing.assert_allclose(np.asarray(twin), oracle, rtol=0,
+                                   atol=1e-5, err_msg=str(layer))
+
+
+def test_packed_read_refuses_a_tile_that_overruns_the_buffer():
+    """The kernel reads the stored buffer in place and never pads it: a
+    tile that does not divide the buffer is refused, and the dispatch
+    predicate routes such a shape to the twin, whose last tile holds the
+    rows left."""
+    from repro.core.dispatch import attn_packed_eligible
+    from repro.kernels.flash_attention.decode_packed import (
+        packed_decode_attention, tiled_packed_attention)
+    rng = np.random.default_rng(3)
+    k_p, k_s = _per_head_cache(rng, 1, 2, 200, 2, 8)
+    v_p, v_s = _per_head_cache(rng, 1, 2, 200, 2, 8)
+    (kc, ks), (vc, vs) = stored_layout(k_p, k_s), stored_layout(v_p, v_s)
+    q = jnp.asarray(rng.normal(size=(2, 1, 4, 8)), jnp.float32)
+    lengths = jnp.asarray([5, 150], jnp.int32)
+    with pytest.raises(ValueError, match="does not divide"):
+        packed_decode_attention(q, kc, vc, ks, vs, lengths, 0, bt=128,
+                                interpret=True)
+    twin = tiled_packed_attention(q, kc, vc, ks, vs, lengths[:, None], 0,
+                                  bt=128)
+    np.testing.assert_allclose(
+        np.asarray(twin), softmax_oracle(q, k_p[0], v_p[0], k_s[0], v_s[0],
+                                         lengths), rtol=0, atol=1e-5)
+    assert not attn_packed_eligible(8, 128, 200)
+    assert attn_packed_eligible(8, 128, 256) and attn_packed_eligible(8, 128,
+                                                                      96)

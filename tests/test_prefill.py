@@ -24,6 +24,8 @@ from repro.models.model import (decode_step, init_cache, init_params,
                                 prefill_step)
 from repro.serve.engine import Request, ServeEngine
 
+from _kvcache import softmax_oracle, stored_layout
+
 
 def _cfg():
     return ArchConfig(name="t", family="dense", n_layers=2, d_model=64,
@@ -83,9 +85,13 @@ def test_chunked_prefill_bitwise_matches_drip(leg, kv, monkeypatch,
     np.testing.assert_allclose(drip, ref, rtol=0, atol=ref_atol)
     assert np.array_equal(np.asarray(cache_a["length"]),
                           np.asarray(cache_b["length"]))
-    # leaves are (L, B, T, ...): compare the live T-rows only — the ragged
-    # chunk's pad rows hold garbage beyond `length`
-    live = lambda c, key: np.asarray(c[key])[:, :, :P]  # noqa: E731
+    # leaves are (L, B, T, ...) and the head-major scales (L, B, Hs, T):
+    # compare the live T-rows only — the ragged chunk's pad rows hold
+    # garbage beyond `length`
+    def live(c, key):
+        a = np.asarray(c[key])
+        return a[..., :P] if key.endswith("_s") else a[:, :, :P]
+
     if kv == "float":
         for key in ("k", "v"):
             np.testing.assert_allclose(live(cache_a, key),
@@ -97,31 +103,11 @@ def test_chunked_prefill_bitwise_matches_drip(leg, kv, monkeypatch,
         np.testing.assert_allclose(live(cache_a, key + "_s"),
                                    live(cache_b, key + "_s"),
                                    rtol=F32_ATOL, atol=0, err_msg=key)
-        codes = [np.asarray(unpack_int4(live(c, key + "_p"), cfg.head_dim,
-                                        axis=-1), np.int32)
-                 for c in (cache_a, cache_b)]
+        codes = [np.asarray(unpack_int4(
+            live(c, key + "_p").reshape(2, 2, P, cfg.n_kv_heads, -1),
+            cfg.head_dim, axis=-1), np.int32) for c in (cache_a, cache_b)]
         # a K/V entry within f32 rounding of a .5 boundary may round apart
         assert np.abs(codes[0] - codes[1]).max() <= 1, key
-
-
-def _attention_oracle(q, k_p, v_p, k_s, v_s, lengths):
-    """Plain softmax attention over the dequantised cache, numpy f64."""
-    from repro.core.quant import unpack_int4
-    B, _, H, Dh = q.shape
-    Hkv = k_p.shape[2]
-    G = H // Hkv
-    k = np.asarray(unpack_int4(k_p, Dh, axis=-1), np.float64) \
-        * np.asarray(k_s, np.float64)[..., None]
-    v = np.asarray(unpack_int4(v_p, Dh, axis=-1), np.float64) \
-        * np.asarray(v_s, np.float64)[..., None]
-    out = np.zeros((B, 1, H, Dh))
-    for b in range(B):
-        L = int(lengths[b])
-        for h in range(H):
-            s = k[b, :L, h // G] @ np.asarray(q[b, 0, h], np.float64)
-            p = np.exp(s / np.sqrt(Dh) - (s / np.sqrt(Dh)).max())
-            out[b, 0, h] = (p / p.sum()) @ v[b, :L, h // G]
-    return out
 
 
 @pytest.mark.parametrize("bt", [32, 64])
@@ -144,12 +130,14 @@ def test_fused_kernel_bitwise_matches_twin(bt):
     v_s = jnp.asarray(rng.uniform(0.01, 0.2, (B, T, Hkv)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.float32)
     lengths = jnp.asarray([1, 37, 128], jnp.int32)
+    kc, ks = stored_layout(k_p[None], k_s[None])
+    vc, vs = stored_layout(v_p[None], v_s[None])
 
-    got = packed_decode_attention(q, k_p, v_p, k_s, v_s, lengths, bt=bt,
+    got = packed_decode_attention(q, kc, vc, ks, vs, lengths, 0, bt=bt,
                                   interpret=True)
-    want = tiled_packed_attention(q, k_p, v_p, k_s, v_s,
-                                  lengths[:, None], bt=bt, packed=True)
-    oracle = _attention_oracle(q, k_p, v_p, k_s, v_s, lengths)
+    want = tiled_packed_attention(q, kc, vc, ks, vs, lengths[:, None], 0,
+                                  bt=bt, packed=True)
+    oracle = softmax_oracle(q, k_p, v_p, k_s, v_s, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0, atol=1e-5)
     np.testing.assert_allclose(np.asarray(got), oracle, rtol=0, atol=1e-5)

@@ -259,8 +259,11 @@ def test_packed_kv_decode_bitwise_matches_unpacked(leg, monkeypatch,
     np.testing.assert_allclose(logits["int4"], logits["int4x2"],
                                rtol=0, atol=1e-5)
     from repro.core.quant import unpack_int4
-    Dh = cfg.head_dim
-    codes = np.asarray(unpack_int4(caches["int4x2"]["k_p"], Dh, axis=-1))
+    Dh, Hkv = cfg.head_dim, cfg.n_kv_heads
+    # stored layout: a row's kv heads side by side, (L, B, T, Hkv * bytes)
+    packed = caches["int4x2"]["k_p"]
+    codes = np.asarray(unpack_int4(packed.reshape(packed.shape[:3] + (
+        Hkv, -1)), Dh, axis=-1)).reshape(caches["int4"]["k_q"].shape)
     assert np.abs(np.asarray(caches["int4"]["k_q"], np.int32)
                   - codes.astype(np.int32)).max() <= 1
     np.testing.assert_allclose(np.asarray(caches["int4"]["k_s"]),
@@ -358,3 +361,44 @@ def test_compile_llama3_2_1b_accounting_and_packed_kv_serve():
     done = eng.run()
     assert len(done) == 2 and all(len(r.out) == 2 for r in reqs)
     assert all(0 <= t < cfg.vocab for r in reqs for t in r.out)
+
+
+def test_decode_updates_the_donated_cache_in_place(monkeypatch):
+    """Every chunked-interleave decode step takes the cache donated and
+    updates it in place (``decode_in_place`` counts each), and serves
+    exactly the tokens of the same engine path run on an undonated copy
+    of the cache, which counts none."""
+    cfg = _cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, 211, size=n).astype(np.int32)
+               for n in (7, 3, 12, 5)]
+
+    def serve(eng):
+        reqs = [Request(uid=i, prompt=p, max_new_tokens=4)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        return [r.out for r in reqs], eng.stats()
+
+    def engine():
+        return ServeEngine(params, cfg, batch_slots=2, max_len=64,
+                           kv_cache="int4x2", prefill_chunk=4,
+                           dispatch="jnp")
+
+    donated, st = serve(engine())
+    assert st["prefill_steps"] > 0 and st["decode_steps"] > 0
+    assert st["decode_in_place"] == st["decode_steps"]
+
+    real_jit = jax.jit
+
+    def undonated_jit(fn, **kw):
+        kw.pop("donate_argnums", None)
+        return real_jit(fn, **kw)
+
+    monkeypatch.setattr(jax, "jit", undonated_jit)
+    kept, st_kept = serve(engine())
+    assert st_kept["decode_in_place"] == 0
+    assert st_kept["decode_steps"] == st["decode_steps"]
+    assert donated == kept
